@@ -1,7 +1,7 @@
 """tpulint — AST-based static analysis for TPU dispatch hazards.
 
-BENCH_r05's verdict is that the train loop is host-dispatch-bound: the
-hazard classes that put it there (hidden host syncs, per-call retraces,
+The train loop is host-dispatch-bound (one dispatch and one readback
+around milliseconds of device work): the hazard classes that break that (hidden host syncs, per-call retraces,
 unaccounted transfers, donated-buffer reuse, unstageable checkpoint tags)
 are all *source-level* mistakes that a profiler only catches after a
 regression ships. This package holds them statically instead:

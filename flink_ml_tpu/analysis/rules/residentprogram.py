@@ -6,7 +6,7 @@ trip — which a single ``io_callback`` / ``pure_callback`` /
 ``jax.debug.print`` / ``jax.debug.callback`` (or a stray builtin
 ``print``) inside the compiled loop silently destroys: each epoch of the
 resident while-loop then re-enters the host, turning the one-dispatch
-program back into a per-epoch tunnel conversation that no counter
+program back into a per-epoch host conversation that no counter
 accounts (callbacks bypass the ``packed_device_get`` funnels AND the
 ``iteration.host_sync`` budget). The rule flags host-callback calls that
 are lexically inside a resident program body:

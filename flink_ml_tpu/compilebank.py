@@ -16,7 +16,7 @@ on-disk bank, and warm-loaded at process start. A bank hit calls the
 loaded executable directly: **no trace, no XLA compile** — the
 ``jit.traces`` and ``jit.compiles`` counters both stay flat, which is
 what makes the serving SLA's ``aotColdStart.serveTraceCount == 0``
-assertion (bench.py) and the zero-tolerance ``servingSlo.recompileCount``
+assertion (scripts/coldstart_smoke.py) and the zero-tolerance ``servingSlo.recompileCount``
 CI pin honest rather than merely lucky.
 
 Integration is at the ``utils/lazyjit.py`` funnel (every accounted
@@ -51,7 +51,7 @@ import os
 import pickle
 import threading
 import time
-from typing import Any, Callable, Dict, Iterable, Optional, Tuple
+from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 from . import config
 from .utils.metrics import inc_counter, record_time, set_gauge
@@ -59,7 +59,7 @@ from .utils.metrics import inc_counter, record_time, set_gauge
 logger = logging.getLogger(__name__)
 
 #: bump when the entry pickle schema or signature descriptor changes
-FORMAT_VERSION = 1
+FORMAT_VERSION = 2  # 2: manifest entries record their execution devices
 
 MANIFEST = "manifest.json"
 ENTRY_SUFFIX = ".pbx"
@@ -262,8 +262,10 @@ class ProgramBank:
                 f"process {self._fingerprint}); refusing every entry"
             )
             return
+        import jax
         from jax.experimental import serialize_executable
 
+        devices_by_id = {d.id: d for d in jax.devices()}
         for sig, record in (manifest.get("entries") or {}).items():
             entry_path = os.path.join(self.path, record.get("file", ""))
             try:
@@ -280,8 +282,17 @@ class ProgramBank:
                 continue
             try:
                 payload = pickle.loads(raw)
+                # load onto the devices the entry was compiled for: the
+                # default is every device of the backend, which a program
+                # compiled for a sub-mesh (or one device of several)
+                # cannot execute on
                 loaded = serialize_executable.deserialize_and_load(
-                    payload["payload"], payload["in_tree"], payload["out_tree"]
+                    payload["payload"],
+                    payload["in_tree"],
+                    payload["out_tree"],
+                    execution_devices=[
+                        devices_by_id[i] for i in record["devices"]
+                    ],
                 )
             except Exception as exc:
                 self._refuse(f"entry {sig} failed to deserialize ({exc})")
@@ -334,6 +345,9 @@ class ProgramBank:
                 payload, in_tree, out_tree = serialize_executable.serialize(
                     compiled
                 )
+                device_ids = [
+                    d.id for d in compiled.runtime_executable().local_devices()
+                ]
                 raw = pickle.dumps(
                     {
                         "payload": payload,
@@ -354,9 +368,15 @@ class ProgramBank:
                         exc,
                     )
                 return
-            self._persist(sig, descriptor, raw)
+            self._persist(sig, descriptor, raw, device_ids)
 
-    def _persist(self, sig: str, descriptor: Dict[str, Any], raw: bytes) -> None:
+    def _persist(
+        self,
+        sig: str,
+        descriptor: Dict[str, Any],
+        raw: bytes,
+        device_ids: List[int],
+    ) -> None:
         from .ckpt.coordinator import atomic_commit
 
         fname = sig + ENTRY_SUFFIX
@@ -369,6 +389,7 @@ class ProgramBank:
             "file": fname,
             "sha256": hashlib.sha256(raw).hexdigest(),
             "kernel": descriptor.get("kernel"),
+            "devices": device_ids,
         }
         manifest = {
             "fingerprint": self._fingerprint,

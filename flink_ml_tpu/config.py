@@ -96,33 +96,6 @@ def fleet_shard_threshold(nbytes: Optional[int]):
         fleet_shard_state_bytes = prev
 
 
-# --- Pallas sparse kernels (ops/sparsekernels.py) -----------------------------
-# Route the sparse padded-CSR gradient path (masked gather row-dots + the
-# segment-sum scatter XLA lowers poorly) through hand-written Pallas
-# kernels instead of the lax gather/scatter ops. The kernels run with
-# interpret=True on the CPU backend so tier-1 exercises them; results are
-# bit-identical to the lax path (same masking convention, same row-major
-# accumulation order — tests/test_dispatch_pipeline.py pins it). Opt-in:
-# the lax path remains the reference.
-use_pallas_sparse: bool = False
-
-
-@contextmanager
-def pallas_sparse_mode(enabled: bool = True):
-    """Scoped override of `use_pallas_sparse`."""
-    global use_pallas_sparse
-    prev = use_pallas_sparse
-    use_pallas_sparse = bool(enabled)
-    try:
-        yield
-    finally:
-        use_pallas_sparse = prev
-
-
-if os.environ.get("FLINK_ML_TPU_USE_PALLAS_SPARSE") in ("1", "true", "on"):
-    use_pallas_sparse = True
-
-
 # --- collectives: chunking, sparse reduction, comm/compute overlap ------------
 # (parallel/collectives.py + parallel/overlap.py)
 # Bucket size for all_reduce_sum_chunked: a large gradient pytree is
@@ -618,35 +591,38 @@ if os.environ.get("FLINK_ML_TPU_LIFECYCLE_CANARY_RTOL"):
 
 
 # --- persistent XLA compilation cache ----------------------------------------
-# Cold-start killer: compiled executables survive process restarts, so the
-# first fit of a new process reuses the previous process's XLA programs
-# (sparseWideLR cold 2.3 s / kmeans cold 936 ms in BENCH_r05 are almost
-# entirely backend compiles). Opt-in via enable_compilation_cache() or the
-# FLINK_ML_TPU_COMPILATION_CACHE_DIR env var.
+# Compiled executables survive process restarts, so the first fit of a new
+# process reuses the previous process's XLA programs. WHERE the cache
+# lives is decided here and nowhere else: the directory is part of what a
+# later process must find again, so it is either the one the environment
+# names (JAX_COMPILATION_CACHE_DIR, read by jax itself at import — the
+# program then sets no directory in code) or a fixed path in the checkout.
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
 compilation_cache_dir: Optional[str] = None
 
 
-def enable_compilation_cache(path: Optional[str] = None) -> Optional[str]:
-    """Point jax's persistent compilation cache at `path` (default:
-    `.jax_cache` under the current working directory). Returns the
-    directory in use, or None when jax refuses the option (ancient jax)."""
+def enable_compilation_cache(path: Optional[str] = None) -> str:
+    """Turn on jax's persistent compilation cache and return the
+    directory in use: `JAX_COMPILATION_CACHE_DIR` when the environment
+    sets it (it wins over `path`), else `path`, else `.jax_cache` at the
+    root of the checkout this module was imported from — never the
+    working directory, so every process of one checkout shares a cache."""
     global compilation_cache_dir
-    path = path or os.path.join(os.getcwd(), ".jax_cache")
-    try:
-        import jax
+    import jax
 
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        path = env_dir
+    else:
+        path = path or CHECKOUT_CACHE_DIR
         jax.config.update("jax_compilation_cache_dir", path)
-        # every kernel here is worth persisting — the hot loops are small
-        # programs that compile in well under the default 1s threshold
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:
-        return None
+    # every kernel here is worth persisting — the hot loops are small
+    # programs that compile in well under the default 1s threshold
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     compilation_cache_dir = path
     return path
-
-
-if os.environ.get("FLINK_ML_TPU_COMPILATION_CACHE_DIR"):
-    enable_compilation_cache(os.environ["FLINK_ML_TPU_COMPILATION_CACHE_DIR"])
 
 
 # --- AOT program bank (compilebank.py) ----------------------------------------

@@ -126,7 +126,6 @@ def run_sgd(
     if isinstance(X, tuple):  # sparse: train on padded CSR, no densify
         indices, values, dim = X
         X = (indices, values)
-        # the Pallas-kernel route when config.use_pallas_sparse is on
         loss_func = sparse_variant(loss_func.name)
         init_coeff = np.zeros(dim, dtype=np.float64)
         # a mesh with a model axis declares the feature-sharded intent:

@@ -156,45 +156,6 @@ SPARSE_VARIANTS = {
 }
 
 
-def _sparse_pallas(pointwise):
-    """Padded-CSR batched loss on the Pallas kernels
-    (ops/sparsekernels.py): the masked gather dot and the segment-sum
-    scatter — the two ops XLA lowers worst on TPU — become hand-written
-    kernels; the pointwise math is unchanged. Bit-identical to `_sparse`
-    (same masking convention and accumulation order, pinned by
-    tests/test_dispatch_pipeline.py)."""
-
-    def fn(X, y, w, coeff) -> LossOut:
-        from .sparsekernels import sparse_grad, sparse_row_dots
-
-        indices, values = X
-        dot = sparse_row_dots(indices, values, coeff)
-        loss, multiplier = pointwise(dot, y, w)
-        grad = sparse_grad(indices, values, multiplier, coeff)
-        return jnp.sum(loss), grad, jnp.sum(w)
-
-    return fn
-
-
-PALLAS_SPARSE_BINARY_LOGISTIC_LOSS = LossFunc(
-    "sparse_binary_logistic_pallas", _sparse_pallas(_logistic_pointwise),
-    _logistic_pointwise, True,
-)
-PALLAS_SPARSE_HINGE_LOSS = LossFunc(
-    "sparse_hinge_pallas", _sparse_pallas(_hinge_pointwise), _hinge_pointwise, True
-)
-PALLAS_SPARSE_LEAST_SQUARE_LOSS = LossFunc(
-    "sparse_least_square_pallas", _sparse_pallas(_least_square_pointwise),
-    _least_square_pointwise, True,
-)
-
-PALLAS_SPARSE_VARIANTS = {
-    BINARY_LOGISTIC_LOSS.name: PALLAS_SPARSE_BINARY_LOGISTIC_LOSS,
-    HINGE_LOSS.name: PALLAS_SPARSE_HINGE_LOSS,
-    LEAST_SQUARE_LOSS.name: PALLAS_SPARSE_LEAST_SQUARE_LOSS,
-}
-
-
 def _feature_sharded(pointwise):
     """Padded-CSR batched loss for the explicit 2D `(data, model)` mesh
     (parallel/overlap.py `sgd2d_*`): runs INSIDE a shard_map body where
@@ -272,16 +233,11 @@ FEATURE_SHARDED_LEAST_SQUARE_LOSS = LossFunc(
     _least_square_pointwise, True,
 )
 
-#: sparse (and pallas-sparse) loss name -> its 2D feature-sharded variant.
-#: The pallas names map to the same plain variant: the 2D body's masked
-#: slice gather is not the kernel the pallas route hand-writes.
+#: sparse loss name -> its 2D feature-sharded variant.
 FEATURE_SHARDED_VARIANTS = {
     SPARSE_BINARY_LOGISTIC_LOSS.name: FEATURE_SHARDED_BINARY_LOGISTIC_LOSS,
     SPARSE_HINGE_LOSS.name: FEATURE_SHARDED_HINGE_LOSS,
     SPARSE_LEAST_SQUARE_LOSS.name: FEATURE_SHARDED_LEAST_SQUARE_LOSS,
-    PALLAS_SPARSE_BINARY_LOGISTIC_LOSS.name: FEATURE_SHARDED_BINARY_LOGISTIC_LOSS,
-    PALLAS_SPARSE_HINGE_LOSS.name: FEATURE_SHARDED_HINGE_LOSS,
-    PALLAS_SPARSE_LEAST_SQUARE_LOSS.name: FEATURE_SHARDED_LEAST_SQUARE_LOSS,
 }
 
 
@@ -293,15 +249,8 @@ def feature_sharded_variant(loss_func: LossFunc) -> LossFunc:
 
 
 def sparse_variant(name: str) -> LossFunc:
-    """The padded-CSR LossFunc for the dense loss `name`, routed to the
-    Pallas kernels under `config.use_pallas_sparse`. The two routes are
-    DISTINCT LossFunc objects: the loss is a jit static argument in every
-    training kernel, so flipping the flag re-enters a different compiled
-    executable instead of silently reusing a stale one."""
-    from .. import config
-
-    table = PALLAS_SPARSE_VARIANTS if config.use_pallas_sparse else SPARSE_VARIANTS
-    return table[name]
+    """The padded-CSR LossFunc for the dense loss `name`."""
+    return SPARSE_VARIANTS[name]
 
 
 def predict_raw(X, coeff):

@@ -18,10 +18,15 @@ each other and against compute. This module therefore carries two tiers:
 - the comm layer proper: `all_reduce_sum_chunked` (bucketed
   reduce_scatter+all_gather with a ring-pipelined ppermute variant) and
   `sparse_all_reduce_sum` (SparCML-style index-value reduction, wire bytes
-  ∝ nnz instead of dim — arXiv:1802.08021). Both are bit-identical to a
-  single `lax.psum` of the same operand (pinned across chunk sizes and
-  shard counts by tests/test_collective_chunks.py); the overlap-scheduled
-  training loops in parallel/overlap.py are built on them.
+  ∝ nnz instead of dim — arXiv:1802.08021). The chunked reduce is
+  bit-identical to a single `lax.psum` of the same operand (pinned across
+  chunk sizes and shard counts by tests/test_collective_chunks.py, and
+  seen on four v5e chips); its ring variant is on the CPU backend only,
+  and the sparse reduce sums the same addends but agrees with the psum
+  of the densified operand to float rounding, not bitwise, on either
+  (see their docstrings).
+  The overlap-scheduled training loops in parallel/overlap.py are built
+  on them.
 
 These wrappers are used inside `shard_map`-ped functions; outside
 `shard_map`, prefer sharding annotations and let XLA insert collectives.
@@ -237,9 +242,14 @@ def _reduce_bucket_rs_ag(vec, axis_name: str, n: int):
 def _reduce_bucket_ring(vec, axis_name: str, n: int):
     """One bucket via the ring pipeline: n-1 `ppermute` hops rotate every
     shard's contribution around the ring, and each shard folds the arrivals
-    IN REPLICA ORDER (0..n-1 left-associated — the order the backend's own
-    all-reduce uses, so the fold stays bit-identical to `psum`; a classic
-    rotation-order ring reassociates the sum and is not). With several
+    IN REPLICA ORDER (0..n-1 left-associated — the order the CPU
+    backend's own all-reduce uses, so there the fold is bit-identical to
+    `psum`, pinned by tests/test_collective_chunks.py; a classic
+    rotation-order ring reassociates the sum and is not). The TPU's
+    all-reduce associates differently: on four v5e chips the ring and
+    `psum` differed in 6908 of 16384 elements, by up to 9.4e-5 relative
+    on an operand built to expose reassociation (PR 21), so on chips the
+    ring agrees with `psum` to float rounding only. With several
     buckets in flight, bucket i+1's hops are dataflow-independent of bucket
     i's fold — the double-buffered schedule where chunk i+1's transfer
     overlaps chunk i's compute (the async-collective pass materializes the
@@ -342,7 +352,7 @@ def sparse_all_reduce_sum(
     axis_name: str = DATA_AXIS,
 ):
     """All-reduce a gradient carried as per-shard (index, value) pairs;
-    returns the dense `(dim,)` sum, bit-identical to
+    returns the dense `(dim,)` sum of exactly the addends of
     `psum(zeros(dim).at[indices].add(values))` of the densified operand.
 
     Wire bytes are the pairs, not the dim: each shard contributes its
@@ -350,10 +360,13 @@ def sparse_all_reduce_sum(
     dense vector never crosses a link — the SparCML index-value exchange
     that makes sparseWideLR gradient traffic scale with nnz instead of
     dim. The cross-shard combine scatters each shard's gathered pairs into
-    its own dense partial and folds the partials in replica order — the
-    exact association of the dense path (per-shard sequential scatter-add,
-    then replica-ordered psum), which is what makes the result bitwise
-    equal, not merely close.
+    its own dense partial and folds the partials in replica order, as
+    the dense path does (per-shard scatter-add, then psum). The result is
+    equal to float rounding, NOT bitwise: XLA may fold a shard's
+    scatter-add into the running sum, so where one shard holds duplicate
+    indices `acc + (a + b)` becomes `(acc + a) + b` — 1-2 ulp on the CPU
+    backend of jax 0.9.0 (tests/test_collective_chunks.py pins 1e-6). One
+    shard is the same expression as the dense path and is exact.
 
     Out-of-range / negative indices are dropped (`mode="drop"`), matching
     the padded-CSR convention of ops/losses.py. Callers pick sparse vs
@@ -411,16 +424,8 @@ def shard_map_over(mesh: Mesh, in_specs, out_specs, fn=None, check_vma: bool = F
     """
 
     def wrap(f):
-        if hasattr(jax, "shard_map"):
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-            )
-        # pre-graft jax (< 0.6): shard_map lives under experimental with
-        # check_rep instead of check_vma
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
+        return jax.shard_map(
+            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
         )
 
     return wrap(fn) if fn is not None else wrap

@@ -15,18 +15,20 @@ hierarchical chunk-and-overlap schedule.
 
 Bit-parity is by construction, the same way the dispatch pipeline pins
 chunked epochs (docs/performance.md §1): the reduction still happens
-before the apply that consumes it, the chunked/sparse reduction is
+before the apply that consumes it, the chunked reduction is
 bit-identical to the monolithic psum, and the per-epoch update order is
 unchanged — so overlap mode produces bit-identical coefficients, stop
 epochs, and criteria (pinned by tests/test_collective_chunks.py for dense
-and sparse losses, tol early-stop included).
+losses, tol early-stop included, and for sparse losses while their
+gradient densifies onto the chunked path).
 
 Sparse gradients additionally ride the SparCML index-value reduction
 (`collectives.sparse_all_reduce_sum`) when their per-shard pair bytes are
 below `config.collective_sparse_threshold` × the dense payload: the
 (indices, values) pairs of the batch cross the links instead of the
 densified `(dim,)` vector, so sparseWideLR gradient traffic scales with
-nnz, not dim.
+nnz, not dim. That reduction matches the densified psum to float rounding
+rather than bitwise (see its docstring), and so does a fit that uses it.
 
 Gated by `config.collective_overlap` (see ops/optimizer.py and the KMeans
 driver); compiled programs are cached per (mesh, loss, flags) so repeated

@@ -326,7 +326,7 @@ class MicroBatchServer:
         """Pad `batch` to its bucket and (optionally) upload numeric host
         columns — the H2D leg of the double buffer. All uploadable columns
         go through ONE `device_put` call (per-column puts would each pay a
-        dispatch; on a remote-attached device, a round trip)."""
+        dispatch)."""
         n = batch.num_rows
         bucket = _next_bucket(n, self.buckets)
         self._buckets_seen.add(bucket)
@@ -454,8 +454,8 @@ class MicroBatchServer:
         (`config.program_bank_dir`, compilebank.py) the compiled
         programs back-fill the bank, so the NEXT process's warmup is
         pure warm-loads — zero traces, zero XLA compiles — and its
-        first request meets the no-compile SLA (`aotColdStart` bench
-        entry asserts exactly this).
+        first request meets the no-compile SLA
+        (`scripts/coldstart_smoke.py` asserts exactly this).
 
         Returns {"programs", "warmupMs", "bankHits", "bankMisses"} for
         the run; a guard tripped by synthetic rows is swallowed (the

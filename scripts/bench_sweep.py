@@ -33,14 +33,11 @@ DEFAULT_OUT = os.path.join(REPO, "benchmarks", "SWEEP.json")
 
 
 def child(config_path: str, runs: int) -> None:
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(REPO, ".jax_cache"))
-    import jax
-
-    jax.config.update("jax_compilation_cache_dir", os.path.join(REPO, ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     sys.path.insert(0, REPO)
+    from flink_ml_tpu import config as lib_config
     from flink_ml_tpu.benchmark import runner
 
+    lib_config.enable_compilation_cache()
     config = runner.load_config(config_path)
     for name, entry in config.items():
         if name == "version":

@@ -17,7 +17,15 @@ modes by a FRESH process each time:
   (every program traces + compiles), for the bank-on vs bank-off
   cold-start walls the `aotColdStart` bench entry reports.
 
-Prints one JSON object on stdout (the bench entry and the CI step both
+- ``bench`` — the `aotColdStart` entry: this process stays OFF jax (a
+  chip belongs to one process at a time, so a parent that had touched
+  jax would starve its children) and runs ``populate``, ``serve`` and
+  ``baseline`` as three children, one at a time, against a temporary
+  bank directory. It asserts the banked serve's zero traces / zero
+  compiles / bank hits and that its output sha256 equals the freshly
+  compiled baseline's, then prints the cold-start walls side by side.
+
+Prints one JSON object on stdout (the bench mode and the CI step both
 parse it): coldStartMs (process start → first result), firstServeMs,
 serveTraceCount, serveCompileCount, bankHits/bankMisses/bankLoads,
 bankLoadMs, and a sha256 of the output column for cross-process
@@ -63,10 +71,80 @@ def build_workload():
     return model, example
 
 
+def run_bench():
+    """Parent of the three cold-start children; imports no jax."""
+    import shutil
+    import subprocess
+    import tempfile
+
+    # the bank is keyed by program signature, not by path, so a
+    # temporary directory is found again by the serve child all the same
+    bank_dir = tempfile.mkdtemp(prefix="aot-bank.")
+
+    def child(mode):
+        t0 = time.perf_counter()
+        proc = subprocess.run(
+            [sys.executable, os.path.abspath(__file__), bank_dir, mode],
+            capture_output=True,
+            text=True,
+            timeout=600,
+        )
+        wall_ms = (time.perf_counter() - t0) * 1000.0
+        if proc.returncode != 0:
+            tail = "; ".join(proc.stderr.strip().splitlines()[-3:])
+            raise RuntimeError(f"{mode} child: exit {proc.returncode}: {tail}")
+        out = json.loads(proc.stdout.strip().splitlines()[-1])
+        out["processWallMs"] = wall_ms
+        return out
+
+    try:
+        populate = child("populate")
+        serve = child("serve")  # exits 1 itself on a trace, a compile or no bank hit
+        baseline = child("baseline")
+    finally:
+        shutil.rmtree(bank_dir, ignore_errors=True)
+    if serve["outSha"] != baseline["outSha"]:
+        raise RuntimeError(
+            "bank-loaded executable output diverged from the freshly-compiled "
+            f"baseline: {serve['outSha']} != {baseline['outSha']}"
+        )
+    print(
+        json.dumps(
+            {
+                "mode": "bench",
+                "device": serve["device"],
+                "coldStartMs": serve["coldStartMs"],
+                "baselineColdStartMs": baseline["coldStartMs"],
+                "firstServeMs": serve["firstServeMs"],
+                "baselineFirstServeMs": baseline["firstServeMs"],
+                "populateMs": populate["warmupMs"],
+                "bankLoadMs": serve["bankLoadMs"],
+                "bankLoads": serve["bankLoads"],
+                "bankHits": serve["bankHits"],
+                "bankMisses": serve["bankMisses"],
+                "serveTraceCount": serve["serveTraceCount"],
+                "serveCompileCount": serve["serveCompileCount"],
+                "baselineServeTraceCount": baseline["serveTraceCount"],
+                "baselineServeCompileCount": baseline["serveCompileCount"],
+                "processWallMs": {
+                    "populate": populate["processWallMs"],
+                    "serve": serve["processWallMs"],
+                    "baseline": baseline["processWallMs"],
+                },
+                "bitIdentical": True,
+            }
+        )
+    )
+    return 0
+
+
 def main(argv):
+    if len(argv) == 2 and argv[1] == "bench":
+        return run_bench()
     if len(argv) != 3 or argv[2] not in ("populate", "serve", "baseline"):
         print(
-            f"usage: {argv[0]} <bankdir> populate|serve|baseline",
+            f"usage: {argv[0]} <bankdir> populate|serve|baseline\n"
+            f"       {argv[0]} bench",
             file=sys.stderr,
         )
         return 2
@@ -83,12 +161,13 @@ def main(argv):
     tracing.install_jax_hooks()
 
     if mode != "baseline":
+        # the bank alone, so bank-on vs bank-off differ in one thing. (The
+        # persistent XLA cache stays off here for a second reason: on the
+        # CPU backend of jaxlib 0.9.0 an executable loaded from a WARM
+        # persistent cache cannot be re-serialized into the bank. The two
+        # tiers together are pinned by tests/test_compilebank.py from a
+        # cold cache, and on the TPU by chip_smoke.py's serve phase.)
         config.program_bank_dir = bank_dir
-        # both persistence tiers on, as production would run (the bank
-        # satisfies the declared programs; the XLA cache memoizes any
-        # residual op-by-op compiles) — their interplay is pinned by
-        # tests/test_compilebank.py
-        config.enable_compilation_cache(os.path.join(bank_dir, "xla-cache"))
 
     import numpy as np
 
@@ -112,8 +191,11 @@ def main(argv):
             np.asarray(out.column("norm"), dtype=np.float32)
         ).tobytes()
     ).hexdigest()
+    from bench import device_facts
+
     payload = {
         "mode": mode,
+        "device": device_facts(),
         "coldStartMs": cold_start_ms,
         "firstServeMs": first_serve_ms,
         "serveTraceCount": float(delta.get("jit.traces", 0)),

@@ -1,8 +1,8 @@
 """bench_diff regression gate (scripts/bench_diff.py) — file-shape
 normalization (headline / driver wrapper / truncated-tail recovery),
 direction + threshold policy, and the two acceptance cases: the
-synthetic 20% wallMs regression exits nonzero, the real checked-in
-BENCH_r04 -> BENCH_r05 pair exits zero."""
+synthetic 20% wallMs regression exits nonzero, a clean pair in the
+driver's wrapper shape exits zero."""
 
 import importlib.util
 import json
@@ -251,9 +251,48 @@ def test_tail_recovery_outermost_fragments():
     assert "sweep" not in entries  # no numeric leaves -> not an entry
 
 
-def test_real_r05_tail_recovers_entries():
-    with open(os.path.join(_ROOT, "BENCH_r05.json")) as f:
-        entries = bench_diff.normalize(json.load(f))
+def _headline(scale=1.0):
+    """A bench.py headline line in the shape the round driver recorded:
+    a long trace entry first, then the stage entries."""
+    return {
+        "metric": "logisticregression_train_throughput",
+        "value": 1.0e8 / scale,
+        "unit": "records/s/chip",
+        "details": {
+            "logisticregressionTrace": {
+                "wallMs": 120.0 * scale,
+                "topOps": {
+                    f"fusion.{i}": {"durUs": 50.0 + i, "bytes": 32000240, "count": 20}
+                    for i in range(40)
+                },
+            },
+            "sparseWideLR": {"coldTimeMs": 2300.0 * scale, "totalTimeMs": 1600.0 * scale},
+            "kmeans": {"coldTimeMs": 936.0 * scale, "totalTimeMs": 93.0 * scale,
+                       "vsPublishedBaseline": 76.9},
+            "sweep": {"file": "benchmarks/SWEEP.json", "meta": {"numEntries": 44}},
+        },
+    }
+
+
+def _driver_wrapper(headline, keep=None):
+    """The driver's record of a bench run: the last `keep` characters of
+    stdout (None = all of it, parsed)."""
+    line = json.dumps(headline)
+    parsed = headline if keep is None else None
+    return {"n": 5, "cmd": "python bench.py", "rc": 0,
+            "tail": line if keep is None else line[-keep:], "parsed": parsed}
+
+
+def test_cut_driver_tail_recovers_entries():
+    """A wrapper whose single JSON line outgrew the tail the driver keeps
+    (`parsed: null`, the head cut mid-entry) still yields the entries that
+    survive whole in the tail."""
+    line = json.dumps(_headline())
+    keep = len(line) - line.index('"fusion.30"')  # cut inside the trace entry
+    wrapper = _driver_wrapper(_headline(), keep=keep)
+    with pytest.raises(ValueError):
+        json.loads(wrapper["tail"])
+    entries = bench_diff.normalize(wrapper)
     assert "sparseWideLR" in entries and "kmeans" in entries
     assert entries["kmeans"]["totalTimeMs"] > 0
 
@@ -287,8 +326,18 @@ def test_cli_synthetic_20pct_wallms_regression_exits_nonzero():
     assert "wallMs" in out.stdout
 
 
-def test_cli_real_r04_r05_pair_exits_zero():
-    out = _run_cli("BENCH_r04.json", "BENCH_r05.json", "--check")
+def test_cli_clean_wrapper_pair_exits_zero(tmp_path):
+    """A clean pair in the driver's wrapper shape — one parsed, one with a
+    cut tail, the second 2% faster — passes the gate."""
+    line = json.dumps(_headline(0.98))
+    keep = len(line) - line.index('"fusion.30"')
+    for name, wrapper in (
+        ("BENCH_a.json", _driver_wrapper(_headline())),
+        ("BENCH_b.json", _driver_wrapper(_headline(0.98), keep=keep)),
+    ):
+        with open(tmp_path / name, "w") as f:
+            json.dump(wrapper, f)
+    out = _run_cli(str(tmp_path / "BENCH_a.json"), str(tmp_path / "BENCH_b.json"), "--check")
     assert out.returncode == 0, out.stdout + out.stderr
     assert "0 regression(s)" in out.stdout
 

@@ -95,8 +95,13 @@ class TestChunkedParity:
 
 
 class TestSparseParity:
-    """sparse_all_reduce_sum == psum of the densified operand, bitwise,
-    including dropped padding indices."""
+    """sparse_all_reduce_sum == psum of the densified operand — the same
+    addends, dropped padding indices included — to float rounding. Not
+    bitwise across shards: XLA folds each shard's scatter-add into the
+    running cross-shard sum, so where one shard holds duplicate indices
+    (a + b) + acc becomes (acc + a) + b, a 1-2 ulp difference on this
+    XLA's CPU backend (jax 0.9.0). One shard is the same expression on
+    both sides and stays exact."""
 
     @pytest.mark.parametrize("ndev", [1, 2, 8])
     def test_matches_densified_psum(self, ndev):
@@ -116,9 +121,12 @@ class TestSparseParity:
         sparse = coll.shard_map_over(mesh, in_specs=in_specs, out_specs=P())(
             lambda i, v: coll.sparse_all_reduce_sum(i[0], v[0], dim)
         )
-        np.testing.assert_array_equal(
-            np.asarray(jax.jit(sparse)(idx, val)), np.asarray(jax.jit(dense)(idx, val))
-        )
+        got = np.asarray(jax.jit(sparse)(idx, val))
+        want = np.asarray(jax.jit(dense)(idx, val))
+        if ndev == 1:
+            np.testing.assert_array_equal(got, want)
+        else:  # sums of O(1) normals: a few f32 ulp
+            np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
 
     def test_wire_bytes_scale_with_nnz(self, mesh8):
         """The acceptance shape (dim=1M, nnz=39): traced sparse pair bytes
@@ -146,7 +154,9 @@ class TestSparseParity:
 
 class TestOverlapSgdParity:
     """Overlap-scheduled SGD (carry-delayed apply) bit-identical to the
-    eager program: coefficients, final loss, stop epoch."""
+    eager program — coefficients, final loss, stop epoch — wherever the
+    reduction is the chunked one; to float rounding where the sparse
+    index-value reduction engages (test_sparse)."""
 
     def _fit(self, mesh, X, y, loss, d, overlap_on, **kw):
         from flink_ml_tpu.ops.optimizer import SGD
@@ -192,9 +202,12 @@ class TestOverlapSgdParity:
 
     @pytest.mark.parametrize("ndev", [1, 2, 8])
     def test_sparse(self, ndev):
-        """Sparse losses: at 8 shards the per-shard pair bytes beat the
-        threshold and the index-value reduction engages; at 1-2 shards the
-        gradient densifies onto the chunked path — both bit-identical."""
+        """Sparse losses: at 1-2 shards the gradient densifies onto the
+        chunked path and the fit is bit-identical; at 8 shards the
+        per-shard pair bytes beat the threshold and the index-value
+        reduction engages, which matches the densified psum to float
+        rounding only (TestSparseParity), so ten epochs agree to ~1e-7
+        relative, pinned here at 1e-5."""
         from flink_ml_tpu.ops.losses import SPARSE_BINARY_LOGISTIC_LOSS
 
         mesh = _mesh(ndev)
@@ -212,8 +225,13 @@ class TestOverlapSgdParity:
             c1, l1, e1 = self._fit(
                 mesh, (indices, values), y, SPARSE_BINARY_LOGISTIC_LOSS, dim, True, **kw
             )
-        np.testing.assert_array_equal(c0, c1)
-        assert (l0, e0) == (l1, e1)
+        assert e0 == e1
+        if ndev <= 2:
+            np.testing.assert_array_equal(c0, c1)
+            assert l0 == l1
+        else:
+            np.testing.assert_allclose(c0, c1, rtol=1e-5, atol=1e-9)
+            assert l0 == pytest.approx(l1, rel=1e-6)
 
     def test_sparse_pairs_route_engages(self, mesh8):
         """The trace-time router picks index-value pairs exactly when the

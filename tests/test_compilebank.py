@@ -107,6 +107,14 @@ def test_bank_with_persistent_xla_cache(tmp_path):
         assert first.tobytes() == again.tobytes()
     finally:
         config.compilation_cache_dir = prev_cache
+        # the jax-level cache switch is process-global: turn it back off
+        # so later tests do not compile through this test's directory
+        # (XLA:CPU cannot re-serialize an executable it loaded from the
+        # persistent cache, which a later bank back-fill would trip on)
+        from jax.experimental.compilation_cache import compilation_cache
+
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
 
 
 # ---------------------------------------------------------------------------

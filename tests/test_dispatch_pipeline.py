@@ -585,80 +585,3 @@ class TestWholeFitFallbacks:
             )
         assert counters.get("dispatch.whole_fit", 0) == 0
         assert counters.get("dispatch.whole_fit_fallback", 0) == 0
-
-
-class TestPallasSparseKernels:
-    """ops/sparsekernels.py: the Pallas gather-dot and segment-sum must be
-    bit-identical to the lax path — same masking, same accumulation
-    order — and the flag routes fits through them."""
-
-    def _matrix(self, n=64, d=24, nnz=5, seed=9):
-        rng = np.random.RandomState(seed)
-        indices = np.stack(
-            [rng.choice(d, nnz, replace=False) for _ in range(n)]
-        ).astype(np.int32)
-        values = rng.randn(n, nnz).astype(np.float32)
-        indices[-3:, -2:] = -1  # padding rows exercise the mask
-        return indices, values
-
-    def test_row_dots_bit_identical(self):
-        from flink_ml_tpu.ops.losses import sparse_dot
-        from flink_ml_tpu.ops.sparsekernels import sparse_row_dots
-
-        indices, values = self._matrix()
-        coeff = jnp.asarray(np.random.RandomState(2).randn(24).astype(np.float32))
-        ref, _, _ = sparse_dot(jnp.asarray(indices), jnp.asarray(values), coeff)
-        got = sparse_row_dots(jnp.asarray(indices), jnp.asarray(values), coeff)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
-
-    def test_grad_matches_lax_segment_sum(self):
-        from flink_ml_tpu.ops.sparsekernels import sparse_grad
-
-        indices, values = self._matrix()
-        d = 24
-        mult = jnp.asarray(np.random.RandomState(4).randn(64).astype(np.float32))
-        coeff = jnp.zeros((d,), jnp.float32)
-        valid = indices >= 0
-        safe = np.where(valid, indices, 0)
-        vals = np.where(valid, values, 0.0)
-        ref = (
-            jnp.zeros_like(coeff)
-            .at[jnp.asarray(safe)]
-            .add(jnp.asarray(vals) * mult[:, None], mode="drop")
-        )
-        got = sparse_grad(jnp.asarray(indices), jnp.asarray(values), mult, coeff)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
-
-    def test_sparse_fit_bit_identical_and_flag_routes(self):
-        from flink_ml_tpu.ops.losses import (
-            PALLAS_SPARSE_BINARY_LOGISTIC_LOSS,
-            sparse_variant,
-        )
-        from flink_ml_tpu.parallel import mesh as mesh_lib
-
-        assert sparse_variant("binary_logistic").name == "sparse_binary_logistic"
-        with config.pallas_sparse_mode():
-            assert (
-                sparse_variant("binary_logistic")
-                is PALLAS_SPARSE_BINARY_LOGISTIC_LOSS
-            )
-        Xs, y = _sparse_problem()
-        # single data shard: the whole fit must be BIT-identical (same
-        # masking + accumulation order). Across a sharded mesh GSPMD
-        # partitions the two formulations with different cross-shard
-        # reduction orders (the documented cross-shard caveat), so the
-        # default-mesh check is allclose.
-        mesh1 = mesh_lib.create_mesh(
-            (mesh_lib.DATA_AXIS,), devices=jax.devices()[:1]
-        )
-        sgd = lambda loss, mesh: SGD(
-            max_iter=9, global_batch_size=32, tol=0.0
-        ).optimize(np.zeros(12), Xs, y, None, loss, mesh=mesh)
-        ref = sgd(SPARSE_BINARY_LOGISTIC_LOSS, mesh1)
-        got = sgd(PALLAS_SPARSE_BINARY_LOGISTIC_LOSS, mesh1)
-        np.testing.assert_array_equal(got[0], ref[0])
-        assert got[1] == ref[1] and got[2] == ref[2]
-        ref8 = sgd(SPARSE_BINARY_LOGISTIC_LOSS, None)
-        got8 = sgd(PALLAS_SPARSE_BINARY_LOGISTIC_LOSS, None)
-        np.testing.assert_allclose(got8[0], ref8[0], rtol=1e-6, atol=1e-7)
-        assert got8[2] == ref8[2]
