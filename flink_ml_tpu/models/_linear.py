@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import tracing
 from ..ops.losses import LossFunc, sparse_variant
 from ..utils.lazyjit import lazy_jit
 from ..ops.optimizer import SGD, read_train_result
@@ -75,11 +76,66 @@ def run_sgd(
     (cache-then-replay, the ReplayOperator contract — SGD.optimize_stream)
     with an identical batch schedule, so both paths produce the same
     coefficients for the same data."""
-    from .. import config
-    from ..parallel.iteration import checkpoint_job_key
     from ..table import StreamTable
 
-    optimizer = SGD(
+    if isinstance(table, StreamTable):
+        chunks = _stream_chunks(
+            table,
+            params.get_features_col(),
+            params.get_label_col(),
+            weight_col,
+            validate_binomial,
+        )
+        coeff, loss, epochs, _ = _optimizer_for(params).optimize_stream(
+            None, chunks, loss_func
+        )
+        return coeff, loss, epochs
+    with tracing.phase("fit.extract"):
+        optimizer = _optimizer_for(params)
+        X, y, w = extract_train_data(
+            table, params.get_features_col(), params.get_label_col(), weight_col,
+            keep_sparse=True,
+        )
+        validate_on_device = False
+        if validate_binomial:
+            if isinstance(y, jax.Array):
+                # device labels: the {0,1} validity check is computed INSIDE the
+                # training program and read back fused with the packed training
+                # result — a standalone bool() here would cost its own host
+                # round trip before training even starts
+                validate_on_device = True
+            else:
+                validate_binomial_labels(y)
+        if isinstance(X, tuple):  # sparse: train on padded CSR, no densify
+            indices, values, dim = X
+            X = (indices, values)
+            loss_func = sparse_variant(loss_func.name)
+            init_coeff = np.zeros(dim, dtype=np.float64)
+            # a mesh with a model axis declares the feature-sharded intent:
+            # wide sparse estimator fits take the 2D (data × model) layout
+            # automatically (coeff + optimizer carries as model-axis slices,
+            # see ops.optimizer.SGD._use_2d / docs/performance.md "2D mesh")
+            from ..parallel import mesh as mesh_lib
+
+            optimizer.shard_features = (
+                mesh_lib.MODEL_AXIS in mesh_lib.default_mesh().axis_names
+            )
+        else:
+            init_coeff = np.zeros(X.shape[1], dtype=np.float64)
+    result = optimizer.optimize_async(
+        init_coeff, X, y, w, loss_func, validate_labels=validate_on_device
+    )
+    flag_val, coeff, criteria, epochs = read_train_result(result)
+    _raise_if_invalid(flag_val)
+    return coeff, criteria, epochs
+
+
+def _optimizer_for(params) -> SGD:
+    """The SGD engine with a Has*-param stage's hyperparameters."""
+    from .. import config
+    from ..parallel.iteration import checkpoint_job_key
+
+    return SGD(
         max_iter=params.get_max_iter(),
         learning_rate=params.get_learning_rate(),
         global_batch_size=params.get_global_batch_size(),
@@ -99,52 +155,6 @@ def run_sgd(
             else None
         ),
     )
-    if isinstance(table, StreamTable):
-        chunks = _stream_chunks(
-            table,
-            params.get_features_col(),
-            params.get_label_col(),
-            weight_col,
-            validate_binomial,
-        )
-        coeff, loss, epochs, _ = optimizer.optimize_stream(None, chunks, loss_func)
-        return coeff, loss, epochs
-    X, y, w = extract_train_data(
-        table, params.get_features_col(), params.get_label_col(), weight_col,
-        keep_sparse=True,
-    )
-    validate_on_device = False
-    if validate_binomial:
-        if isinstance(y, jax.Array):
-            # device labels: the {0,1} validity check is computed INSIDE the
-            # training program and read back fused with the packed training
-            # result — a standalone bool() here would cost its own host
-            # round trip before training even starts
-            validate_on_device = True
-        else:
-            validate_binomial_labels(y)
-    if isinstance(X, tuple):  # sparse: train on padded CSR, no densify
-        indices, values, dim = X
-        X = (indices, values)
-        loss_func = sparse_variant(loss_func.name)
-        init_coeff = np.zeros(dim, dtype=np.float64)
-        # a mesh with a model axis declares the feature-sharded intent:
-        # wide sparse estimator fits take the 2D (data × model) layout
-        # automatically (coeff + optimizer carries as model-axis slices,
-        # see ops.optimizer.SGD._use_2d / docs/performance.md "2D mesh")
-        from ..parallel import mesh as mesh_lib
-
-        optimizer.shard_features = (
-            mesh_lib.MODEL_AXIS in mesh_lib.default_mesh().axis_names
-        )
-    else:
-        init_coeff = np.zeros(X.shape[1], dtype=np.float64)
-    result = optimizer.optimize_async(
-        init_coeff, X, y, w, loss_func, validate_labels=validate_on_device
-    )
-    flag_val, coeff, criteria, epochs = read_train_result(result)
-    _raise_if_invalid(flag_val)
-    return coeff, criteria, epochs
 
 
 @lazy_jit

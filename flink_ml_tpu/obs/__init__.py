@@ -13,7 +13,9 @@ Three layers:
   (`FLINK_ML_TPU_TRACE_FILE`) or an in-memory ring buffer
   (`FLINK_ML_TPU_TRACE_RING`), and aggregated into `metrics.snapshot()`.
   The no-op path (no sink configured) is a shared singleton context
-  manager — cheap enough to stay always-on.
+  manager — cheap enough to stay always-on. `tracing.phase` is the
+  always-counted form for the few phases of a fit: counters without a
+  sink, `fml.*` host events in any `jax.profiler` trace.
 - `timeline` — the flight recorder: a bounded lock-cheap ring of
   begin/end events (`FLINK_ML_TPU_TIMELINE_RING` /
   `FLINK_ML_TPU_TIMELINE_FILE`) with thread + logical-stream lanes,
@@ -25,7 +27,9 @@ Three layers:
 - `exporters` — render `metrics.snapshot()` (and the histogram
   registry) as JSON or Prometheus text, with a name-collision check.
 - `report` — reduce a JSONL trace to per-stage / per-epoch time-breakdown
-  tables with category accounting (see `scripts/obs_report.py`).
+  tables with category accounting, and a `jax.profiler` trace to the
+  device's busy and idle time with the idle time by fit phase (see
+  `scripts/obs_report.py`).
 
 See docs/observability.md for the full surface and a worked example.
 """
@@ -40,6 +44,7 @@ from .tracing import (  # noqa: F401
     enabled,
     event,
     install_jax_hooks,
+    phase,
     set_dispatch_depth,
     span,
 )

@@ -24,6 +24,8 @@ import json
 from collections import OrderedDict
 from typing import Dict, Iterable, List, Optional, Tuple
 
+from .tracing import PHASE_PREFIX
+
 CATEGORIES = ("collective", "readback", "compile", "cache")
 _STAGE_NAMES = ("pipeline.stage", "stage.fit", "stage.transform")
 
@@ -409,32 +411,212 @@ def render_report(records: List[Dict], max_epochs: int = 20) -> str:
     return "\n\n".join(sections)
 
 
-def render_device_profile(path: str) -> str:
-    """Cross-reference a jax.profiler device trace (traceprof.analyze_trace)
-    against the host-side span accounting."""
+# ---------------------------------------------------------------------------
+# device profile: busy, idle, and what the host was doing in each idle gap
+# ---------------------------------------------------------------------------
+
+_DEVICE_PLANE = "/device:"
+_HOST_PLANE = "/host:CPU"
+_DEVICE_LINES = ("XLA Modules", "XLA Ops")
+
+
+def load_device_profile(path: str) -> Dict:
+    """A jax.profiler trace in plain form:
+    {"planes": [{"name", "lines": [{"name", "events": [[name, start_ns, dur_ns]]}]}]}
+    holding each device plane's module and op lines and the host's `fml.*`
+    phase annotations. `path` is a profiler log dir (its newest
+    `.xplane.pb`), one such file, or a `.json` file already in this form."""
     import glob
     import os
 
-    from ..utils.traceprof import analyze_trace
-
     if os.path.isdir(path):
-        candidates = sorted(
-            glob.glob(
-                os.path.join(path, "plugins", "profile", "*", "*.trace.json.gz")
-            )
+        found = sorted(
+            glob.glob(os.path.join(path, "plugins", "profile", "*", "*.xplane.pb"))
         )
-        if not candidates:
-            return f"== Device profile ==\n(no *.trace.json.gz under {path})"
-        path = candidates[-1]
-    stats = analyze_trace(path)
-    lines = [
-        f"deviceBusyMs: {stats['deviceBusyMs']:.1f}",
-        f"moduleExecutions: {stats['numModuleExecutions']}",
-        f"hbmBytesAccessed: {stats['hbmBytesAccessed']}",
+        if not found:
+            raise FileNotFoundError(f"no *.xplane.pb under {path}")
+        path = found[-1]
+    if path.endswith(".json"):
+        with open(path) as f:
+            return json.load(f)
+    from jax.profiler import ProfileData
+
+    planes = []
+    for plane in ProfileData.from_file(path).planes:
+        entry = {"name": plane.name}
+        if plane.name.startswith(_DEVICE_PLANE):
+            entry["lines"] = [
+                {
+                    "name": line.name,
+                    "events": [[e.name, e.start_ns, e.duration_ns] for e in line.events],
+                }
+                for line in plane.lines
+                if line.name in _DEVICE_LINES
+            ]
+        elif plane.name == _HOST_PLANE:
+            events = [
+                [e.name, e.start_ns, e.duration_ns]
+                for line in plane.lines
+                for e in line.events
+                if e.name.startswith(PHASE_PREFIX)
+            ]
+            entry["lines"] = [{"name": "phases", "events": events}]
+        else:
+            continue
+        planes.append(entry)
+    return {"planes": planes}
+
+
+def _union(intervals) -> List[Tuple[float, float]]:
+    """Sorted, disjoint intervals covering the same points."""
+    out: List[Tuple[float, float]] = []
+    for start, end in sorted(intervals):
+        if out and start <= out[-1][1]:
+            out[-1] = (out[-1][0], max(out[-1][1], end))
+        else:
+            out.append((start, end))
+    return out
+
+
+def _innermost(phases) -> List[Tuple[float, float, str]]:
+    """Nested (start, end, name) phases cut into sorted, disjoint pieces,
+    each named by the innermost phase over it."""
+    out: List[Tuple[float, float, str]] = []
+    stack: List[Tuple[float, str]] = []  # (end, name), outermost first
+    cursor = 0.0
+
+    def emit(upto: float) -> float:
+        if stack and upto > cursor:
+            out.append((cursor, upto, stack[-1][1]))
+        return max(cursor, upto)
+
+    for start, end, name in sorted(phases, key=lambda p: (p[0], -p[1])):
+        while stack and stack[-1][0] <= start:
+            cursor = emit(stack[-1][0])
+            stack.pop()
+        cursor = emit(start) if stack else start
+        stack.append((end, name))
+    while stack:
+        cursor = emit(stack[-1][0])
+        stack.pop()
+    return out
+
+
+def _idle_by_phase(gaps, pieces) -> Dict[str, float]:
+    """Seconds of the sorted, disjoint idle `gaps` under each of the sorted,
+    disjoint `(start, end, phase)` pieces; `outside` where none lies."""
+    idle: Dict[str, float] = {}
+    j = 0
+    for start, end in gaps:
+        while j < len(pieces) and pieces[j][1] <= start:
+            j += 1
+        named, k = 0.0, j
+        while k < len(pieces) and pieces[k][0] < end:
+            a, b, phase = pieces[k]
+            part = min(b, end) - max(a, start)
+            idle[phase] = idle.get(phase, 0.0) + part / 1e9
+            named += part
+            k += 1
+        if end - start > named:
+            idle["outside"] = idle.get("outside", 0.0) + (end - start - named) / 1e9
+    return idle
+
+
+def reduce_device_profile(profile: Dict) -> Optional[Dict]:
+    """Busy and idle seconds of the busiest device over the profile's
+    window (first `fml.*` phase's start to the last one's end; with no
+    phase, first to last device event), device seconds by program, and the
+    idle seconds by the innermost phase over each part of each gap
+    (`outside` where the host was in none). None without a device plane."""
+    phases = [
+        (start, start + dur, name[len(PHASE_PREFIX):])
+        for plane in profile["planes"] if plane["name"] == _HOST_PLANE
+        for line in plane["lines"]
+        for name, start, dur in line["events"]
+        if name.startswith(PHASE_PREFIX)
     ]
-    cats = stats.get("byCategory", {})
-    if cats:
-        lines.append("top HLO categories: " + ", ".join(
-            f"{k} {v['durUs'] / 1000.0:.1f}ms" for k, v in list(cats.items())[:5]
-        ))
-    return "== Device profile (" + path + ") ==\n" + "\n".join(lines)
+    devices = []  # (plane, its events by line)
+    for plane in profile["planes"]:
+        if plane["name"].startswith(_DEVICE_PLANE):
+            lines = {line["name"]: line["events"] for line in plane["lines"]}
+            if any(lines.get(name) for name in _DEVICE_LINES):
+                devices.append((plane, lines))
+    if not devices:
+        return None
+    events_of = lambda lines: [e for name in _DEVICE_LINES for e in lines.get(name, [])]
+    if phases:
+        lo, hi = min(p[0] for p in phases), max(p[1] for p in phases)
+    else:
+        lo = min(e[1] for _, lines in devices for e in events_of(lines))
+        hi = max(e[1] + e[2] for _, lines in devices for e in events_of(lines))
+    inside = lambda events: [e for e in events if e[1] + e[2] > lo and e[1] < hi]
+    busy, busy_ns = [], -1.0
+    for candidate, candidate_lines in devices:  # the busiest device decides
+        intervals = [
+            (max(s, lo), min(e, hi))
+            for s, e in _union((s, s + d) for _, s, d in inside(events_of(candidate_lines)))
+        ]
+        total = sum(e - s for s, e in intervals)
+        if total > busy_ns:
+            busy, busy_ns, plane, lines = intervals, total, candidate, candidate_lines
+    modules = inside(lines.get(_DEVICE_LINES[0], []))
+    gaps, cursor = [], lo
+    for start, end in busy:
+        if start > cursor:
+            gaps.append((cursor, start))
+        cursor = end
+    if cursor < hi:
+        gaps.append((cursor, hi))
+    programs: Dict[str, float] = {}
+    for module, _, dur in modules:
+        module = module.split("(", 1)[0]  # jit_f(1234) -> jit_f
+        programs[module] = programs.get(module, 0.0) + dur / 1e9
+    busy_s = busy_ns / 1e9
+    return {
+        "device": plane["name"],
+        "devices": len(devices),
+        "windowS": (hi - lo) / 1e9,
+        "busyS": busy_s,
+        "idleS": (hi - lo) / 1e9 - busy_s,
+        "programsS": programs,
+        "idleByPhaseS": _idle_by_phase(gaps, _innermost(phases)),
+    }
+
+
+def render_device_profile(path: str) -> str:
+    """The operator's view of a jax.profiler trace of one or more fits: for
+    the busiest device, busy and idle time, device seconds by program, and
+    the idle time by what the program says the host was doing in it."""
+    head = f"== Device profile ({path}) =="
+    try:
+        stats = reduce_device_profile(load_device_profile(path))
+    except FileNotFoundError as e:
+        return f"{head}\n({e})"
+    if stats is None:
+        return f"{head}\n(no device plane in the profile)"
+    window = stats["windowS"]
+
+    def rows(seconds: Dict[str, float], of: float) -> List[List[str]]:
+        """Largest first (the dict's own order where two are equal)."""
+        return [
+            [k, f"{v:.6f}", f"{100.0 * v / of:.1f}%" if of > 0 else "-"]
+            for k, v in sorted(seconds.items(), key=lambda kv: -kv[1])
+        ]
+
+    busy, idle = stats["busyS"], stats["idleS"]
+    sections = [
+        head,
+        f"{stats['device']} (busiest of {stats['devices']}), window {window:.6f} s",
+        f"busy {busy:.6f} s ({100.0 * busy / window:.1f}%), "
+        f"idle {idle:.6f} s ({100.0 * idle / window:.1f}%)",
+        "Device seconds by program:\n"
+        + _table(["program", "seconds", "of window"], rows(stats["programsS"], window)),
+    ]
+    sections.append(
+        "Idle seconds by phase (innermost fml.* phase over each part of a gap):\n"
+        + _table(
+            ["phase", "seconds", "of idle"],
+            rows(stats["idleByPhaseS"], stats["idleS"]),
+        )
+    )
+    return "\n\n".join(sections)

@@ -21,6 +21,13 @@ instrumented hot layers rely on (bounded by a micro-benchmark test).
 Completed spans are also folded into the flat `utils.metrics` registry
 (`span.<name>` timers), so `metrics.snapshot()` keeps working as the one
 aggregate view.
+
+A few phases a fit (`phase()`: `fit.total`, `fit.extract`, `fit.stage` and
+`fit.layout` inside it, `fit.launch`, `fit.readback`) are spans that do not
+wait for a sink: always counted (`<name>.ns`, `<name>.n`), and `fml.<name>`
+host events of whatever `jax.profiler` trace is being taken, so that an idle
+gap of the device can be named by what the host was doing in it
+(`report.render_device_profile`).
 """
 
 from __future__ import annotations
@@ -134,6 +141,7 @@ _NOOP = _NoopSpan()
 
 class Span:
     __slots__ = ("name", "attrs", "span_id", "parent_id", "_start_ns", "_token")
+    _marks = True  # begin and end marks on the timeline's host lane
 
     def __init__(self, name: str, attrs: Dict[str, Any]):
         self.name = name
@@ -151,19 +159,22 @@ class Span:
         self.parent_id = parent.span_id if parent is not None else 0
         self.span_id = next(_ids)
         self._token = _current.set(self)
-        if timeline.enabled():  # flight recorder: a live begin mark
+        if self._marks and timeline.enabled():  # flight recorder: a live begin mark
             timeline.record_begin(timeline.host_lane(), self.name, ref=self.span_id)
         self._start_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        end_ns = time.perf_counter_ns()
+        self._close(time.perf_counter_ns(), exc_type)
+        return False
+
+    def _close(self, end_ns: int, exc_type) -> None:
         _current.reset(self._token)
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
         dur_ns = end_ns - self._start_ns
         metrics.record_time("span." + self.name, dur_ns / 1e9)
-        if timeline.enabled():
+        if self._marks and timeline.enabled():
             timeline.record_end(
                 timeline.host_lane(), self.name, ref=self.span_id, **self.attrs
             )
@@ -177,7 +188,67 @@ class Span:
                 "attrs": self.attrs,
             }
         )
+
+
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, bound by the first phase
+PHASE_PREFIX = "fml."  # not "perf.": that prefix is the benchmark's own
+
+
+class Phase(Span):
+    """One of the few phases a fit is split into (`fit.extract`, `fit.stage`
+    with `fit.layout` inside it, `fit.launch`, `fit.readback`, all inside
+    `fit.total`): once a fit on the whole-fit routes, and never per epoch,
+    batch or request, where `span` keeps its no-op path. (`fit.launch` is
+    the dispatch funnel's, so the chunked routes, stream, checkpointed and
+    iteration, count one a chunk.) With one pair of clock reads it is
+    counted whether or not anything listens (`<name>.ns` and `<name>.n` in
+    `utils.metrics`), lies on the host plane of any profile being taken as
+    `fml.<name>`, on the device planes' clock, and is an ordinary span
+    record where a sink is configured. `dur_ns` and `start_ns` hold the
+    reads, for a call site that keeps a timer of its own; one that also puts
+    an event of its own on the timeline passes `marks=False`, and the
+    phase leaves its begin and end marks off the host lane."""
+
+    __slots__ = ("dur_ns", "_annotation", "_sunk", "_marks")
+
+    def __init__(self, name: str, marks: bool = True):
+        self.name = name
+        self.attrs = {}
+        self._marks = marks
+
+    @property
+    def start_ns(self) -> int:
+        return self._start_ns
+
+    def __enter__(self):
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            from jax.profiler import TraceAnnotation as _TraceAnnotation
+        # an annotation only while a profile is being taken: asking costs a
+        # tenth of making one that nothing records
+        self._annotation = None
+        if _TraceAnnotation.is_enabled():
+            self._annotation = _TraceAnnotation(PHASE_PREFIX + self.name)
+            self._annotation.__enter__()
+        self._sunk = _enabled
+        if self._sunk:
+            return Span.__enter__(self)
+        self._start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        end_ns = time.perf_counter_ns()
+        self.dur_ns = end_ns - self._start_ns
+        metrics.inc_counter(self.name + ".ns", self.dur_ns)
+        metrics.inc_counter(self.name + ".n")
+        if self._sunk:
+            self._close(end_ns, exc_type)
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         return False
+
+
+phase = Phase  # spelled like `span`
 
 
 def span(name: str, **attrs):
@@ -386,9 +457,10 @@ def _wrap_stage_method(fn, op: str):
     @functools.wraps(fn)
     def wrapper(self, *args, **kwargs):
         if op == "fit":
-            # per-fit HBM watermark (hbm.peak.fit) — always on, like the
-            # metrics registry: two dict ops per fit, no sink required
-            with memledger.fit_peak_scope():
+            # the fit.total phase and the per-fit HBM watermark
+            # (hbm.peak.fit) are always on, like the metrics registry:
+            # no sink required
+            with Phase("fit.total"), memledger.fit_peak_scope():
                 if not _enabled:
                     return fn(self, *args, **kwargs)
                 with Span("stage." + op, {"stage": type(self).__name__}):

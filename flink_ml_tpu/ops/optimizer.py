@@ -39,6 +39,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs import tracing
 from ..parallel import mesh as mesh_lib
 from ..parallel import prefetch as h2d
 from ..utils.lazyjit import lazy_jit
@@ -656,10 +657,6 @@ def read_train_result(async_result):
     """Materialize an `optimize_async` result on the host in one transfer.
     Returns (flag_or_None, coeff[:d], criteria, epochs); the checkpointed
     host-driven path passes its host values through unchanged."""
-    import time
-
-    from ..obs import tracing
-
     if async_result[0] == "host":  # checkpointed host-driven path
         _, coeff, criteria, epochs, flag, d = async_result
         # tpulint: disable=host-sync-leak -- host-driven branch: coeff is already host numpy here, the copy is free
@@ -671,22 +668,25 @@ def read_train_result(async_result):
         # ONE device_get of the model-sharded pack (per-shard block =
         # [flag?, coeff_slice, criteria, epochs]) — no device hops a full
         # replicated result vector, matching the sharded residency story
-        t0 = time.perf_counter()
-        host = np.asarray(jax.device_get(packed))
-        tracing.account_host_sync("fit")
-        tracing.account_readback(host.nbytes, time.perf_counter() - t0)
         coeff, criteria, epochs, flag = sgd2d_unpack_host(
-            host, nm, d_local, has_flag
+            _read_packed(packed), nm, d_local, has_flag
         )
         return flag, coeff[:d], criteria, epochs
     _, packed, d, has_flag = async_result
-    # explicit device_get: the transfer-guard readback-budget tests run
-    # fits under jax.transfer_guard("disallow") to catch stray implicit pulls
-    t0 = time.perf_counter()
-    host = np.asarray(jax.device_get(packed))
+    return unpack_train_result(_read_packed(packed), d, has_flag=has_flag)
+
+
+def _read_packed(packed) -> np.ndarray:
+    """The fit's one packed readback, as the `fit.readback` phase (it holds
+    the wait for the device): an explicit device_get, because the
+    transfer-guard readback-budget tests run fits under
+    jax.transfer_guard("disallow") to catch stray implicit pulls. On a
+    timeline it is the readback lane's event, from the same clock reads."""
+    with tracing.phase("fit.readback", marks=False) as readback:
+        host = np.asarray(jax.device_get(packed))
     tracing.account_host_sync("fit")
-    tracing.account_readback(host.nbytes, time.perf_counter() - t0)
-    return unpack_train_result(host, d, has_flag=has_flag)
+    tracing.account_readback(host.nbytes, readback.dur_ns / 1e9)
+    return host
 
 
 @dataclass
@@ -820,6 +820,19 @@ class SGD:
         the training program and rides the same transfer. The checkpointed
         path is host-driven in epoch chunks and returns host values
         directly as ("host", coeff, criteria, epochs, flag, true_dim)."""
+        # the host's work up to the launch is the `fit.stage` phase (the
+        # batch layout inside it is `fit.layout`); the launch that follows
+        # is `fit.launch`, in dispatch.timed_dispatch
+        with tracing.phase("fit.stage"):
+            launch = self._stage_async(
+                init_coeff, X, y, weights, loss_func, mesh, validate_labels
+            )
+        return launch()
+
+    def _stage_async(self, init_coeff, X, y, weights, loss_func, mesh, validate_labels):
+        """`optimize_async` up to the launch: picks the route, stages its
+        inputs, and returns the launch itself as a call without arguments
+        that gives the async handle."""
         mesh = mesh or mesh_lib.default_mesh()
         # the model length is the feature dim — X may be sparse (indices,
         # values), whose second axis is the nnz width, not the dim
@@ -837,7 +850,8 @@ class SGD:
             from ..parallel import overlap
 
             X_b, y_b, w_b = self._batchify(mesh, X, y, weights)
-            packed = dispatch.timed_dispatch(
+            launch = partial(
+                dispatch.timed_dispatch,
                 overlap.overlapped_sgd_train,
                 mesh,
                 X_b,
@@ -849,16 +863,16 @@ class SGD:
                 validate_labels,
                 start=0, end=self.max_iter,
             )
-            return ("packed", packed, d, validate_labels)
+            return lambda: ("packed", launch(), d, validate_labels)
         if (
             not self.shard_features
             and self.checkpoint_dir is None
             and mesh_lib.num_data_shards(mesh) == 1
         ):
-            packed = self._optimize_flat_async(
+            launch = self._stage_flat(
                 mesh, init_coeff, X, y, weights, loss_func, validate_labels
             )
-            return ("packed", packed, d, validate_labels)
+            return lambda: ("packed", launch(), d, validate_labels)
         if self.shard_features:
             # zero-pad the feature dim to divide over the model axis; padded
             # coefficients start 0, get zero gradients, and stay 0
@@ -875,13 +889,17 @@ class SGD:
                 init, mesh_lib.model_sharding(mesh), category="optimizer"
             )
         if self.checkpoint_dir is not None:
-            coeff, criteria, epochs = self._optimize_with_checkpoints(
-                X_b, y_b, w_b, init, loss_func, mesh
-            )
-            flag = None
-            if validate_labels:
-                flag = float(jax.device_get(_binomial_labels_ok(y_b)))
-            return ("host", coeff, criteria, epochs, flag, d)
+
+            def host_driven():
+                coeff, criteria, epochs = self._optimize_with_checkpoints(
+                    X_b, y_b, w_b, init, loss_func, mesh
+                )
+                flag = None
+                if validate_labels:
+                    flag = float(jax.device_get(_binomial_labels_ok(y_b)))
+                return ("host", coeff, criteria, epochs, flag, d)
+
+            return host_driven
         if self._use_2d(mesh, loss_func) and isinstance(X_b, tuple):
             from ..parallel import overlap
 
@@ -891,7 +909,8 @@ class SGD:
                 jnp.asarray(0.0, self.dtype),
                 jnp.asarray(0, jnp.int32),
             )
-            _, _, packed = dispatch.timed_dispatch(
+            launch = partial(
+                dispatch.timed_dispatch,
                 overlap.sgd2d_whole_fit,
                 mesh, X_b, y_b, w_b, carry,
                 jnp.asarray(np.inf, jnp.float32),
@@ -899,8 +918,11 @@ class SGD:
                 start=0, end=self.max_iter,
             )
             nm = mesh_lib.num_model_shards(mesh)
-            return ("packed2d", packed, d, validate_labels, nm, d_pad // nm)
-        packed = dispatch.timed_dispatch(
+            return lambda: (
+                "packed2d", launch()[2], d, validate_labels, nm, d_pad // nm
+            )
+        launch = partial(
+            dispatch.timed_dispatch,
             _sgd_train,
             X_b,
             y_b,
@@ -912,7 +934,7 @@ class SGD:
             self._pack_sharding(mesh),
             start=0, end=self.max_iter,
         )
-        return ("packed", packed, d, validate_labels)
+        return lambda: ("packed", launch(), d, validate_labels)
 
     def optimize_stream(
         self,
@@ -1102,7 +1124,6 @@ class SGD:
         from .. import config
         from ..ckpt import faults
         from ..data.devicecache import CachedEpochLoader
-        from ..obs import tracing
         from ..parallel import dispatch
         from ..utils.packing import packed_device_get
 
@@ -1238,7 +1259,6 @@ class SGD:
         construction (pinned in tests/test_dispatch_pipeline.py)."""
         from .. import config
         from ..ckpt import faults
-        from ..obs import tracing
         from ..parallel import dispatch
         from ..utils.packing import packed_device_get
 
@@ -1299,13 +1319,14 @@ class SGD:
         }
         return np.asarray(coeff_h), final_crit, final_epoch, stats
 
-    def _optimize_flat_async(self, mesh, init_coeff, X, y, weights, loss_func, validate_labels):
-        """Single-data-shard dispatch: no batched re-layout, no weights
+    def _stage_flat(self, mesh, init_coeff, X, y, weights, loss_func, validate_labels):
+        """Single-data-shard staging: no batched re-layout, no weights
         synthesis program — see `_sgd_train_flat`. Ragged row counts are
         padded to a batch multiple (the only case that copies). Host inputs
         are placed on the mesh's device (a 1-device mesh may deliberately
         pin a fit to a non-default chip); already-device-resident inputs
-        stay where they are. Returns the packed result device vector."""
+        stay where they are. Returns the launch, a call without arguments
+        that gives the packed result device vector."""
         n = int(np.shape(X[0] if isinstance(X, tuple) else X)[0])
         B = int(self.global_batch_size)
         num_batches = max(1, -(-n // B))
@@ -1353,7 +1374,8 @@ class SGD:
         memledger.track((X_f, y_f, w_f), "streamSegments")
         from ..parallel import dispatch
 
-        return dispatch.timed_dispatch(
+        return partial(
+            dispatch.timed_dispatch,
             _sgd_train_flat,
             X_f,
             y_f,
@@ -1389,7 +1411,6 @@ class SGD:
         from .. import config
         from ..ckpt import faults
         from ..ckpt import snapshot as _snapshot
-        from ..obs import tracing
         from ..parallel import dispatch
         from ..utils.packing import packed_device_get
 
@@ -1566,6 +1587,12 @@ class SGD:
         return np.asarray(coeff_h), final_crit, final_epoch
 
     def _batchify(self, mesh: Mesh, X, y, weights, d_pad=None, replicate_data=False):
+        """`_lay_out` as the `fit.layout` phase: the host's time to cast,
+        stage and enqueue the layout programs, not the device's to run them."""
+        with tracing.phase("fit.layout"):
+            return self._lay_out(mesh, X, y, weights, d_pad, replicate_data)
+
+    def _lay_out(self, mesh: Mesh, X, y, weights, d_pad, replicate_data):
         """Stage data into device-resident (num_batches, padded_batch, ...)
         arrays sharded over the data axis.
 

@@ -231,7 +231,9 @@ def timed_dispatch(step: Callable, *args, start: int = None, end: int = None):
     """THE accounted chunk-dispatch funnel: every chunk program launch in
     the iteration runtime rides through here, so the host-side dispatch
     cost is one timer (`iteration.dispatch` — the `hostDispatchMs` BENCH
-    field) and one timeline `dispatch`-lane event, and the dispatch-wall
+    field), the always-counted `fit.launch` phase (`fml.fit.launch` in a
+    profile; one a chunk where a fit is launched in chunks) and one
+    timeline `dispatch`-lane event, and the dispatch-wall
     attribution (`obs.timeline.dispatch_attribution`) can split every
     fit's wall into dispatch + device + readback + idle-gap. On an async
     backend this times the enqueue; on CPU, the synchronous execution —
@@ -245,20 +247,23 @@ def timed_dispatch(step: Callable, *args, start: int = None, end: int = None):
     from . import supervisor
 
     supervisor.pulse_boundary(supervisor.PHASE_DISPATCH)
-    t0 = time.perf_counter_ns()
-    try:
-        out = step(*args)
-    except Exception as e:
-        # a backend RESOURCE_EXHAUSTED surfacing from the launch becomes
-        # the typed HbmExhausted carrying the ranked ledger snapshot —
-        # the OOM names who holds the memory, not just that it ran out
-        from ..obs import memledger
+    # the launch is also the always-counted `fit.launch` phase: one pair of
+    # clock reads feeds the phase, the timer and the timeline's one event
+    # of it (the dispatch lane's, below: the phase marks no host lane)
+    with tracing.phase("fit.launch", marks=False) as launch:
+        try:
+            out = step(*args)
+        except Exception as e:
+            # a backend RESOURCE_EXHAUSTED surfacing from the launch becomes
+            # the typed HbmExhausted carrying the ranked ledger snapshot —
+            # the OOM names who holds the memory, not just that it ran out
+            from ..obs import memledger
 
-        wrapped = memledger.wrap_oom(e)
-        if wrapped is not None:
-            raise wrapped from e
-        raise
-    dur_ns = time.perf_counter_ns() - t0
+            wrapped = memledger.wrap_oom(e)
+            if wrapped is not None:
+                raise wrapped from e
+            raise
+    t0, dur_ns = launch.start_ns, launch.dur_ns
     metrics.record_time("iteration.dispatch", dur_ns / 1e9)
     supervisor.note_progress(dur_ns / 1e9)
     if timeline.enabled():

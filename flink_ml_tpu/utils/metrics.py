@@ -26,6 +26,8 @@ import time
 from contextlib import contextmanager
 from typing import Dict, List
 
+# [count, total seconds, last seconds] a name: a server records a time with
+# every fit and every readback for as long as it lives
 _timers: Dict[str, List[float]] = {}
 _gauges: Dict[str, float] = {}
 _counters: Dict[str, int] = {}
@@ -38,11 +40,17 @@ def timed(name: str):
     try:
         yield
     finally:
-        _timers.setdefault(name, []).append(time.perf_counter() - start)
+        record_time(name, time.perf_counter() - start)
 
 
 def record_time(name: str, seconds: float) -> None:
-    _timers.setdefault(name, []).append(seconds)
+    stats = _timers.get(name)
+    if stats is None:
+        _timers[name] = [1, seconds, seconds]
+    else:
+        stats[0] += 1
+        stats[1] += seconds
+        stats[2] = seconds
 
 
 def set_gauge(name: str, value: float) -> None:
@@ -63,7 +71,7 @@ def get_counter(name: str, default: int = 0) -> int:
 
 def timer_totals() -> Dict[str, float]:
     """Total seconds per phase."""
-    return {k: float(sum(v)) for k, v in _timers.items()}
+    return {k: float(v[1]) for k, v in _timers.items()}
 
 
 def snapshot() -> Dict[str, Dict]:
@@ -71,11 +79,7 @@ def snapshot() -> Dict[str, Dict]:
     gauges, counters."""
     return {
         "timers": {
-            k: {
-                "count": len(v),
-                "totalMs": sum(v) * 1000.0,
-                "lastMs": v[-1] * 1000.0,
-            }
+            k: {"count": v[0], "totalMs": v[1] * 1000.0, "lastMs": v[2] * 1000.0}
             for k, v in _timers.items()
         },
         "gauges": dict(_gauges),

@@ -4,12 +4,18 @@
 Usage:
     python scripts/obs_report.py TRACE.jsonl [options]
     python scripts/obs_report.py --hbm-dump DUMP.json
+    python scripts/obs_report.py --device-profile PROFILE_DIR
 
 Options:
-    --device-profile PATH   Cross-reference a jax.profiler trace (a
-                            profiler log dir or a *.trace.json.gz file)
-                            via traceprof.analyze_trace — device-busy time
-                            vs the host-side span accounting.
+    --device-profile PATH   Reduce a jax.profiler trace (a profiler log
+                            dir or one *.xplane.pb) taken around one or
+                            more fits: busy and idle time of the busiest
+                            device, device seconds by program, and the
+                            idle time by the `fml.*` phase (fit.extract,
+                            fit.stage, fit.layout, fit.launch,
+                            fit.readback, fit.total) the host was in.
+                            Works standalone (no trace file) or
+                            alongside one.
     --hbm-dump PATH         Render an HBM forensic dump (the JSON an
                             `HbmExhausted` writes when
                             FLINK_ML_TPU_HBM_DUMP is set, or any
@@ -96,6 +102,9 @@ def main(argv):
         if argv[0] == "--hbm-dump":  # standalone mode, no trace to render
             return 0
         print()
+    if argv[0] == "--device-profile":  # standalone mode, no trace to render
+        print(report.render_device_profile(argv[1]))
+        return 0
     trace_path = argv[0]
     max_epochs = 20
     if "--max-epochs" in argv:

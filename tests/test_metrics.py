@@ -26,6 +26,24 @@ def test_timed_accumulates():
     assert metrics.timer_totals()["phase.a"] >= 0.0
 
 
+def test_a_timer_does_not_grow_with_its_records():
+    """A server records a time with every fit and readback for as long as
+    it lives: a name keeps count, total and last, and the views are as
+    before."""
+    for i in range(1000):
+        metrics.record_time("phase.b", 0.001 * (i + 1))
+    assert len(metrics._timers["phase.b"]) == 3
+    stats = metrics.snapshot()["timers"]["phase.b"]
+    assert stats["count"] == 1000
+    assert stats["totalMs"] == pytest.approx(500500.0)
+    assert stats["lastMs"] == pytest.approx(1000.0)
+    assert metrics.timer_totals()["phase.b"] == pytest.approx(500.5)
+    before = metrics.snapshot()
+    metrics.record_time("phase.b", 0.25)
+    delta = metrics.snapshot_delta(before, metrics.snapshot())["timers"]["phase.b"]
+    assert delta == {"count": 1, "totalMs": pytest.approx(250.0), "lastMs": pytest.approx(250.0)}
+
+
 def test_gauges_and_counters():
     metrics.set_gauge("g", 7.5)
     metrics.inc_counter("c")
