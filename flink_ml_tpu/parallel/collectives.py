@@ -204,6 +204,25 @@ def all_gather(x, axis_name: str = DATA_AXIS, axis: int = 0, tiled: bool = True)
     return lax.all_gather(x, axis_name, axis=axis, tiled=tiled)
 
 
+def all_to_all(x, mesh: Mesh, src_axis: int, dst_axis: int, axis_name: str = DATA_AXIS):
+    """Exchange over a whole (global) array inside a jitted program: `x`
+    comes sharded over the mesh's `axis_name` on `src_axis` and leaves
+    sharded on `dst_axis`, so every participant sends piece j of what it
+    holds to participant j — the reference's partitionCustom shuffle as one
+    hardware collective (the batch layout of a row-sharded table,
+    ops/optimizer.py). Asked of XLA's partitioner by a sharding constraint
+    and not through `lax.all_to_all` under `shard_map`: the collective is
+    the same, but the partitioner's carries XLA's own name (`all-to-all`) in
+    a device trace, where readers of collective time look for it; the
+    traced primitive's is named `all_to_all.N`."""
+    local = list(x.shape)
+    local[src_axis] //= mesh.shape[axis_name]
+    _account("all_to_all", jax.ShapeDtypeStruct(tuple(local), x.dtype), axis_name)
+    spec = [None] * x.ndim
+    spec[dst_axis] = axis_name
+    return lax.with_sharding_constraint(x, NamedSharding(mesh, P(*spec)))
+
+
 def reduce_scatter(x, axis_name: str = DATA_AXIS, scatter_dimension: int = 0):
     _account("psum_scatter", x, axis_name)
     return lax.psum_scatter(x, axis_name, scatter_dimension=scatter_dimension, tiled=True)

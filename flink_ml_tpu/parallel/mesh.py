@@ -219,6 +219,24 @@ def replicate(mesh: Mesh, array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
+# how a device keeps an array (read by the batch layout's exchange,
+# ops/optimizer.py)
+# ---------------------------------------------------------------------------
+# the TPU's tile over the two minor axes of an array of a 32-bit type:
+# 8 sublanes by 128 lanes (narrower types pack along the sublanes)
+SUBLANES, LANES = 8, 128
+
+
+def rows_minor(arr: jax.Array) -> bool:
+    """Whether the device keeps a table's rows on its minor-most axis: the
+    TPU's layout for a table narrower than a tile's 128 lanes (the
+    reference's 100 features, a padded-CSR leaf), where a row is no
+    contiguous run of memory and a reshape that splits the rows is not
+    free. Read off the array; the CPU and a wide table keep rows major."""
+    return arr.ndim == 2 and arr.format.layout.major_to_minor[-1] == 0
+
+
+# ---------------------------------------------------------------------------
 # host-group mapping (multi-host snapshot coordination, ckpt/coordinator.py)
 # ---------------------------------------------------------------------------
 # On real DCN hardware `jax.devices()` spans processes and each host owns a
