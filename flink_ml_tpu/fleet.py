@@ -592,7 +592,7 @@ class FitFleet:
         row_sharding = NamedSharding(mesh, P() if sharded else P(mesh_lib.DATA_AXIS))
         X_pad, _ = mesh_lib.pad_to_multiple(X_host, shards)
         X_dev = h2d.stage_to_device(X_pad, mat_sharding)
-        w_dev = km._unit_weights(n, n_pad, row_sharding)
+        w_dev = km._unit_weights(n, n_pad, row_sharding) if n_pad != n else None
         init_spec = (
             mesh_lib.fleet_sharding(mesh, 3) if sharded
             else mesh_lib.replicated_sharding(mesh)
@@ -608,6 +608,7 @@ class FitFleet:
                 km._lloyd_fleet_train,
                 X_dev, w_dev, inits_dev, jnp.asarray(max_iters), measure,
                 self._pack_sharding(mesh),
+                mesh if shards > 1 else None,  # the rows' mesh (_lloyd_partials)
                 start=0, end=gmax,
             )
             (host,) = packed_device_get(packed, sync_kind="fit")

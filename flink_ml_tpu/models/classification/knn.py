@@ -18,6 +18,7 @@ import numpy as np
 
 from ...api import Estimator, Model
 from ...common.param import HasFeaturesCol, HasLabelCol, HasPredictionCol
+from ...ops.distance import cross_term
 from ...param import IntParam, ParamValidators
 from ...table import Table, as_dense_matrix
 from ...utils import read_write
@@ -51,7 +52,7 @@ def _top_k_indices(X_test, X_train, k):
     """Squared-euclidean pairwise distances -> top-k neighbor indices."""
     t2 = jnp.sum(X_test * X_test, axis=1, keepdims=True)
     r2 = jnp.sum(X_train * X_train, axis=1)[None, :]
-    dists = t2 - 2.0 * (X_test @ X_train.T) + r2
+    dists = t2 - 2.0 * cross_term(X_test, X_train) + r2
     _, idx = jax.lax.top_k(-dists, k)  # (n_test, k)
     return idx
 

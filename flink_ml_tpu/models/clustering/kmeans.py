@@ -5,7 +5,7 @@ KMeansModel.java and KMeansModelData.java:53-116. The reference's per-epoch
 flow (broadcast centroids -> per-point argmin assignment -> partial sums ->
 countWindowAll(parallelism) funnel reduce -> parallelism-1 centroid update,
 KMeans.java:135-212) becomes one jitted while-loop epoch: a pairwise
-distance matmul, a one-hot segment-sum (both MXU work), and a psum over the
+distance matmul on the MXU, a segment-sum of the rows, and a psum over the
 mesh data axis — no funnel-to-one-task bottleneck. Termination is maxIter
 (TerminateOnMaxIter.java:56). Init mirrors selectRandomCentroids
 (KMeans.java:310): sample k distinct rows with the stage seed.
@@ -30,8 +30,9 @@ from ...common.param import (
     HasPredictionCol,
     HasSeed,
 )
-from ...ops.distance import DistanceMeasure, jit_find_closest
+from ...ops.distance import DistanceMeasure, first_minimum, jit_find_closest
 from ...param import IntParam, ParamValidators, StringParam
+from ...parallel import collectives
 from ...parallel import mesh as mesh_lib
 from ...parallel import prefetch as h2d
 from ...table import Table, as_dense_matrix
@@ -65,61 +66,204 @@ class KMeansParams(KMeansModelParams, HasSeed, HasMaxIter):
         return self.set(self.INIT_MODE, value)
 
 
-def _lloyd_train_impl(X, weights, init_centroids, max_iter, measure_name):
-    """The full Lloyd loop as one XLA program; X is (n, d) sharded over the
-    data axis, the segment-sum contraction over n makes XLA reduce over ICI.
-    Data and max_iter are runtime arguments so repeated fits with the same
-    shapes reuse the compiled executable."""
+# One block of rows meets all k centroids as a (block, k) matrix of
+# distances. A block holds at most this many elements of it and of its
+# (block, d) slice of the table, whatever n is.
+_BLOCK_ELEMENTS = 1 << 25
+
+
+def _block_rows(n: int, k: int, d: int) -> int:
+    """Rows of one block of an (n, d) table against k centroids, from the
+    shapes alone: a multiple of 256 that keeps block x (k + d) under
+    `_BLOCK_ELEMENTS`, and never more than n."""
+    return min(n, max(256, _BLOCK_ELEMENTS // (k + d) // 256 * 256))
+
+
+def _num_blocks(n: int, k: int, d: int) -> int:
+    return -(-n // _block_rows(n, k, d))
+
+
+def _block_step(Xb, counted, centroids, measure):
+    """THE Lloyd block step, shared by every KMeans fit: each row of Xb
+    (b, d) is assigned to its closest centroid (the lowest index on a tie),
+    and the rows that `counted` (b,) marks add to the block's (sums (k, d),
+    counts (k,)); a row not counted goes to a segment past the last cluster,
+    which is dropped. The sums are a segment-sum: on the v5e a fit of 2.7M x
+    784 rows against 4,096 centroids took 4.75 s with it and 5.65 s with
+    `one_hot.T @ X` at `HIGHEST` (PERF.md, PR 27), and it adds float32 to
+    float32 with no product to state a precision for."""
+    k = centroids.shape[0]
+    assign = jnp.where(counted, first_minimum(measure.closeness(Xb, centroids)), k)
+    sums = jax.ops.segment_sum(Xb, assign, k + 1)[:k]
+    counts = jax.ops.segment_sum(jnp.ones_like(assign, Xb.dtype), assign, k + 1)[:k]
+    return sums, counts
+
+
+def _one_block(X, w, i, block, centroids, measure):
+    """(sums, counts) of block i of the rows X (n, d), `block` rows each. n
+    need not be a whole number of blocks: the last block is moved back to
+    end at row n, and the rows the block before it has counted are masked
+    out of it. `w` (n,) marks the rows that count at all (shard and bucket
+    padding has weight 0); None counts every row."""
+    n = X.shape[0]
+    if block == n:
+        counted = jnp.ones((n,), bool) if w is None else w > 0
+        return _block_step(X, counted, centroids, measure)
+    start = jnp.minimum(i * block, n - block)
+    Xb = lax.dynamic_slice_in_dim(X, start, block, 0)
+    counted = start + jnp.arange(block) >= i * block
+    if w is not None:
+        counted = counted & (lax.dynamic_slice_in_dim(w, start, block, 0) > 0)
+    return _block_step(Xb, counted, centroids, measure)
+
+
+def _accumulate_batch_impl(X, w, centroids, measure_name):
+    """One pass of the rows held here, X (n, d), over the centroids: the
+    (sums, counts) partials of a Lloyd iteration, block by block
+    (`_block_rows`, `_one_block`), so that nothing of shape (n, k) exists.
+    Each batch of both stream fits and the overlap schedule take it; the
+    in-memory fit and the fleet walk the same blocks in `_lloyd_loop`."""
     measure = DistanceMeasure.get_instance(measure_name)
+    n, d = X.shape
+    k = centroids.shape[0]
+    block = _block_rows(n, k, d)
+    blocks = -(-n // block)
+    if blocks == 1:
+        return _one_block(X, w, 0, block, centroids, measure)
+
+    def add(i, partials):
+        s, c = _one_block(X, w, i, block, centroids, measure)
+        return partials[0] + s, partials[1] + c
+
+    zero = (jnp.zeros((k, d), X.dtype), jnp.zeros((k,), X.dtype))
+    return lax.fori_loop(0, blocks, add, zero)
+
+
+def _on_row_shards(local, mesh, replicated, X, weights):
+    """`local(*replicated, X, w)` run by every shard of the mesh's data axis
+    on its own rows of X and of the weights (w is None where there are
+    none); what it returns has to be the same on every shard."""
+    axis = mesh_lib.DATA_AXIS
+    rows = (X,) if weights is None else (X, weights)
+    specs = (P(),) * len(replicated) + (P(axis, None), P(axis))[: len(rows)]
+
+    def shard(*args):
+        w = args[-1] if weights is not None else None
+        return local(*args[: len(replicated)], args[len(replicated)], w)
+
+    return collectives.shard_map_over(mesh, specs, (P(), P()), fn=shard)(*replicated, *rows)
+
+
+def _lloyd_partials(X, weights, centroids, measure_name, mesh):
+    """(sums, counts) of one pass over a batch of rows. `mesh` is None
+    where every device holds all the rows it is given (one shard, or a
+    replicated table); else the rows are shared over the mesh's data axis,
+    each shard runs the blocks of its own rows and one psum adds them."""
+    if mesh is None:
+        return _accumulate_batch_impl(X, weights, centroids, measure_name)
+
+    def local(centroids, X, w):
+        partials = _accumulate_batch_impl(X, w, centroids, measure_name)
+        return collectives.all_reduce_sum(partials, mesh_lib.DATA_AXIS)
+
+    return _on_row_shards(local, mesh, (centroids,), X, weights)
+
+
+# the host-driven stream loop's program: one batch's partials
+_accumulate_batch = lazy_jit(_lloyd_partials, static_argnames=("measure_name", "mesh"))
+
+
+def _new_centroids(centroids, sums, counts):
+    """The mean of each cluster's rows; an empty cluster keeps its centroid."""
+    return jnp.where(
+        counts[:, None] > 0, sums / jnp.maximum(counts[:, None], 1e-30), centroids
+    )
+
+
+def _lloyd_loop(X, w, init_centroids, max_iter, measure_name, axis):
+    """`max_iter` Lloyd iterations over the rows held here as ONE flat loop:
+    step t is block t mod blocks of iteration t div blocks, and the step
+    that ends an iteration adds the shards' partials (`axis`; None where the
+    rows are all here) and updates the centroids. One loop and not a loop of
+    blocks inside a loop of iterations, because the TPU keeps a table of a
+    few hundred columns with its rows on the lanes, and for a loop nested in
+    a loop its compiler first copies the WHOLE table into row-major order: a
+    second table, which a table over half the chip has no room for."""
+    measure = DistanceMeasure.get_instance(measure_name)
+    n, d = X.shape
+    k = init_centroids.shape[0]
+    block = _block_rows(n, k, d)
+    blocks = -(-n // block)
+    zero = (jnp.zeros((k, d), X.dtype), jnp.zeros((k,), X.dtype))
 
     def cond(state):
-        _, _, epoch = state
-        return epoch < max_iter
+        return state[0] < max_iter * blocks
 
     def step(state):
-        centroids, _, epoch = state
-        dists = measure.pairwise(X, centroids)  # (n, k)
-        assign = jnp.argmin(dists, axis=1)  # (n,)
-        one_hot = jax.nn.one_hot(assign, centroids.shape[0], dtype=X.dtype)  # (n, k)
-        one_hot = one_hot * weights[:, None]
-        counts = jnp.sum(one_hot, axis=0)  # (k,)
-        # reduce form rather than `one_hot.T @ X`: the matmat's blocked
-        # accumulation over n changes under vmap batching, which would break
-        # the fleet contract (every fleet member bit-identical to its solo
-        # fit — see ops/losses.py module docstring and fleet.py)
-        sums = jnp.sum(one_hot[:, :, None] * X[:, None, :], axis=0)  # (k, d)
-        new_centroids = jnp.where(
-            counts[:, None] > 0, sums / jnp.maximum(counts[:, None], 1e-30), centroids
-        )
-        return (new_centroids, counts, epoch + 1)
+        t, centroids, last_counts, partials = state
+        s, c = _one_block(X, w, t % blocks, block, centroids, measure)
+        partials = (partials[0] + s, partials[1] + c)
 
-    init = (init_centroids, jnp.zeros(init_centroids.shape[0], X.dtype), jnp.asarray(0, jnp.int32))
-    centroids, counts, _ = jax.lax.while_loop(cond, step, init)
+        def end_of_iteration():
+            sums, counts = partials if axis is None else collectives.all_reduce_sum(partials, axis)
+            return _new_centroids(centroids, sums, counts), counts, zero
+
+        if blocks == 1:
+            return (t + 1,) + end_of_iteration()
+        return (t + 1,) + lax.cond(
+            t % blocks == blocks - 1,
+            end_of_iteration,
+            lambda: (centroids, last_counts, partials),
+        )
+
+    init = (jnp.asarray(0, jnp.int32), init_centroids, zero[1], zero)
+    _, centroids, counts, _ = lax.while_loop(cond, step, init)
     return centroids, counts
 
 
-_lloyd_train = lazy_jit(_lloyd_train_impl, static_argnames=("measure_name",))
-# Donating variant for fit-owned buffers: the staged/padded dataset, the
-# synthesized unit weights, and the initial centroids are all consumed by
-# the train loop, so XLA may reuse their HBM in place instead of holding a
-# second copy for the duration of the fit.
-_lloyd_train_donating = lazy_jit(
-    _lloyd_train_impl, static_argnames=("measure_name",), donate_argnums=(0, 1, 2)
-)
+def _lloyd_train_impl(X, weights, init_centroids, max_iter, measure_name, mesh):
+    """The full Lloyd fit as one XLA program (`_lloyd_loop`). `mesh` is None
+    where every device holds all the rows it is given (one shard, or a
+    replicated table); else the rows are shared over the mesh's data axis
+    and each shard walks the blocks of its own. Data and max_iter are
+    runtime arguments so repeated fits with the same shapes reuse the
+    compiled executable."""
+    if mesh is None:
+        return _lloyd_loop(X, weights, init_centroids, max_iter, measure_name, None)
+
+    def local(init_centroids, max_iter, X, w):
+        return _lloyd_loop(X, w, init_centroids, max_iter, measure_name, mesh_lib.DATA_AXIS)
+
+    return _on_row_shards(local, mesh, (init_centroids, max_iter), X, weights)
 
 
-def _lloyd_fleet_train_impl(X, weights, init_centroids, max_iters, measure_name, pack_sharding):
+def _lloyd_fit_impl(X, weights, init_centroids, max_iter, measure_name, mesh):
+    """`_lloyd_train_impl` with its result packed for ONE readback:
+    [centroids.ravel | counts]."""
+    centroids, counts = _lloyd_train_impl(
+        X, weights, init_centroids, max_iter, measure_name, mesh
+    )
+    return jnp.concatenate([centroids.ravel(), counts])
+
+
+# Nothing is donated: the table is the caller's where it is trained in
+# place, and no output has its shape anyway.
+_lloyd_fit = lazy_jit(_lloyd_fit_impl, static_argnames=("measure_name", "mesh"))
+
+
+def _lloyd_fleet_train_impl(X, weights, init_centroids, max_iters, measure_name, pack_sharding, mesh):
     """N Lloyd fits as ONE vmapped resident program (fleet.py): the member
     loop is `_lloyd_train_impl` verbatim, vmapped over the per-member
     (init_centroids[N,k,d], max_iters[N]) with the staged dataset closed
     over unbatched — input bytes are paid once for N models. The vmapped
     `while_loop` runs until every member hits its own maxIter and
-    select-freezes finished members, and every contraction in the body is
-    vmap-batching bit-stable (see `_lloyd_train_impl`), so each member's
-    centroids are bit-identical to its solo fit. Readback is ONE packed
+    select-freezes finished members. A member's sums are a segment-sum,
+    which `vmap` batches into one scatter-add: on the CPU a member is
+    bit-identical to its solo fit (tests/test_fleet.py), though no backend
+    promises a scatter-add's order (ROADMAP D4). Readback is ONE packed
     [N, k*d + k] array ([centroids.ravel | counts] per member)."""
     def member(c0, mi):
-        return _lloyd_train_impl(X, weights, c0, mi, measure_name)
+        return _lloyd_train_impl(X, weights, c0, mi, measure_name, mesh)
 
     centroids, counts = jax.vmap(member)(init_centroids, max_iters)
     n_members, k, d = init_centroids.shape
@@ -130,7 +274,7 @@ def _lloyd_fleet_train_impl(X, weights, init_centroids, max_iters, measure_name,
 
 
 _lloyd_fleet_train = lazy_jit(
-    _lloyd_fleet_train_impl, static_argnames=("measure_name", "pack_sharding")
+    _lloyd_fleet_train_impl, static_argnames=("measure_name", "pack_sharding", "mesh")
 )
 
 
@@ -214,32 +358,17 @@ class KMeansModel(Model, KMeansModelParams):
             self.centroids, self.weights = loaded
 
 
-def _accumulate_batch_impl(X, w, centroids, measure_name):
-    """Per-batch Lloyd accumulation for out-of-core training: assign each
-    row to its closest centroid and return (sums, counts) partials that the
-    host adds across the replayed stream. w masks shard-padding rows. The
-    un-jitted impl is shared with the whole-fit resident program, which
-    inlines the same accumulation inside its epoch loop."""
-    measure = DistanceMeasure.get_instance(measure_name)
-    dists = measure.pairwise(X, centroids)
-    assign = jnp.argmin(dists, axis=1)
-    one_hot = jax.nn.one_hot(assign, centroids.shape[0], dtype=X.dtype) * w[:, None]
-    return one_hot.T @ X, jnp.sum(one_hot, axis=0)
-
-
-_accumulate_batch = lazy_jit(_accumulate_batch_impl, static_argnames=("measure_name",))
-
-
-def _lloyd_stream_whole_fit_impl(packed_all, init_centroids, init_counts, start_epoch, max_iter, measure_name):
+def _lloyd_stream_whole_fit_impl(packed_all, init_centroids, init_counts, start_epoch, max_iter, measure_name, mesh):
     """The whole out-of-core Lloyd fit as ONE resident program: the
     stacked [X | w] stream batches (nb, rows, d+1) live in HBM (the device
     epoch cache's contents staged once) and each epoch's inner loop
     dynamic-slices batch partials in replay order — the same sequential
-    `sums + s` fold the host-driven loop performs, so centroids and counts
-    are bit-identical to it (the `optimization_barrier` materializes the
-    column views exactly as the per-batch staging path does). Requires
-    every batch bucketed to the SAME row count; ragged streams fall back
-    to the host-driven loop (dispatch.whole_fit_plan)."""
+    `sums + s` fold of the same `_lloyd_partials` the host-driven loop
+    performs, so centroids and counts are bit-identical to it (the
+    `optimization_barrier` materializes the column views exactly as the
+    per-batch staging path does). Requires every batch bucketed to the SAME
+    row count; ragged streams fall back to the host-driven loop
+    (dispatch.whole_fit_plan)."""
     nb, _, dp1 = packed_all.shape
     d = dp1 - 1
     k = init_centroids.shape[0]
@@ -248,7 +377,7 @@ def _lloyd_stream_whole_fit_impl(packed_all, init_centroids, init_counts, start_
         sums, counts, centroids = acc
         batch = lax.dynamic_index_in_dim(packed_all, bi, 0, False)
         Xb, wb = lax.optimization_barrier((batch[:, :d], batch[:, d]))
-        s, c = _accumulate_batch_impl(Xb, wb, centroids, measure_name)
+        s, c = _lloyd_partials(Xb, wb, centroids, measure_name, mesh)
         return sums + s, counts + c, centroids
 
     def epoch_step(_, state):
@@ -263,10 +392,7 @@ def _lloyd_stream_whole_fit_impl(packed_all, init_centroids, init_counts, start_
                 centroids,
             ),
         )
-        centroids = jnp.where(
-            counts[:, None] > 0, sums / jnp.maximum(counts[:, None], 1e-30), centroids
-        )
-        return centroids, counts
+        return _new_centroids(centroids, sums, counts), counts
 
     return lax.fori_loop(
         start_epoch, max_iter, epoch_step, (init_centroids, init_counts)
@@ -274,7 +400,7 @@ def _lloyd_stream_whole_fit_impl(packed_all, init_centroids, init_counts, start_
 
 
 _lloyd_stream_whole_fit = lazy_jit(
-    _lloyd_stream_whole_fit_impl, static_argnames=("measure_name",)
+    _lloyd_stream_whole_fit_impl, static_argnames=("measure_name", "mesh")
 )
 
 
@@ -296,11 +422,37 @@ def _sample_without_replacement(rng: np.random.RandomState, n: int, k: int) -> n
 
 @partial(lazy_jit, static_argnames=("n_pad", "sharding"))
 def _stage_points(X, n_pad, sharding):
-    """Device-side row padding + sharding for device-born inputs (the
-    benchmark generators produce tables in HBM) — no host round trip."""
+    """A device-born table brought to what the fit needs, in HBM and in one
+    copy: cast to float32, rows padded to the shards, laid over the mesh.
+    A table that needs none of the three is trained in place instead
+    (`KMeans._stage`); each call here is one `lloyd.table_copy`."""
+    X = X.astype(jnp.float32)
     if X.shape[0] != n_pad:
         X = jnp.pad(X, [(0, n_pad - X.shape[0]), (0, 0)])
     return jax.lax.with_sharding_constraint(X, sharding)
+
+
+def _take_rows_impl(X, idx):
+    """Rows `idx` of a table held by one device: for each, the group of 128
+    rows around it is sliced out and a masked sum picks the row (exact: the
+    other rows add zeros). A gather, or a slice of one row, would do, but
+    the TPU keeps a table of a few hundred columns with its rows on the
+    lanes and copies the WHOLE table into row-major order to serve either:
+    a second table, which a table over half the chip has no room for."""
+    n, d = X.shape
+    group = min(128, n)
+
+    def take(i, out):
+        start = jnp.minimum(idx[i] // group * group, n - group)
+        rows = lax.dynamic_slice_in_dim(X, start, group, 0)
+        picked = (jnp.arange(group) == idx[i] - start)[:, None]
+        row = jnp.sum(jnp.where(picked, rows, 0), axis=0, keepdims=True)
+        return lax.dynamic_update_slice_in_dim(out, row, i, 0)
+
+    return lax.fori_loop(0, idx.shape[0], take, jnp.zeros((idx.shape[0], d), X.dtype))
+
+
+_take_rows = lazy_jit(_take_rows_impl)
 
 
 @partial(lazy_jit, static_argnames=("d", "mat_sharding", "row_sharding"))
@@ -332,87 +484,103 @@ class KMeans(Estimator, KMeansParams):
 
         if isinstance(table, StreamTable):
             return self._fit_stream(table)
-        mesh = mesh_lib.default_mesh()
-        X = as_dense_matrix(table.column(self.get_features_col()), allow_device=True)
-        n, d = X.shape
-        k = self.get_k()
-        if n < k:
-            raise ValueError(f"Number of points ({n}) is less than k ({k})")
-
-        # selectRandomCentroids (KMeans.java:310): sample k rows without replacement.
-        rng = np.random.RandomState(self.get_seed() % (2**32))
-        centroid_idx = rng.choice(n, size=k, replace=False)
-
-        shards = mesh_lib.num_data_shards(mesh)
-        n_pad = -(-n // shards) * shards
-        mat_sharding = NamedSharding(mesh, P(mesh_lib.DATA_AXIS, None))
-        row_sharding = NamedSharding(mesh, P(mesh_lib.DATA_AXIS))
-        if isinstance(X, jax.Array):  # device-born: stage entirely in HBM
-            X32 = X.astype(jnp.float32) if X.dtype != jnp.float32 else X
-            init_centroids = jnp.take(X32, jnp.asarray(centroid_idx), axis=0)
-            X_dev = _stage_points(X32, n_pad, mat_sharding)
-        else:
-            X_host = np.asarray(X, dtype=np.float32)
-            init_centroids = jnp.asarray(X_host[centroid_idx])
-            X_pad, _ = mesh_lib.pad_to_multiple(X_host, shards)
-            X_dev = h2d.stage_to_device(X_pad, mat_sharding)
-        w_dev = _unit_weights(n, n_pad, row_sharding)
-
-        from ...obs import tracing
-        from ...utils.packing import packed_device_get
-
-        # the Lloyd loop is one on-device while_loop (always maxIter
-        # epochs): no per-epoch host boundary exists, so a single
-        # `iteration.run` span carries the per-run summary
-        from ...parallel import dispatch
-
-        # the staged/padded points, synthesized weights, and gathered init
-        # centroids are all fit-owned buffers consumed by the train loop —
-        # donate them so Lloyd ping-pongs in the same HBM instead of
-        # holding a second copy of the dataset for the whole fit
         from ... import config
+        from ...obs import tracing
+        from ...ops.optimizer import _read_packed
+        from ...parallel import dispatch
+        from ...utils import metrics
 
-        if config.collective_overlap:
+        with tracing.phase("fit.extract"):
+            mesh = mesh_lib.default_mesh()
+            X = as_dense_matrix(table.column(self.get_features_col()), allow_device=True)
+            n, d = X.shape
+            k, max_iter = self.get_k(), self.get_max_iter()
+            measure = self.get_distance_measure()
+            if n < k:
+                raise ValueError(f"Number of points ({n}) is less than k ({k})")
+        with tracing.phase("fit.stage"):
+            # selectRandomCentroids (KMeans.java:310): sample k rows without replacement.
+            rng = np.random.RandomState(self.get_seed() % (2**32))
+            centroid_idx = rng.choice(n, size=k, replace=False)
+            X_dev, w_dev, init_centroids = self._stage(X, centroid_idx, mesh)
+            shards = mesh_lib.num_data_shards(mesh)
+            row_mesh = mesh if shards > 1 else None
+            max_iter_dev = jnp.asarray(max_iter, jnp.int32)
+
+        if config.collective_overlap and row_mesh is not None:
             # overlap-scheduled Lloyd: epoch e's centroid-partial reduce
             # rides the chunked collective under epoch e+1's distance
-            # matmul (parallel/overlap.py; bit-identical to _lloyd_train)
+            # matmul (parallel/overlap.py; the same block step)
             from ...parallel import overlap
 
-            def train(X, w, init, max_iter, measure):
-                return overlap.overlapped_lloyd_train(
-                    mesh, X, w, init, max_iter, measure
-                )
+            def train(X, w, init, max_iter, measure, mesh):
+                return overlap.overlapped_lloyd_train(mesh, X, w, init, max_iter, measure)
 
         else:
-            train = (
-                _lloyd_train_donating if dispatch.supports_donation() else _lloyd_train
-            )
+            train = _lloyd_fit
         # the in-memory Lloyd loop has always been a whole-fit resident
         # program (one dispatch, one packed readback); counted when the
         # mode is on, like the fused SGD paths
         if dispatch.whole_fit_enabled():
             dispatch.account_whole_fit("lloyd")
-        with tracing.span(
-            "iteration.run", mode="device", epochs=self.get_max_iter()
-        ):
-            centroids, counts = dispatch.timed_dispatch(
-                train,
-                X_dev,
-                w_dev,
-                init_centroids,
-                jnp.asarray(self.get_max_iter(), jnp.int32),
-                self.get_distance_measure(),
-                start=0, end=self.get_max_iter(),
+        metrics.inc_counter("lloyd.iterations", max_iter)
+        metrics.inc_counter(
+            "lloyd.blocks", max_iter * shards * _num_blocks(X_dev.shape[0] // shards, k, d)
+        )
+        # the Lloyd loop is one on-device while_loop (always maxIter
+        # epochs): no per-epoch host boundary exists, so a single
+        # `iteration.run` span carries the per-run summary
+        with tracing.span("iteration.run", mode="device", epochs=max_iter):
+            packed = dispatch.timed_dispatch(
+                train, X_dev, w_dev, init_centroids, max_iter_dev, measure, row_mesh,
+                start=0, end=max_iter,
             )
-
-            model = KMeansModel()
-            # one packed readback: (centroids, counts) pulled separately
-            # would be two blocking readbacks
-            host_centroids, host_counts = packed_device_get(centroids, counts)
-        model.centroids = np.asarray(host_centroids, dtype=np.float64)
-        model.weights = np.asarray(host_counts, dtype=np.float64)
+            host = _read_packed(packed)  # the fit's one readback
+        model = KMeansModel()
+        model.centroids = np.asarray(host[: k * d].reshape(k, d), dtype=np.float64)
+        model.weights = np.asarray(host[k * d :], dtype=np.float64)
         update_existing_params(model, self)
         return model
+
+    @staticmethod
+    def _stage(X, centroid_idx, mesh):
+        """(table, weights, initial centroids) on the device, as the train
+        program takes them. A device-born float32 table that already lies
+        over the mesh as the fit needs it, with no row to pad, is trained IN
+        PLACE: it stays the caller's, and nothing table-sized is allocated.
+        Anything else is brought there in one copy, counted as
+        `lloyd.table_copy` when the copy is made on the device. The weights
+        mark the padding rows and are None where there are none."""
+        from ...utils import metrics
+
+        n = X.shape[0]
+        shards = mesh_lib.num_data_shards(mesh)
+        n_pad = -(-n // shards) * shards
+        mat_sharding = NamedSharding(mesh, P(mesh_lib.DATA_AXIS, None))
+        if isinstance(X, jax.Array):  # device-born: stage entirely in HBM
+            in_place = (
+                X.dtype == jnp.float32
+                and n_pad == n
+                and X.sharding.is_equivalent_to(mat_sharding, X.ndim)
+            )
+            if in_place:
+                X_dev = X
+            else:
+                metrics.inc_counter("lloyd.table_copy")
+                X_dev = _stage_points(X, n_pad, mat_sharding)
+            if len(X_dev.sharding.device_set) == 1:
+                init_centroids = _take_rows(X_dev, jnp.asarray(centroid_idx, jnp.int32))
+            else:
+                init_centroids = jnp.take(X_dev, jnp.asarray(centroid_idx), axis=0)
+        else:
+            X_host = np.asarray(X, dtype=np.float32)
+            init_centroids = jnp.asarray(X_host[centroid_idx])
+            X_pad, _ = mesh_lib.pad_to_multiple(X_host, shards)
+            X_dev = h2d.stage_to_device(X_pad, mat_sharding)
+        w_dev = None
+        if n_pad != n:
+            w_dev = _unit_weights(n, n_pad, NamedSharding(mesh, P(mesh_lib.DATA_AXIS)))
+        return X_dev, w_dev, init_centroids
 
     def _fit_stream(self, stream) -> KMeansModel:
         """Out-of-core Lloyd over a StreamTable: the first pass caches every
@@ -466,6 +634,7 @@ class KMeans(Estimator, KMeansParams):
 
         mesh = mesh_lib.default_mesh()
         shards = mesh_lib.num_data_shards(mesh)
+        row_mesh = mesh if shards > 1 else None  # _lloyd_partials' `mesh`
         mat_sharding = NamedSharding(mesh, P(mesh_lib.DATA_AXIS, None))
         row_sharding = NamedSharding(mesh, P(mesh_lib.DATA_AXIS))
         centroids = jnp.asarray(init)
@@ -606,6 +775,7 @@ class KMeans(Estimator, KMeansParams):
                     jnp.asarray(start_epoch, jnp.int32),
                     jnp.asarray(self.get_max_iter(), jnp.int32),
                     measure,
+                    row_mesh,
                     start=start_epoch, end=self.get_max_iter(),
                 )
             final_epoch = self.get_max_iter()
@@ -630,14 +800,10 @@ class KMeans(Estimator, KMeansParams):
             sums = jnp.zeros((k, centroids.shape[1]), jnp.float32)
             counts = jnp.zeros((k,), jnp.float32)
             for batch in loader.epoch(range(nb)):
-                s, c = _accumulate_batch(*batch, centroids, measure)
+                s, c = _accumulate_batch(*batch, centroids, measure, row_mesh)
                 sums = sums + s
                 counts = counts + c
-            centroids = jnp.where(
-                counts[:, None] > 0,
-                sums / jnp.maximum(counts[:, None], 1e-30),
-                centroids,
-            )
+            centroids = _new_centroids(centroids, sums, counts)
             if ckpt_dir is not None and (epoch + 1) % interval == 0:
                 _snapshot.save_job_snapshot(
                     ckpt_dir,

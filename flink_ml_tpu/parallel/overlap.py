@@ -368,35 +368,31 @@ def overlapped_lloyd_train(
 ):
     """Lloyd's loop with the same carry-delayed schedule: the (k, d)+(k,)
     centroid-partial reduction of epoch e rides the chunked collective at
-    the top of epoch e+1, overlapping the pairwise-distance matmul of the
-    next assignment. Bit-identical to the eager `_lloyd_train` (the
-    reduce is psum-bit-equal and the update order is unchanged)."""
-    key = (mesh, measure_name, _config_key())
+    the top of epoch e+1, overlapping the blocks of the next assignment.
+    Each shard's partials are the eager fit's own blocked pass
+    (`kmeans._accumulate_batch_impl`), the reduce is psum-bit-equal and the
+    update order is unchanged. `weights` is None where no row is padding.
+    Returns the eager `_lloyd_fit`'s packed [centroids.ravel | counts]."""
+    key = (mesh, measure_name, weights is None, _config_key())
     fn = _LLOYD_CACHE.get(key)
     if fn is None:
-        fn = _build_lloyd_program(mesh, measure_name)
+        fn = _build_lloyd_program(mesh, measure_name, weights is None)
         _LLOYD_CACHE[key] = fn
-    return fn(X, weights, init_centroids, max_iter)
+    rows = (X,) if weights is None else (X, weights)
+    return fn(init_centroids, max_iter, *rows)
 
 
-def _build_lloyd_program(mesh: Mesh, measure_name: str):
-    from ..ops.distance import DistanceMeasure
+def _build_lloyd_program(mesh: Mesh, measure_name: str, unweighted: bool):
+    from ..models.clustering.kmeans import _accumulate_batch_impl, _new_centroids
 
     axis = mesh_lib.DATA_AXIS
-    measure = DistanceMeasure.get_instance(measure_name)
 
-    def train(X, weights, init_centroids, max_iter):
+    def train(init_centroids, max_iter, X, *w):
         k = init_centroids.shape[0]
+        weights = w[0] if w else None
 
         def reduce_partials(sums, counts):
             return collectives.all_reduce_sum_chunked((sums, counts), axis)
-
-        def update(centroids, sums, counts):
-            return jnp.where(
-                counts[:, None] > 0,
-                sums / jnp.maximum(counts[:, None], 1e-30),
-                centroids,
-            )
 
         def cond(state):
             return state[3] < max_iter
@@ -408,14 +404,9 @@ def _build_lloyd_program(mesh: Mesh, measure_name: str):
             # (counts 0 -> centroids keep their init values, exactly the
             # eager loop's first assignment)
             sums, counts = reduce_partials(local_sums, local_counts)
-            centroids = update(centroids, sums, counts)
-            dists = measure.pairwise(X, centroids)
-            assign = jnp.argmin(dists, axis=1)
-            one_hot = jax.nn.one_hot(assign, k, dtype=X.dtype) * weights[:, None]
-            # reduce-form segment sum, matching kmeans._lloyd_train_impl
-            # (vmap-batching bit-stability — see ops/losses.py docstring)
-            sums = jnp.sum(one_hot[:, :, None] * X[:, None, :], axis=0)
-            return (centroids, sums, jnp.sum(one_hot, axis=0), epoch + 1)
+            centroids = _new_centroids(centroids, sums, counts)
+            sums, counts = _accumulate_batch_impl(X, weights, centroids, measure_name)
+            return (centroids, sums, counts, epoch + 1)
 
         init = (
             init_centroids,
@@ -425,11 +416,10 @@ def _build_lloyd_program(mesh: Mesh, measure_name: str):
         )
         centroids, local_sums, local_counts, _ = lax.while_loop(cond, step, init)
         sums, counts = reduce_partials(local_sums, local_counts)
-        return update(centroids, sums, counts), counts
+        return jnp.concatenate([_new_centroids(centroids, sums, counts).ravel(), counts])
 
-    mapped = collectives.shard_map_over(
-        mesh, (P(axis, None), P(axis), P(), P()), (P(), P()), fn=train
-    )
+    row_specs = (P(axis, None),) if unweighted else (P(axis, None), P(axis))
+    mapped = collectives.shard_map_over(mesh, (P(), P()) + row_specs, P(), fn=train)
     # tpulint: disable=retrace-hazard -- overlap mode builds one program per fit by design (opt-in; caching keyed on mesh/shape is ROADMAP item 2)
     return jax.jit(mapped)
 

@@ -204,25 +204,27 @@ class TestIterateBoundedChunked:
         assert seen == [1, 2, 3, 4, 5, "end"]
         assert res.num_epochs == 5
 
-    def test_lloyd_donating_variant_bit_identical(self):
-        """KMeans' donating Lloyd kernel (HBM ping-pong) computes exactly
-        what the borrowing one does."""
+    def test_lloyd_fit_donates_nothing_and_packs_the_train_loop(self):
+        """KMeans' fit program (`_lloyd_fit`) is the train loop with its
+        result packed [centroids.ravel | counts], and it donates nothing: a
+        table trained in place is the caller's and stays alive. (The
+        donating variant went with the staged copy it consumed.)"""
         from flink_ml_tpu.models.clustering.kmeans import (
-            _lloyd_train,
-            _lloyd_train_donating,
+            _lloyd_fit,
+            _lloyd_train_impl,
         )
 
         rng = np.random.RandomState(1)
-        X = rng.randn(50, 4).astype(np.float32)
-        w = np.ones(50, np.float32)
+        X = jnp.asarray(rng.randn(50, 4).astype(np.float32))
         init = X[:3]
         mi = jnp.asarray(10, jnp.int32)
-        c_b, n_b = _lloyd_train(jnp.asarray(X), jnp.asarray(w), jnp.asarray(init), mi, "euclidean")
-        c_d, n_d = _lloyd_train_donating(
-            jnp.asarray(X), jnp.asarray(w), jnp.asarray(init), mi, "euclidean"
+        c, n = jax.jit(_lloyd_train_impl, static_argnums=(4, 5))(
+            X, None, init, mi, "euclidean", None
         )
-        np.testing.assert_array_equal(np.asarray(c_b), np.asarray(c_d))
-        np.testing.assert_array_equal(np.asarray(n_b), np.asarray(n_d))
+        packed = _lloyd_fit(X, None, init, mi, "euclidean", None)
+        assert not X.is_deleted() and not init.is_deleted()
+        np.testing.assert_array_equal(np.asarray(packed[:12]).reshape(3, 4), np.asarray(c))
+        np.testing.assert_array_equal(np.asarray(packed[12:]), np.asarray(n))
 
 
 class TestHostSyncBudget:
