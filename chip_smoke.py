@@ -186,7 +186,8 @@ def phase_train(rows=None, batch=None, parity_rows=200_000):
         )
         all_devices = set(jax.devices())
         spans_all = all(
-            set(a.sharding.device_set) == all_devices for a in (X_b, y_b, w_b)
+            set(a.sharding.device_set) == all_devices
+            for a in jax.tree_util.tree_leaves((X_b, y_b, w_b))
         )
         check(spans_all, "a batched training array does not span every device")
         del X_b, y_b, w_b

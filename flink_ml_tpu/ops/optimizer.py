@@ -48,12 +48,49 @@ from ..utils.lazyjit import lazy_jit
 from .losses import LossFunc
 
 
+@partial(jax.tree_util.register_dataclass, data_fields=("strips",), meta_fields=("width",))
+@dataclass(frozen=True)
+class BatchStrips:
+    """A table as the exchange lays it out (`_exchange_batches_impl`): `strips`
+    is (num_batches, shards, width_pad, piece), each batch's share on a shard
+    [column, row] as it arrived, and `width` the table's own. Written for the
+    TPU, which keeps such an array as it reads, [batch][shard][column][row] in
+    whole (8, 128) tiles, once the column axis is whole sublanes (`width_pad`;
+    the rows it pads itself): batch k of a shard's share is one contiguous
+    run. The same array as (num_batches, batch, width), the general form's,
+    the v5e keeps [column][batch][row], eight batches to a tile. `shape` and
+    `dtype` are the general form's, for those who only ask."""
+
+    strips: jax.Array
+    width: int
+
+    @property
+    def dtype(self):
+        return self.strips.dtype
+
+    @property
+    def shape(self):
+        num_batches, shards, _, piece = self.strips.shape
+        return (num_batches, shards * piece, self.width)
+
+    def batch(self, k):
+        """Batch k as [rows, width]: the strips where they lie, read the
+        other way round, the pad columns left out."""
+        strips = lax.dynamic_index_in_dim(self.strips, k, 0, False)
+        return jnp.swapaxes(strips, 1, 2)[:, :, : self.width].reshape(-1, self.width)
+
+
 def _index_batch(X_b, k):
     """Select batch k from batched features; X may be a dense array or the
     sparse (indices, values) tuple — every driver treats features as a
-    pytree so the sparse padded-CSR layout flows through unchanged."""
+    pytree so the sparse padded-CSR layout flows through unchanged. Each
+    array says by its own form how its batch is found: the general layout's
+    (num_batches, batch, ...) by an index, the exchange's `BatchStrips` by
+    its view."""
     if isinstance(X_b, tuple):
-        return tuple(lax.dynamic_index_in_dim(leaf, k, 0, False) for leaf in X_b)
+        return tuple(_index_batch(leaf, k) for leaf in X_b)
+    if isinstance(X_b, BatchStrips):
+        return X_b.batch(k)
     return lax.dynamic_index_in_dim(X_b, k, 0, False)
 
 
@@ -124,19 +161,22 @@ def _pad_is_small(width, piece) -> bool:
 
 def _can_exchange(arr, n, batch, shards, d_pad, mesh) -> bool:
     """Whether `_exchange_batches_impl` gives `_layout_batches_impl`'s
-    array, and cheaper. It gives it for a device table of exactly n rows,
+    batches, and cheaper. It gives them for a device table of exactly n rows,
     sharded by rows over the mesh's data axis (`shards` of them; 1 under
     `replicate_data`), where every shard's rows are whole batches, a batch
     divides over the shards and no feature pad is asked for. It is cheaper
     where the device keeps the rows minor (`mesh_lib.rows_minor`; on rows
     kept major, the CPU's and a wide table's, the general form is already
-    one all-to-all between two copies), the pad to whole tiles is small,
-    and a shard's batches are whole slabs of the exchange. Only 32-bit
-    types: a bfloat16 table's tile is another, and the same code compiled
-    for it to two passes where this has one. A 1-D column (y, a weight)
-    keeps the general form, 0.5 ms of a 111 ms fit on four v5e chips. All
-    read off shapes, the dtype, the sharding and the device's layout,
-    nothing a user sets."""
+    one all-to-all between two copies), the pad to whole tiles is small
+    (`BatchStrips` keeps it for the whole fit), and a shard's batches are
+    whole slabs of the exchange. Only 32-bit types: a bfloat16 table's tile
+    is another, and the same code compiled for it to two passes where this
+    has one. A 1-D column (y, a weight) keeps the general form, 0.5 ms of a
+    fit on four v5e chips. All read off shapes, the dtype, the sharding and
+    the device's layout, nothing a user sets; and the one place that
+    decides: what it turns away keeps the general form's (num_batches,
+    batch, ...) array as it was, and the training loops tell the two apart
+    by what they are handed (`_index_batch`)."""
     return (
         shards > 1
         and d_pad is None
@@ -153,15 +193,15 @@ def _can_exchange(arr, n, batch, shards, d_pad, mesh) -> bool:
     )
 
 
-def _exchange_batches_impl(arr, batch, sharding):
+def _exchange_batches_impl(arr, batch, mesh):
     """The batch layout of a row-sharded table [rows, width] as one explicit
     exchange: every shard cuts each of its own batches (it holds whole
     ones) into one piece a shard, an all-to-all sends piece j of every batch
     to shard j, and source shard c's local batch b arrives as global batch
-    c*nb_local + b. The result is `_layout_batches_impl`'s to the letter
-    (shape, values and sharding, so the training programs re-enter the same
-    executable) for the inputs `_can_exchange` admits: a dense X and both
-    sparse leaves.
+    c*nb_local + b. The batches are `_layout_batches_impl`'s to the letter,
+    shard by shard, for the inputs `_can_exchange` admits (a dense X and
+    both sparse leaves), handed over as `BatchStrips`: every batch stays
+    [column, row], as it arrived.
 
     Written for a table the device keeps rows-minor, in memory [column,
     row]: there a piece of a batch is a strip of every column, and a
@@ -170,15 +210,17 @@ def _exchange_batches_impl(arr, batch, sharding):
     139 ms the general form takes for a 3.3 GB share on four v5e chips.
     Here each strip is sliced out where it lies and written, padded to
     whole tiles, into a staging buffer [shard, batch, column, row] that the
-    all-to-all takes as it is; what arrives is put in the training order by
-    one transposing copy (70 ms for the same share, 38 of them the
-    all-to-all). The padding is what keeps XLA from transposing the staged
-    buffer before and after the all-to-all. The exchange goes in slabs of
-    one tile's sublanes of batches (whole tiles of the laid-out copy's batch
-    axis, else the last copy becomes two), so that only one slab is staged
-    and received at a time: the compiler reckons 4.0 GB of temporaries for
-    that share where the general form has 6.4."""
-    mesh = sharding.mesh
+    all-to-all takes as it is. The padding is what keeps XLA from
+    transposing the staged buffer before and after the all-to-all. The
+    exchange goes in slabs of eight batches, so that only one slab is staged
+    and received at a time.
+
+    `order` below says where every strip belongs and moves nothing: since
+    the result keeps a batch as it arrived, the compiler lays the buffer the
+    slabs are stacked into [source shard][slab][batch of the slab], writes
+    each slab's arrival into its final place, and the cut of the pad rows is
+    the same bytes read another way (compiled for four v5e chips: 1.0 GB of
+    temporaries for a 3.3 GB share, where the general form takes 6.4)."""
     data = mesh_lib.DATA_AXIS
     shards = mesh_lib.num_data_shards(mesh)
     width = arr.shape[1]
@@ -210,18 +252,18 @@ def _exchange_batches_impl(arr, batch, sharding):
 
     def order(local):
         """[slab index, source shard, 1, batch of the slab x column, row] ->
-        [source shard x slab index x batch, row, column]: the global batch
-        order."""
-        strips = local.reshape(-1, shards, slab, width_pad, piece_pad)[..., :width, :piece]
-        return jnp.transpose(strips, (1, 0, 2, 4, 3)).reshape(shards * nb_local, piece, width)
+        [source shard x slab index x batch, 1, column, row]: the global batch
+        order, the pad rows cut."""
+        strips = local.reshape(-1, shards, slab, width_pad, piece_pad)[..., :piece]
+        return jnp.swapaxes(strips, 0, 1).reshape(shards * nb_local, 1, width_pad, piece)
 
-    out = collectives.shard_map_over(
-        mesh, P(None, None, data, None, None), P(None, data, None), order
+    strips = collectives.shard_map_over(
+        mesh, P(None, None, data, None, None), P(None, data, None, None), order
     )(arrived)
-    return lax.with_sharding_constraint(out, sharding)
+    return BatchStrips(strips, width)
 
 
-_EXCHANGE_STATICS = ("batch", "sharding")
+_EXCHANGE_STATICS = ("batch", "mesh")
 _exchange_batches = lazy_jit(_exchange_batches_impl, static_argnames=_EXCHANGE_STATICS)
 _exchange_batches_donating = lazy_jit(
     _exchange_batches_impl, static_argnames=_EXCHANGE_STATICS, donate_argnums=(0,)
@@ -1793,7 +1835,7 @@ class SGD:
             if _can_exchange(arr, n, batch, shards, d_pad, mesh):
                 metrics.inc_counter("layout.exchange")
                 fn = _exchange_batches_donating if owned else _exchange_batches
-                args = (batch, sharding)
+                args = (batch, mesh)
             else:
                 metrics.inc_counter("layout.general")
                 fn = _layout_batches_donating if owned else _layout_batches

@@ -14,14 +14,21 @@ Pinned here, on the suite's eight virtual devices:
 3. a four-shard LogisticRegression fit gives the same coefficient, bit for
    bit, by either form;
 4. compiled for four v5e chips at the benchmark's size (no chip needed), the
-   exchange holds one all-to-all under XLA's own name, in a loop of slabs.
+   exchange holds one all-to-all under XLA's own name, in a loop of slabs;
+5. the exchange hands its batches over as `BatchStrips`, an array the TPU
+   keeps with the batch axis outermost in memory by its own default: compiled
+   for the chip, the train program reads batch k where it lies and each slab
+   arrives in its final place; on the CPU a fit is the general form's to the
+   bit, pad rows and columns and all.
 
 The CPU keeps every table rows-major, so `_can_exchange` never admits one
 here: the tests that need the exchange taken tell `mesh_lib.rows_minor` to
 say what the TPU says of a narrow table.
 """
 
+import math
 import re
+from functools import cache
 
 import numpy as np
 import pytest
@@ -32,7 +39,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from flink_ml_tpu import Table
 from flink_ml_tpu.models.classification.logisticregression import LogisticRegression
-from flink_ml_tpu.ops import optimizer
+from flink_ml_tpu.ops import losses, optimizer
 from flink_ml_tpu.ops.optimizer import SGD
 from flink_ml_tpu.parallel import mesh as mesh_lib
 from flink_ml_tpu.table import SparseBatch
@@ -82,6 +89,15 @@ def batched_sharding(mesh, ndim):
     return NamedSharding(mesh, P(None, mesh_lib.DATA_AXIS, *([None] * (ndim - 1))))
 
 
+def as_batches(laid_out):
+    """A laid-out array as [batch, row, ...] on the host, by either form."""
+    if not isinstance(laid_out, optimizer.BatchStrips):
+        return np.asarray(laid_out)
+    strips = np.asarray(laid_out.strips)  # [batch, shard, column (padded), row]
+    assert not strips[:, :, laid_out.width:].any()  # the pad columns are zeros
+    return np.swapaxes(strips, 2, 3)[..., : laid_out.width].reshape(laid_out.shape)
+
+
 def batches_of(host, batch, b_pad=None, d_pad=None):
     """The layout in numpy: rows padded to whole batches, [batch, row, ...],
     the row axis padded to b_pad, the feature axis to d_pad."""
@@ -117,15 +133,15 @@ def test_exchange_gives_the_general_layout(shards, kind, owned, slabs):
     )
     exchange = optimizer._exchange_batches_donating if owned else optimizer._exchange_batches
     given = by_rows(mesh, host)
-    got = exchange(given, BATCH, sharding)
-    assert got.shape == general.shape and got.dtype == general.dtype
-    assert got.sharding == general.sharding
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(general))
-    np.testing.assert_array_equal(np.asarray(got), batches_of(host, BATCH))
-    # every shard holds its own rows of every batch, as the training programs expect
+    got = exchange(given, BATCH, mesh)
+    assert got.shape == general.shape and got.dtype == general.dtype and got.width == host.shape[1]
+    np.testing.assert_array_equal(as_batches(got), np.asarray(general))
+    np.testing.assert_array_equal(as_batches(got), batches_of(host, BATCH))
+    # every shard holds its own rows of every batch, [column, row] as they arrived
     piece = BATCH // shards
-    for shard in got.addressable_shards:
-        assert shard.data.shape[:2] == (rows // BATCH, piece)
+    assert got.strips.sharding.is_equivalent_to(batched_sharding(mesh, 3), 4)
+    for shard in got.strips.addressable_shards:
+        assert shard.data.shape == (rows // BATCH, 1, mesh_lib.SUBLANES, piece)  # 5 or 3 columns kept as 8
     if not owned:
         np.testing.assert_array_equal(np.asarray(given), host)  # a borrowed input is left alone
 
@@ -136,7 +152,7 @@ def test_the_exchange_is_one_accounted_all_to_all_in_a_loop_of_slabs():
     sharding = batched_sharding(mesh, 2)
     before = metrics.snapshot()
     fn = jax.jit(  # a jit of its own: the module's may have this trace cached
-        lambda arr: optimizer._exchange_batches_impl(arr, BATCH, sharding)
+        lambda arr: optimizer._exchange_batches_impl(arr, BATCH, mesh)
     )
     text = fn.lower(by_rows(mesh, host)).compile().as_text()
     counters = metrics.snapshot_delta(before, metrics.snapshot())["counters"]
@@ -163,8 +179,8 @@ def test_device_table_of_whole_batches_takes_the_exchange(rows_minor):
     )
     assert (exchanged, general) == (1, 2)  # the table; a 1-D column keeps the general form
     for got, host in ((X_b, X), (y_b, y), (w_b, w)):
-        np.testing.assert_array_equal(np.asarray(got), batches_of(host, WIDE_BATCH))
-    assert X_b.sharding.is_equivalent_to(batched_sharding(mesh, 2), 3)
+        np.testing.assert_array_equal(as_batches(got), batches_of(host, WIDE_BATCH))
+    assert X_b.strips.sharding.is_equivalent_to(batched_sharding(mesh, 3), 4)
     assert y_b.sharding.is_equivalent_to(batched_sharding(mesh, 1), 2)
 
 
@@ -176,8 +192,8 @@ def test_sparse_leaves_take_the_exchange(rows_minor):
         mesh, (by_rows(mesh, indices), by_rows(mesh, values)), by_rows(mesh, y), batch=WIDE_BATCH
     )
     assert (exchanged, general) == (2, 1)  # both leaves; y; the default weights are made in place
-    np.testing.assert_array_equal(np.asarray(X_b[0]), batches_of(indices, WIDE_BATCH))
-    np.testing.assert_array_equal(np.asarray(X_b[1]), batches_of(values, WIDE_BATCH))
+    np.testing.assert_array_equal(as_batches(X_b[0]), batches_of(indices, WIDE_BATCH))
+    np.testing.assert_array_equal(as_batches(X_b[1]), batches_of(values, WIDE_BATCH))
     np.testing.assert_array_equal(np.asarray(y_b), batches_of(y, WIDE_BATCH))
 
 
@@ -186,7 +202,7 @@ def test_host_table_that_divides_is_staged_by_rows_and_exchanged(rows_minor):
     X, y = host_column("X", WIDE_ROWS, width=WIDE_DIM), host_column("y", WIDE_ROWS)
     (X_b, y_b, _), exchanged, general = lay_out(mesh, X, y, batch=WIDE_BATCH)  # numpy in: staged, owned, donated
     assert (exchanged, general) == (1, 1)
-    np.testing.assert_array_equal(np.asarray(X_b), batches_of(X, WIDE_BATCH))
+    np.testing.assert_array_equal(as_batches(X_b), batches_of(X, WIDE_BATCH))
     np.testing.assert_array_equal(np.asarray(y_b), batches_of(y, WIDE_BATCH))
 
 
@@ -305,8 +321,8 @@ def test_sparse_leaves_on_a_2d_mesh_take_the_exchange(mesh_2d, rows_minor):
         mesh_2d, (rows_sharded(indices), rows_sharded(values)), rows_sharded(y), batch=batch
     )
     assert (exchanged, general) == (2, 1)
-    np.testing.assert_array_equal(np.asarray(X_b[0]), batches_of(indices, batch))
-    np.testing.assert_array_equal(np.asarray(X_b[1]), batches_of(values, batch))
+    np.testing.assert_array_equal(as_batches(X_b[0]), batches_of(indices, batch))
+    np.testing.assert_array_equal(as_batches(X_b[1]), batches_of(values, batch))
 
 
 def test_a_table_sharded_another_way_keeps_the_general_form(rows_minor):
@@ -319,41 +335,83 @@ def test_a_table_sharded_another_way_keeps_the_general_form(rows_minor):
     assert X_b.sharding.is_equivalent_to(batched_sharding(mesh, 2), 3)
 
 
-def dense_table(mesh, rows):
+def dense_table(mesh, rows, width=WIDE_DIM):
     rng = np.random.default_rng(7)
-    X = rng.normal(size=(rows, WIDE_DIM)).astype(np.float32)
-    y = (X @ rng.normal(size=WIDE_DIM) > 0).astype(np.float32)
+    X = rng.normal(size=(rows, width)).astype(np.float32)
+    y = (X @ rng.normal(size=width) > 0).astype(np.float32)
     return Table({"features": by_rows(mesh, X), "label": by_rows(mesh, y)})
 
 
-def sparse_table(mesh, rows):
+def sparse_table(mesh, rows, width=WIDE_DIM):
     rng = np.random.default_rng(11)
-    indices = np.sort(rng.integers(0, 40, (rows, WIDE_DIM)).astype(np.int32), axis=1)
-    values = rng.random((rows, WIDE_DIM)).astype(np.float32)
-    y = (values.sum(axis=1) > WIDE_DIM / 2).astype(np.float32)
+    indices = np.sort(rng.integers(0, 40, (rows, width)).astype(np.int32), axis=1)
+    values = rng.random((rows, width)).astype(np.float32)
+    y = (values.sum(axis=1) > width / 2).astype(np.float32)
     features = SparseBatch(40, by_rows(mesh, indices), by_rows(mesh, values))
     return Table({"features": features, "label": by_rows(mesh, y)})
+
+
+def coefficients_by_either_form(table, batch, max_iter, monkeypatch):
+    """LogisticRegression fitted with the exchange taken, then with it turned away."""
+
+    def fit():
+        before = metrics.snapshot()
+        model = LogisticRegression().set_global_batch_size(batch).set_max_iter(max_iter).fit(table)
+        counters = metrics.snapshot_delta(before, metrics.snapshot())["counters"]
+        return np.asarray(model.coefficient), counters
+
+    exchanged, counters = fit()
+    assert counters.get("layout.exchange", 0) >= 1 and counters.get("layout.general", 0) == 1  # y
+    monkeypatch.setattr(optimizer, "_can_exchange", lambda *args: False)
+    general, counters = fit()
+    assert counters.get("layout.general", 0) >= 2 and "layout.exchange" not in counters
+    assert np.all(np.isfinite(exchanged)) and np.any(exchanged != 0)
+    return exchanged, general
 
 
 @pytest.mark.parametrize("make_table", [dense_table, sparse_table], ids=["dense", "sparse"])
 def test_four_shard_fit_is_bit_identical_by_either_form(make_table, rows_minor, monkeypatch):
     mesh = data_mesh(4)
     with mesh_lib.use_mesh(mesh):
-        table = make_table(mesh, WIDE_ROWS)
-
-        def fit():
-            before = metrics.snapshot()
-            model = LogisticRegression().set_global_batch_size(WIDE_BATCH).set_max_iter(12).fit(table)
-            counters = metrics.snapshot_delta(before, metrics.snapshot())["counters"]
-            return np.asarray(model.coefficient), counters
-
-        exchanged, counters = fit()
-        assert counters.get("layout.exchange", 0) >= 1 and counters.get("layout.general", 0) == 1  # y
-        monkeypatch.setattr(optimizer, "_can_exchange", lambda *args: False)
-        general, counters = fit()
-        assert counters.get("layout.general", 0) >= 2 and "layout.exchange" not in counters
-    assert np.all(np.isfinite(exchanged)) and np.any(exchanged != 0)
+        exchanged, general = coefficients_by_either_form(make_table(mesh, WIDE_ROWS), WIDE_BATCH, 12, monkeypatch)
     np.testing.assert_array_equal(exchanged, general)
+
+
+# a strip neither of whose sides is whole tiles: 31 columns are sent as 32,
+# 127 rows as 128, so the staged copy holds pad rows and the strips pad columns
+RAGGED_DIM, RAGGED_PIECE = 31, 127
+
+
+@pytest.mark.parametrize("make_table", [dense_table, sparse_table], ids=["dense", "sparse"])
+@pytest.mark.parametrize("shards", [2, 4, 8])
+def test_ragged_strips_fit_bit_identical_by_either_form(shards, make_table, rows_minor, monkeypatch):
+    """The pad to whole tiles adds no row and no column to any batch: the
+    same weight sum, the same loss, so the same coefficient to the bit."""
+    assert optimizer._padded_strip(RAGGED_DIM, RAGGED_PIECE) == (32, 128)
+    mesh = data_mesh(shards)
+    batch = shards * RAGGED_PIECE
+    with mesh_lib.use_mesh(mesh):
+        table = make_table(mesh, shards * batch * SLAB, RAGGED_DIM)
+        exchanged, general = coefficients_by_either_form(table, batch, 2 * SLAB + 3, monkeypatch)
+    np.testing.assert_array_equal(exchanged, general)
+
+
+def test_the_exchanged_table_is_strips_and_every_other_array_is_not(rows_minor):
+    """The training loop finds its batch by the array's own form."""
+    mesh = data_mesh(4)
+    X, y = host_column("X", WIDE_ROWS, width=5), host_column("y", WIDE_ROWS)
+    wide = host_column("X", WIDE_ROWS, width=WIDE_DIM)
+    (X_b, y_b, w_b), exchanged, _ = lay_out(mesh, by_rows(mesh, wide), by_rows(mesh, y), batch=WIDE_BATCH)
+    assert exchanged == 1 and isinstance(X_b, optimizer.BatchStrips)
+    assert X_b.strips.shape == (WIDE_ROWS // WIDE_BATCH, 4, WIDE_DIM, WIDE_BATCH // 4)
+    assert isinstance(y_b, jax.Array) and isinstance(w_b, jax.Array)
+    (X_b, _, _), exchanged, _ = lay_out(mesh, by_rows(mesh, X), by_rows(mesh, y), batch=WIDE_BATCH)
+    assert exchanged == 0 and isinstance(X_b, jax.Array)
+    # batch k by either form, under jit: the same rows
+    strips = optimizer._exchange_batches(by_rows(mesh, wide), WIDE_BATCH, mesh)
+    for k in (0, 5, 4 * SLAB - 1):
+        got = jax.jit(optimizer._index_batch)(strips, k)
+        np.testing.assert_array_equal(np.asarray(got), batches_of(wide, WIDE_BATCH)[k])
 
 
 # --- compiled for the chip, at the benchmark's size; nothing runs ---------
@@ -370,9 +428,29 @@ def four_v5e():
     return Mesh(np.array(topo.devices), (mesh_lib.DATA_AXIS,))
 
 
-def compiled_for(mesh, fn, shape, dtype, **statics):
-    table = jax.ShapeDtypeStruct(shape, dtype, sharding=mesh_lib.data_sharding(mesh, 2))
+def compiled_for(devices, fn, shape, dtype, **statics):
+    table = jax.ShapeDtypeStruct(shape, dtype, sharding=mesh_lib.data_sharding(devices, 2))
     return jax.jit(fn, static_argnames=tuple(statics)).lower(table, **statics).compile()
+
+
+CELL_BATCH = 100_000
+COMPILED_TABLES = pytest.mark.parametrize(
+    "rows, width, dtype",
+    [
+        (32_000_000, 100, np.float32),
+        (48_000_000, 100, np.float32),
+        (32_000_000, 40, np.int32),
+        (32_000_000, 40, np.float32),
+    ],
+    ids=["benchmark_cell", "48m_rows", "csr_indices", "csr_values"],
+)
+
+
+@cache
+def compiled_exchange(mesh, rows, width, dtype):
+    return compiled_for(
+        mesh, optimizer._exchange_batches_impl, (rows, width), dtype, batch=CELL_BATCH, mesh=mesh
+    )
 
 
 def instructions(compiled):
@@ -387,32 +465,125 @@ def instructions(compiled):
     return found
 
 
-@pytest.mark.parametrize(
-    "rows, width, dtype",
-    [
-        (32_000_000, 100, np.float32),
-        (48_000_000, 100, np.float32),
-        (32_000_000, 40, np.int32),
-        (32_000_000, 40, np.float32),
-    ],
-    ids=["benchmark_cell", "48m_rows", "csr_indices", "csr_values"],
-)
+@COMPILED_TABLES
 def test_compiled_for_four_v5e_the_exchange_is_one_all_to_all_by_xlas_name(four_v5e, rows, width, dtype):
     """A device trace's readers find collective time by XLA's own name of
     the op (`all-to-all`, perf/tracereduce.py); the table is never split
     along its rows by a loop over its columns; the temporaries stay under
     the general form's."""
-    batch = 100_000
     sharding = batched_sharding(four_v5e, 2)
-    exchange = compiled_for(
-        four_v5e, optimizer._exchange_batches_impl, (rows, width), dtype, batch=batch, sharding=sharding
-    )
+    exchange = compiled_exchange(four_v5e, rows, width, dtype)
     ops = instructions(exchange)
     exchanges = [name for name, opcode in ops if opcode == "all-to-all"]
     assert len(exchanges) == 1 and exchanges[0].startswith("all-to-all")
     assert sum(opcode == "while" for _, opcode in ops) == 2  # the slabs, and a slab's strips
     general = compiled_for(
         four_v5e, optimizer._layout_batches_impl, (rows, width), dtype,
-        n=rows, num_batches=rows // batch, batch=batch, b_pad=batch, d_pad=None, sharding=sharding,
+        n=rows, num_batches=rows // CELL_BATCH, batch=CELL_BATCH, b_pad=CELL_BATCH, d_pad=None, sharding=sharding,
     )
     assert exchange.memory_analysis().temp_size_in_bytes < 0.7 * general.memory_analysis().temp_size_in_bytes
+
+
+@COMPILED_TABLES
+def test_compiled_for_four_v5e_every_slab_arrives_in_its_final_place(four_v5e, rows, width, dtype):
+    """The strips are kept [batch][shard][column][row] in whole (8, 128) tiles
+    by the device's own default (no layout is asked for: a custom one does not
+    survive this installation's persistent compile cache), and they are the
+    buffer the loop of slabs writes into: no copy of the share puts the
+    batches in order or cuts the pad afterwards."""
+    exchange = compiled_exchange(four_v5e, rows, width, dtype)
+    layout = exchange.output_formats.strips.layout
+    assert tuple(layout.major_to_minor) == (0, 1, 2, 3) and tuple(layout.tiling) == ((8, 128),)
+    memory = exchange.memory_analysis()
+    width_pad, piece_pad = optimizer._padded_strip(width, CELL_BATCH // 4)
+    assert memory.output_size_in_bytes == rows // CELL_BATCH * width_pad * piece_pad * 4  # the pad is kept
+    assert memory.temp_size_in_bytes < 0.5 * memory.output_size_in_bytes  # two slabs staged, no second copy
+    after_the_loops = instructions(exchange)
+    after_the_loops = after_the_loops[max(i for i, (_, opcode) in enumerate(after_the_loops) if opcode == "while") + 1:]
+    assert {opcode for _, opcode in after_the_loops} <= {"get-tuple-element", "bitcast"}
+
+
+TABLE_TYPE = re.compile(r"\w+\[([\d,]*)\]\{([\d,]*)(?::T\((\d+),(\d+)\))?")
+HANDS_ON = {"parameter", "tuple", "get-tuple-element", "while", "fusion", "bitcast", "conditional", "call"}
+
+
+def bytes_touched(compiled, dims):
+    """{instruction: bytes of memory it touches} for every instruction of a
+    compiled program that reads a 32-bit array of `dims`, fusions' insides
+    included, and does more than hand it on. A `dynamic-slice` touches whole
+    tiles of the array as the device keeps it: one batch of a table kept
+    [column][batch][row] shares each (8, 128) tile with seven others."""
+    found = {}
+    for computation in re.split(r"\n(?=\S)", compiled.as_text()):
+        types = {}
+        for line in computation.splitlines():
+            m = re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = (\S+) ([a-z][\w\-]*)\((.*)", line)
+            if not m:
+                continue
+            name, kept, opcode, operands = m.groups()
+            types[name] = kept
+            if opcode in HANDS_ON:
+                continue
+            for operand in re.findall(r"%([\w.\-]+)", operands.split("), ")[0]):
+                t = TABLE_TYPE.match(types.get(operand, ""))
+                if not t or [int(d) for d in t.group(1).split(",") if d] != list(dims):
+                    continue
+                read = list(dims)
+                if opcode == "dynamic-slice":
+                    read = [int(d) for d in re.search(r"dynamic_slice_sizes=\{([\d,]+)\}", line).group(1).split(",")]
+                if t.group(3):  # tiles lie over the two minor-most axes in memory
+                    for axis, tile in zip((int(d) for d in t.group(2).split(",")), (int(t.group(4)), int(t.group(3)))):
+                        read[axis] = -(-read[axis] // tile) * tile
+                found[name] = 4 * math.prod(read)
+    return found
+
+
+def compiled_train(mesh, loss_func, dim, X_b):
+    """`_sgd_train` for laid-out features described by `X_b` (a pytree of
+    ShapeDtypeStructs), y and weights in the general form's array."""
+    nb, batch, _ = (X_b[0] if isinstance(X_b, tuple) else X_b).shape
+    y_b = jax.ShapeDtypeStruct((nb, batch), np.float32, sharding=batched_sharding(mesh, 1))
+    whole = NamedSharding(mesh, P())
+    coefficient = jax.ShapeDtypeStruct((dim,), np.float32, sharding=whole)
+    hyper = jax.ShapeDtypeStruct((5,), np.float32, sharding=whole)
+    train = lambda X, y, w, c, h: optimizer._sgd_train(X, y, w, c, loss_func, h, True, None)
+    return jax.jit(train).lower(X_b, y_b, y_b, coefficient, hyper).compile()
+
+
+def laid_out(exchange):
+    """What the exchange's executable hands on, as the train program's input."""
+    return jax.tree_util.tree_map(
+        lambda out, kept: jax.ShapeDtypeStruct(out.shape, out.dtype, sharding=kept),
+        exchange.out_info, exchange.output_formats,
+    )
+
+
+@pytest.mark.parametrize("rows", [32_000_000, 48_000_000], ids=["benchmark_cell", "48m_rows"])
+def test_compiled_for_four_v5e_the_dense_epoch_reads_its_batch_where_it_lies(four_v5e, rows):
+    """Batch k is read once an epoch, whole tiles of one batch and no other's,
+    and the row-dot and the gradient share what was read. From the general
+    form's array, which the v5e keeps [column][batch][row], the one slice
+    touches eight batches (the 34 ms a fit this PR removed)."""
+    X_b = laid_out(compiled_exchange(four_v5e, rows, 100, np.float32))
+    assert X_b.width == 100 and X_b.shape == (rows // CELL_BATCH, CELL_BATCH, 100)
+    share = (rows // CELL_BATCH, 1, 104, CELL_BATCH // 4)
+    batch_bytes = CELL_BATCH // 4 * 100 * 4
+    touched = bytes_touched(compiled_train(four_v5e, losses.BINARY_LOGISTIC_LOSS, 100, X_b), share)
+    assert touched and all(name.startswith("dynamic-slice") for name in touched)
+    assert sum(touched.values()) <= 2.5 * batch_bytes
+    if rows == 32_000_000:
+        general = jax.ShapeDtypeStruct(X_b.shape, X_b.dtype, sharding=batched_sharding(four_v5e, 2))
+        share = (rows // CELL_BATCH, CELL_BATCH // 4, 100)
+        touched = bytes_touched(compiled_train(four_v5e, losses.BINARY_LOGISTIC_LOSS, 100, general), share)
+        assert max(touched.values()) > 7 * batch_bytes
+
+
+def test_compiled_for_four_v5e_the_sparse_epoch_reads_its_batch_where_it_lies(four_v5e):
+    """Both padded-CSR leaves, 39 hashed fields sent as 40, a million coefficients."""
+    rows = 32_000_000
+    X_b = tuple(laid_out(compiled_exchange(four_v5e, rows, 40, dtype)) for dtype in (np.int32, np.float32))
+    share = (rows // CELL_BATCH, 1, 40, CELL_BATCH // 4)
+    batch_bytes = CELL_BATCH // 4 * 40 * 4
+    touched = bytes_touched(compiled_train(four_v5e, losses.SPARSE_BINARY_LOGISTIC_LOSS, 1_000_000, X_b), share)
+    assert len(touched) == 2 and all(name.startswith("dynamic-slice") for name in touched)  # one a leaf
+    assert max(touched.values()) <= 1.1 * batch_bytes
