@@ -67,6 +67,14 @@ ONE_DEVICE_RTOL = 1e-5
 # Engine loss vs the float64 numpy reference-semantics SGD (the last
 # recorded chip run gave 3.4e-5).
 LOSS_PARITY_RTOL = 1e-4
+# The loss-parity check's problem: the north-star hyperparameters
+# (conf/logisticregression-benchmark.json) and the table's seed.
+DIM = 100
+MAX_ITER = 20
+BATCH = 100_000
+LEARNING_RATE = 0.1
+TOL = 1e-6
+PARITY_SEED = 7
 
 
 def check(cond, message: str) -> None:
@@ -118,14 +126,88 @@ def _all_finite(x) -> bool:
     return bool(jnp.all(jnp.isfinite(jnp.asarray(x))))
 
 
+def device_facts():
+    """The device as jax reports it — stamped into the report and the result."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+
+
 # ---------------------------------------------------------------------------
 # train: the north-star fit at full width
 # ---------------------------------------------------------------------------
 
+def _numpy_reference_sgd(X, y, w, max_iter, batch, lr, tol):
+    """The reference's exact SGD semantics (SGD.java:82-292 +
+    TerminateOnMaxIterOrTol.java) in plain numpy: batch k = rows
+    [k*B,(k+1)*B) cycling; first epoch computes the gradient on the init
+    model before any update; one extra update after termination."""
+    n, d = X.shape
+    coeff = np.zeros(d, X.dtype)
+    grad = np.zeros(d, X.dtype)
+    wsum = 0.0
+    loss = np.inf
+    epoch = 0
+    while epoch < max_iter and loss > tol:
+        if wsum > 0:
+            coeff = coeff - (lr / wsum) * grad
+        k = epoch % max(1, -(-n // batch))
+        sl = slice(k * batch, min((k + 1) * batch, n))
+        Xk, yk, wk = X[sl], y[sl], w[sl]
+        margin = (Xk @ coeff) * (2.0 * yk - 1.0)
+        loss_sum = float(np.sum(wk * np.logaddexp(0.0, -margin)))
+        mult = wk * (-(2.0 * yk - 1.0) / (np.exp(margin) + 1.0))
+        grad = Xk.T @ mult
+        wsum = float(np.sum(wk))
+        loss = loss_sum / max(wsum, 1e-30)
+        epoch += 1
+    if wsum > 0:
+        coeff = coeff - (lr / wsum) * grad
+    return coeff, loss
+
+
+def loss_parity(num_rows):
+    """The same learnable problem through the engine and through the float64
+    numpy reference-semantics loop: (engine loss, reference loss, rel diff)."""
+    from flink_ml_tpu.ops.losses import BINARY_LOGISTIC_LOSS
+    from flink_ml_tpu.ops.optimizer import SGD
+
+    rng = np.random.default_rng(PARITY_SEED)
+    X = rng.random((num_rows, DIM), dtype=np.float32)
+    truth = rng.random(DIM, dtype=np.float32) - 0.5
+    y = (X @ truth > 0).astype(np.float32)
+    w = rng.random(num_rows, dtype=np.float32)
+    batch = min(BATCH, num_rows)
+
+    sgd = SGD(
+        max_iter=MAX_ITER,
+        learning_rate=LEARNING_RATE,
+        global_batch_size=batch,
+        tol=TOL,
+    )
+    _, loss, _ = sgd.optimize(np.zeros(DIM, np.float32), X, y, w, BINARY_LOGISTIC_LOSS)
+    _, ref_loss = _numpy_reference_sgd(
+        X.astype(np.float64),
+        y.astype(np.float64),
+        w.astype(np.float64),
+        MAX_ITER,
+        batch,
+        LEARNING_RATE,
+        TOL,
+    )
+    rel = abs(loss - ref_loss) / max(abs(ref_loss), 1e-30)
+    log(f"loss parity: engine {loss:.6f} vs reference-semantics {ref_loss:.6f} (rel {rel:.2e})")
+    return loss, ref_loss, rel
+
+
 def phase_train(rows=None, batch=None, parity_rows=200_000):
     import jax
 
-    import bench
     from flink_ml_tpu.benchmark import runner
     from flink_ml_tpu.ops.optimizer import SGD
     from flink_ml_tpu.parallel import mesh as mesh_lib
@@ -200,9 +282,8 @@ def phase_train(rows=None, batch=None, parity_rows=200_000):
     del table, out, pred, raw
 
     # loss parity on a learnable problem: the engine vs the float64 numpy
-    # reference-semantics SGD on the same schedule (bench.bench_loss_parity)
-    parity = bench.bench_loss_parity(parity_rows)
-    loss, ref_loss, loss_rel = parity["tpuLoss"], parity["referenceLoss"], parity["relDiff"]
+    # reference-semantics SGD on the same schedule
+    loss, ref_loss, loss_rel = loss_parity(parity_rows)
     check(math.isfinite(loss), f"non-finite loss {loss}")
     # zero coefficients score every row at log 2: a loss below it has fallen
     check(loss < math.log(2.0), f"loss {loss} did not fall below log 2")
@@ -544,7 +625,6 @@ def main() -> int:
 
     import jaxlib
 
-    import bench
     from flink_ml_tpu import config
     from flink_ml_tpu.obs import tracing
     from flink_ml_tpu.utils import metrics
@@ -567,7 +647,7 @@ def main() -> int:
         libtpu_version = getattr(libtpu, "__version__", "unknown")
     except ImportError:
         libtpu_version = "not importable"
-    device = bench.device_facts()
+    device = device_facts()
     log(
         f"device {device}; jax {jax.__version__}, jaxlib {jaxlib.__version__}, "
         f"libtpu {libtpu_version}; compile cache {cache_dir}"

@@ -4,9 +4,8 @@ The AOT program bank (compilebank.py, docs/performance.md §12) promises
 that a warmed serving process never traces or compiles on the request
 path: every kernel the dispatch path can reach must route through a
 bank-consulting funnel (``utils/lazyjit.py`` or ``compilebank.py``), so
-that a bank hit is a warm-loaded executable call and the
-``aotColdStart.serveTraceCount == 0`` CI pin holds by construction, not
-by luck.
+that a bank hit is a warm-loaded executable call and the zero-trace CI
+pin (scripts/coldstart_smoke.py) holds by construction, not by luck.
 
 This rule walks the v2 call graph from the serving dispatch roots
 (``MicroBatchServer`` and ``serve_stream``) and flags, in any reachable

@@ -239,17 +239,9 @@ def run_benchmark(name: str, entry: Dict) -> Dict:
         "swapCount": int(delta["counters"].get("lifecycle.swap", 0)),
         "rollbackCount": int(delta["counters"].get("lifecycle.rollback", 0)),
         "promoteRejected": int(delta["counters"].get("lifecycle.promoteRejected", 0)),
-        # serving-SLO evidence (serving.py + data/modelstore.py): the
-        # open-loop load-gen rates a serving entry sustained (0 for
-        # non-serving entries — the gauges only exist when a load
-        # generator set them), model-store page-ins this entry paid, and
-        # the compile count on its serving path — a saturationQps drop or
-        # a pageInCount/recompileCount jump between BENCH files is a
-        # serving regression (recompileCount is gated zero-tolerance for
-        # servingSlo in CI)
-        "offeredQps": float(delta["gauges"].get("serving.offeredQps", 0.0)),
-        "goodputQps": float(delta["gauges"].get("serving.goodputQps", 0.0)),
-        "saturationQps": float(delta["gauges"].get("serving.saturationQps", 0.0)),
+        # serving evidence (data/modelstore.py): the model-store page-ins
+        # this entry paid — a jump between two results of the same job is
+        # a serving regression
         "pageInCount": int(delta["counters"].get("modelstore.pageIn", 0)),
         # per-op collective traffic this entry traced (calls/bytes/chunks
         # from the accounted wrappers in parallel/collectives.py, plus the

@@ -15,9 +15,9 @@ serialized (``jax.experimental.serialize_executable``) to a versioned
 on-disk bank, and warm-loaded at process start. A bank hit calls the
 loaded executable directly: **no trace, no XLA compile** — the
 ``jit.traces`` and ``jit.compiles`` counters both stay flat, which is
-what makes the serving SLA's ``aotColdStart.serveTraceCount == 0``
-assertion (scripts/coldstart_smoke.py) and the zero-tolerance ``servingSlo.recompileCount``
-CI pin honest rather than merely lucky.
+what makes the serving SLA's zero-trace assertion
+(scripts/coldstart_smoke.py) and the flat ``jit.compiles`` of steady-state
+paging (tests/test_modelstore.py) honest rather than merely lucky.
 
 Integration is at the ``utils/lazyjit.py`` funnel (every accounted
 kernel consults the bank before tracing; a miss falls through to the

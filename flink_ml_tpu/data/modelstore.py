@@ -22,8 +22,9 @@ each model's kernel constants host<->HBM under an LRU byte budget
   operands* on the fused path: `FusedSegment.execute` re-reads
   `device_constants()` per dispatch and the plan-cache token excludes
   swap-capable array identities, so a page-out/page-in cycle re-uploads
-  the same avals into the same compiled program. The `servingSlo` bench
-  pins `jit.compiles` at 0 across steady-state paging.
+  the same avals into the same compiled program.
+  `tests/test_modelstore.py` pins `jit.compiles` flat across steady-state
+  paging.
 - **Admission is conservative.** Eviction is driven by the *host-side*
   byte estimate of each model's kernel constants, which (under jax's
   default x64-disabled canonicalization) is >= the device-resident bytes

@@ -40,9 +40,9 @@ what a live swap must never be allowed to skip:
 Fault sites (ckpt/faults.py): `lifecycle.promote` fires at promote entry
 (a trainer kill before anything durable happened) and `lifecycle.swap`
 fires between the snapshot write and the pointer swap (the mid-publish
-kill the resume contract covers). The chaos soak (tests/test_hot_swap.py,
-bench.py `hotSwapSoak`) composes both with flaky snapshot I/O,
-NaN-poisoned updates and overload bursts.
+kill the resume contract covers). The chaos soak (tests/test_hot_swap.py)
+composes both with flaky snapshot I/O, NaN-poisoned updates and overload
+bursts.
 
 Thread contract: `promote`/`rollback` are trainer-side and may run on one
 trainer thread; `record_serve_ok`/`record_guard_error` are serve-side.

@@ -170,13 +170,8 @@ BENCH_FIELDS = (
     "swapCount",
     "rollbackCount",
     "promoteRejected",
-    # the serving-SLO surface (PR 19): open-loop load-gen rates, model
-    # store paging, and the zero-tolerance recompile pin
-    "offeredQps",
-    "goodputQps",
-    "saturationQps",
+    # the serving surface (PR 19): model store paging
     "pageInCount",
-    "recompileCount",
 )
 
 

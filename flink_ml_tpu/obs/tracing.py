@@ -425,8 +425,9 @@ def install_jax_hooks() -> bool:
         # REAL XLA backend compiles only: program-bank executable loads
         # (compilebank.py) never fire this event — they tick the distinct
         # jit.bankLoads counter instead, which is what keeps the
-        # zero-tolerance servingSlo.recompileCount / aotColdStart CI pins
-        # honest when the bank satisfies a program without a compile.
+        # zero-compile pins (scripts/coldstart_smoke.py,
+        # tests/test_modelstore.py) honest when the bank satisfies a
+        # program without a compile.
         metrics.inc_counter("jit.compiles")
         metrics.record_time("jit.compile", duration)
         from . import hist

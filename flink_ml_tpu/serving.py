@@ -58,10 +58,9 @@ flakes. Mechanisms on top of the fusion planner:
    forming budget (`config.serving_form_budget_ms`), so throughput at
    saturation gets full buckets while latency at low offered QPS stays
    bounded by the budget. `batching="fixed"` is the classic baseline
-   (wait for a full batch, however long that takes) the `servingSlo`
-   bench compares against; results are bit-identical across all three
-   modes because the kernels are row-wise and the pad rows are copies of
-   real rows. Requests carry an optional `tenant`: a forming batch never
+   (wait for a full batch, however long that takes); results are
+   bit-identical across all three modes because the kernels are row-wise
+   and the pad rows are copies of real rows. Requests carry an optional `tenant`: a forming batch never
    coalesces across tenants, each tenant may route to its own model via
    a `data.modelstore.ModelStore` (HBM-paged under an LRU byte budget —
    far more models than fit on device serve from one mesh, zero
@@ -976,7 +975,7 @@ class MicroBatchServer:
         device slice compiles one XLA program per distinct (shape, span)
         pair, and continuous forming produces an open-ended set of those
         — steady-state paging would keep compiling, breaking the
-        zero-recompile contract the servingSlo bench pins. Push results
+        zero-recompile contract tests/test_modelstore.py pins. Push results
         are terminal per-request responses, so the one materialization
         here replaces the consumer's own later pull; an unpadded solo
         batch still retires device-resident, untouched."""

@@ -15,9 +15,9 @@ modes by a FRESH process each time:
   this is the CI gate.
 - ``baseline`` — the same fresh-process first serve with the bank off
   (every program traces + compiles), for the bank-on vs bank-off
-  cold-start walls the `aotColdStart` bench entry reports.
+  cold-start walls the ``bench`` mode prints.
 
-- ``bench`` — the `aotColdStart` entry: this process stays OFF jax (a
+- ``bench`` — the three above side by side: this process stays OFF jax (a
   chip belongs to one process at a time, so a parent that had touched
   jax would starve its children) and runs ``populate``, ``serve`` and
   ``baseline`` as three children, one at a time, against a temporary
@@ -191,11 +191,16 @@ def main(argv):
             np.asarray(out.column("norm"), dtype=np.float32)
         ).tobytes()
     ).hexdigest()
-    from bench import device_facts
+    import jax
 
+    devices = jax.devices()
     payload = {
         "mode": mode,
-        "device": device_facts(),
+        "device": {
+            "platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices),
+        },
         "coldStartMs": cold_start_ms,
         "firstServeMs": first_serve_ms,
         "serveTraceCount": float(delta.get("jit.traces", 0)),

@@ -1,18 +1,14 @@
-"""Bring-up contracts (ISSUE 21): where the compile cache goes, and a
-benchmark driver that can fail.
+"""Bring-up contracts (ISSUE 21): where the compile cache goes, and which
+native build is loaded.
 
 - One function places the persistent compile cache. With
   JAX_COMPILATION_CACHE_DIR set the program sets no directory in code
   (jax read the variable at import); unset, it is `.jax_cache` at the root
   of the checkout — from any working directory.
-- `bench.py main` still prints its line when a stage raises, stamps the
-  device into it, names budget-skipped stages as such, and returns
-  non-zero.
+- The native library's file name carries a hash of its sources and flags.
 """
 
-import json
 import os
-import sys
 
 import jax
 import pytest
@@ -20,9 +16,6 @@ import pytest
 from flink_ml_tpu import config
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, _ROOT)
-
-import bench  # noqa: E402
 
 
 @pytest.fixture
@@ -51,52 +44,6 @@ def test_cache_dir_defaults_to_checkout_from_any_cwd(monkeypatch, config_updates
     assert config.enable_compilation_cache() == expected
     assert ("jax_compilation_cache_dir", expected) in config_updates
     assert ("jax_persistent_cache_min_compile_time_secs", 0.0) in config_updates
-
-
-def test_bench_main_prints_its_line_and_fails_when_a_stage_raises(monkeypatch, capsys):
-    monkeypatch.setattr(config, "enable_compilation_cache", lambda: None)
-    for name in dir(bench):
-        if name.startswith("bench_"):
-            monkeypatch.setattr(bench, name, lambda *a, **k: {"totalTimeMs": 1.0})
-    monkeypatch.setattr(
-        bench, "bench_logreg",
-        lambda rows, in_budget: {"throughputPerChip": 5.0, "inputThroughput": 5.0},
-    )
-
-    def boom():
-        raise RuntimeError("stage exploded")
-
-    monkeypatch.setattr(bench, "bench_kmeans", boom)
-    # a budget that is spent once the always-run first stage is done
-    monkeypatch.setenv("BENCH_BUDGET_S", "100")
-    rc = bench.main(["--skip-cpu"])
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rc != 0
-    assert line["failedStages"] == ["kmeans"]
-    assert "stage exploded" in line["details"]["kmeans"]["failed"]
-    assert line["details"]["cpuBaseline"] == {"skipped": "--skip-cpu"}
-    assert line["details"]["sparseWideLR"] == {"totalTimeMs": 1.0}
-    assert line["value"] == 5.0
-    assert line["device"] == {
-        "platform": jax.devices()[0].platform,
-        "kind": jax.devices()[0].device_kind,
-        "count": len(jax.devices()),
-    }
-
-    # no budget left: every later stage is NAMED as skipped, none raised
-    monkeypatch.setenv("BENCH_BUDGET_S", "0")
-    rc = bench.main([])
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rc == 0 and line["failedStages"] == []
-    assert line["details"]["logisticregression"]["throughputPerChip"] == 5.0
-    assert line["details"]["kmeans"] == {"skipped": "budget"}
-
-
-def test_unknown_device_kind_has_no_peaks():
-    # the CPU substrate is not in the table: an error, not a default
-    with pytest.raises(KeyError, match="no published peaks"):
-        bench._device_peaks()
-    assert bench.DEVICE_PEAKS["TPU v5 lite"] == {"flops": 197e12, "hbmGBps": 819.0}
 
 
 def test_native_library_is_keyed_by_source_bytes(monkeypatch, tmp_path):

@@ -14,8 +14,7 @@ path in pipeline.py/api.py):
   and quarantines the trainer, and the JobSnapshot meta contract makes a
   killed+resumed train-while-serve job re-publish the same version;
 - the chaos soak composes ckpt fault sites with the new
-  lifecycle.promote/lifecycle.swap sites — the deterministic tier-1
-  variant of bench.py's `hotSwapSoak`.
+  lifecycle.promote/lifecycle.swap sites, with deterministic invariants.
 """
 
 import numpy as np
@@ -325,7 +324,7 @@ def test_resume_republishes_persisted_version_not_zero(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# the chaos soak (deterministic tier-1 variant of bench.py hotSwapSoak)
+# the chaos soak (deterministic invariants)
 # ---------------------------------------------------------------------------
 
 def test_train_while_serving_chaos_soak(tmp_path):

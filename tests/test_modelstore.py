@@ -282,6 +282,58 @@ def test_server_submit_unregistered_tenant_is_typed():
     assert h.modelStore is not None and h.modelStore["models"] == 1
 
 
+def test_round_robin_tenants_page_through_one_server_without_recompiles():
+    """Six tenants whose constants together exceed the store's budget,
+    served round-robin by ONE continuous-batching server: the store pages,
+    `hbm.live.model` never passes the budget, every request retires ok and
+    `jit.compiles` stays flat once each tenant's plan has compiled."""
+    from flink_ml_tpu.obs import tracing
+
+    tracing.install_jax_hooks()
+    d, n_tenants, n_requests = 512, 6, 48
+    tenants = [f"tenant{i}" for i in range(n_tenants)]
+
+    def tenant_model():
+        scaler = _scaler(d).set_output_col("features")
+        return PipelineModel([scaler, _olr(d)])
+
+    per_model = _est(tenant_model())
+    budget = int(per_model * 3.3)  # room for three of the six
+    store = ModelStore(budget_bytes=budget)
+    for t in tenants:
+        store.register(t, tenant_model(), quota=16)
+
+    def serve_round_robin(count, start):
+        # admission and every tenant's quota exceed the requests in flight:
+        # nothing is refused, and the results wait in the server's buffer
+        server = MicroBatchServer(
+            store=store, buckets=(8, 32), batching="continuous", form_rows=32, admission=64
+        )
+        peak = 0
+        for i in range(count):
+            server.submit(_feature_batch(4, d), tenant=tenants[(start + i) % n_tenants])
+            peak = max(peak, memledger.live_bytes("model"))
+        server.close()
+        outputs = list(server.results())
+        return outputs, max(peak, memledger.live_bytes("model"))
+
+    for t in tenants:  # each tenant's fused plan compiles once per bucket shape
+        list(
+            MicroBatchServer(store.acquire(t), buckets=(8, 32)).serve(
+                iter([_feature_batch(8, d), _feature_batch(32, d)])
+            )
+        )
+    serve_round_robin(2 * n_tenants, start=0)
+    compiles = metrics.get_counter("jit.compiles", 0)
+    page_ins = metrics.get_counter("modelstore.pageIn", 0)
+    outputs, peak = serve_round_robin(n_requests, start=1)
+    assert len(outputs) == n_requests and all(r.status == "ok" for r in outputs)
+    assert metrics.get_counter("jit.compiles", 0) == compiles
+    assert metrics.get_counter("modelstore.pageIn", 0) > page_ins
+    assert peak <= budget
+    store.check_ledger_parity()
+
+
 def test_server_requires_model_or_store():
     with pytest.raises(TypeError, match="model"):
         MicroBatchServer()

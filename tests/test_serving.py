@@ -493,10 +493,14 @@ def _push_all(server, batches, tenant=None):
     return seqs, {r.seq: r for r in server.results()}
 
 
-def test_continuous_bit_identical_to_request_mode():
+@pytest.mark.parametrize(
+    "batching,form_rows", [("continuous", 32), ("fixed", 8)], ids=["continuous", "fixed"]
+)
+def test_continuous_bit_identical_to_request_mode(batching, form_rows):
     """ISSUE 19 acceptance: continuous batching returns bit-identical
     per-request rows — coalescing is a scheduling decision, never a
-    numerics decision (same bucket padding, same fused plan)."""
+    numerics decision (same bucket padding, same fused plan). So does the
+    fixed-batch baseline it is compared with."""
     pm = _scaler_pipeline()
     sizes = [3, 5, 2, 8, 1, 4, 7, 2]
     batches = _batches(sizes)
@@ -507,8 +511,8 @@ def test_continuous_bit_identical_to_request_mode():
         in_flight=2,
         admission=16,
         buckets=(8, 32),
-        batching="continuous",
-        form_rows=32,
+        batching=batching,
+        form_rows=form_rows,
         form_budget_ms=20.0,
     )
     _, got = _push_all(cont, batches)

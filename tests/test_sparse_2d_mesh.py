@@ -330,6 +330,29 @@ class TestBeyondBudget:
                      global_batch_size=128)
         memledger.reset()
 
+    def test_ledger_reads_one_shard_of_a_feature_sharded_carry(self):
+        """What the ledger holds for a staged carry is ONE device's share:
+        on a (2, 4) mesh a feature-sharded carry costs a quarter of the
+        replicated one, never the sum across shards."""
+        from flink_ml_tpu.parallel import prefetch as h2d
+
+        dim, model_shards = 40_000, 4
+
+        def staged_bytes(mesh):
+            memledger.reset()
+            staged = h2d.stage_to_device(
+                np.zeros(dim, np.float32), mesh_lib.model_sharding(mesh), category="optimizer"
+            )
+            live = memledger.live_bytes("optimizer")
+            del staged
+            memledger.reset()
+            return live
+
+        per_shard = staged_bytes(mesh_lib.create_mesh_2d(model_shards))
+        replicated = staged_bytes(mesh_lib.create_mesh(("data",)))
+        assert replicated == 4 * dim
+        assert per_shard * model_shards == replicated
+
 
 # ---------------------------------------------------------------------------
 # 2D checkpoints through the multi-host coordinator + elastic resume
