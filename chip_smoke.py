@@ -8,8 +8,11 @@ at a time), with every phase fatal:
 
   train      run_benchmark on the shipped config (fit + transform), the
              same fit driven directly for finite outputs of the expected
-             shape, and the engine's loss against the numpy
-             reference-semantics SGD at 200k rows
+             shape, the engine's loss against the numpy
+             reference-semantics SGD at 200k rows, and LogisticRegression,
+             LinearSVC and LinearRegression at 1M x 100 on one shard as
+             the estimator fits them (on the chip: the one-read dense
+             epoch, ops/dense_epoch.py) against the reduce form
   loops      the other training loops at 1M rows — the stream loop's
              per-epoch DrainQueue path with donated carries and the
              checkpointed chunked path, both under whole_fit "off",
@@ -67,6 +70,10 @@ ONE_DEVICE_RTOL = 1e-5
 # Engine loss vs the float64 numpy reference-semantics SGD (the last
 # recorded chip run gave 3.4e-5).
 LOSS_PARITY_RTOL = 1e-4
+# A one-shard dense fit whose epochs read their batch once (the Pallas
+# kernel of ops/dense_epoch.py) vs the same fit on the reduce form: the
+# same float32 products, summed in another order.
+DENSE_FORMS_RTOL = 1e-5
 # The loss-parity check's problem: the north-star hyperparameters
 # (conf/logisticregression-benchmark.json) and the table's seed.
 DIM = 100
@@ -205,7 +212,85 @@ def loss_parity(num_rows):
     return loss, ref_loss, rel
 
 
-def phase_train(rows=None, batch=None, parity_rows=200_000):
+def dense_forms(rows, batch, dim=DIM):
+    """The three dense linear estimators on ONE shard, as a user fits them,
+    against `_sgd_train_flat` on the reduce form over the same rows; and the
+    two loss functions called directly on one batch that starts off the
+    lanes. On the chip the estimator's fit must have taken the one-read
+    kernel (`dense_epoch.one_pass` ticks); anywhere else it keeps the reduce
+    form, and the direct call runs the kernel's own code interpreted."""
+    import jax
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.models import _linear
+    from flink_ml_tpu.models.classification.linearsvc import LinearSVC
+    from flink_ml_tpu.models.classification.logisticregression import LogisticRegression
+    from flink_ml_tpu.models.regression.linearregression import LinearRegression
+    from flink_ml_tpu.ops import dense_epoch, losses, optimizer
+    from flink_ml_tpu.parallel import mesh as mesh_lib
+    from flink_ml_tpu.table import Table
+
+    host = _learnable_table(rows, dim, PARITY_SEED)
+    device = jax.devices()[0]
+    X = jax.device_put(host.column("features"), device)
+    y = jax.device_put(host.column("label"), device)
+    table = Table({"features": X, "label": y})
+    on_chip = mesh_lib.on_tpu(X)
+    n = jnp.asarray(rows, jnp.int32)
+    out = {}
+    with mesh_lib.use_mesh(mesh_lib.create_mesh(devices=[device])):
+        for estimator, loss_func in (
+            (LogisticRegression, losses.BINARY_LOGISTIC_LOSS),
+            (LinearSVC, losses.HINGE_LOSS),
+            (LinearRegression, losses.LEAST_SQUARE_LOSS),
+        ):
+            name = estimator.__name__
+            stage = (
+                estimator().set_max_iter(MAX_ITER).set_learning_rate(LEARNING_RATE)
+                .set_global_batch_size(batch).set_tol(TOL)
+            )
+            before = _counters()
+            coefficient = np.asarray(stage.fit(table).coefficient)
+            taken = {
+                form: _counter_delta(before, "dense_epoch." + form) for form in ("one_pass", "reduce")
+            }
+            check(
+                taken == ({"one_pass": 1, "reduce": 0} if on_chip else {"one_pass": 0, "reduce": 1}),
+                f"{name}: the dense epoch's form was counted {taken} on {device.platform}",
+            )
+            t0 = time.perf_counter()
+            np.asarray(stage.fit(table).coefficient)
+            fit_ms = 1e3 * (time.perf_counter() - t0)
+            packed = optimizer._sgd_train_flat(
+                X, y, jnp.zeros((0,), jnp.float32), jnp.zeros((dim,), jnp.float32), loss_func,
+                batch, False, n, _linear._optimizer_for(stage)._hyper(), False, False, False,
+            )
+            _, reduce_form, _, epochs = optimizer.unpack_train_result(np.asarray(packed), dim)
+            check(epochs == MAX_ITER, f"{name}: {epochs} epochs on the reduce form")
+            check(_all_finite(coefficient) and np.any(coefficient != 0), f"{name}: coefficient {coefficient[:4]}")
+            coef_rel = float(np.max(np.abs(coefficient - reduce_form)) / np.max(np.abs(reduce_form)))
+            check(coef_rel <= DENSE_FORMS_RTOL, f"{name}: one read vs reduce form coefficients: rel {coef_rel:.2e}")
+            # one epoch's sums by either loss function, called directly, on
+            # a batch that starts off the lanes unless the batch is whole ones
+            start = 3 * batch
+            coeff = jnp.asarray(reduce_form, jnp.float32)
+            one_read = jax.jit(
+                lambda X, y, c: dense_epoch.one_pass(
+                    loss_func.pointwise, X.T, y, None, c, jnp.int32(start), n, batch, device.platform != "tpu"
+                )
+            )(X, y, coeff)
+            reduced = jax.jit(
+                lambda X, y, c: loss_func(
+                    X[start:start + batch], y[start:start + batch], jnp.ones((batch,), jnp.float32), c
+                )
+            )(X, y, coeff)
+            sums_rel = max(rel_diff(np.atleast_1d(a), np.atleast_1d(b)) for a, b in zip(one_read, reduced))
+            check(sums_rel <= DENSE_FORMS_RTOL, f"{name}: one read vs reduce form sums of a batch: rel {sums_rel:.2e}")
+            out[name] = {"onePass": bool(taken["one_pass"]), "fitMs": fit_ms, "coefRel": coef_rel, "sumsRel": sums_rel}
+    return out
+
+
+def phase_train(rows=None, batch=None, parity_rows=200_000, forms_rows=1_000_000):
     import jax
 
     from flink_ml_tpu.benchmark import runner
@@ -291,9 +376,11 @@ def phase_train(rows=None, batch=None, parity_rows=200_000):
         loss_rel <= LOSS_PARITY_RTOL,
         f"loss {loss} vs reference {ref_loss}: rel {loss_rel:.2e} > {LOSS_PARITY_RTOL}",
     )
+    forms = dense_forms(forms_rows, stage.get_global_batch_size())
     return {
         "rows": rows,
         "dim": dim,
+        "denseForms": forms,
         "benchmarkTotalTimeMs": result["totalTimeMs"],
         "benchmarkPhaseTimesMs": result["phaseTimesMs"],
         "hostSyncCount": result["hostSyncCount"],

@@ -17,9 +17,25 @@ CPU backend (1–2 ULP drift for d >= 8), which would break the fleet
 training contract — every fleet member bit-identical to its solo fit
 (fleet.py, pinned by tests/test_fleet.py). The reduce form lowers to the
 same per-row accumulation order whether or not a leading batch dimension
-is present, so solo and vmapped fits share bits. XLA fuses the
-multiply into the reduction, and on TPU the reduce form is rewritten to
-the MXU anyway, so the hot path does not regress.
+is present, so solo and vmapped fits share bits.
+
+Which form runs where. XLA fuses the multiply into each reduction, and on
+the TPU they stay what they are, two VPU reductions (`multiply_reduce_fusion`
+in a device trace; nothing is rewritten to the MXU), each of which streams
+the batch from HBM: 93% of a one-chip dense fit's device time went to
+reading X twice (PERF.md §5, §6, PR 30). So on the TPU the one-shard flat
+loop takes its epoch's sums from `ops/dense_epoch.one_pass` instead, a
+Pallas kernel that reads the batch once and forms the row-dot, this
+module's `pointwise` and the gradient from the tile in fast memory, for a
+narrow float32 table the device keeps rows-minor
+(`optimizer._can_one_pass` decides, from the array alone). Everything else
+keeps the reduce form: laid-out batches on several shards (GSPMD; that
+program reads its batch once already), the fleet's `vmap`, sparse rows, a
+wide or 16-bit table, and every fit on the CPU, where the bit-parity
+contracts above live and a second read of X costs nothing like it does on
+the chip. The kernel sums the same float32 products in another order (rows
+by lane, then the lanes), so its fits agree with the reduce form's to
+rounding, not to the bit.
 """
 
 from __future__ import annotations

@@ -45,6 +45,7 @@ from ..parallel import mesh as mesh_lib
 from ..parallel import prefetch as h2d
 from ..utils import metrics
 from ..utils.lazyjit import lazy_jit
+from . import dense_epoch
 from .losses import LossFunc
 
 
@@ -190,6 +191,33 @@ def _can_exchange(arr, n, batch, shards, d_pad, mesh) -> bool:
         and n // (shards * batch) % mesh_lib.SUBLANES == 0
         and arr.sharding.is_equivalent_to(mesh_lib.data_sharding(mesh, 2), 2)
         and mesh_lib.rows_minor(arr)
+    )
+
+
+def _can_one_pass(X, loss_func, mesh) -> bool:
+    """Whether a one-shard flat fit's epochs take `dense_epoch.one_pass`, one
+    read of the batch, for `loss_func`'s two reductions over it. The kernel
+    is written for a dense table the TPU keeps rows-minor
+    (`mesh_lib.rows_minor`: narrower than a tile's lanes; a wide table kept
+    rows-major gives it no [column, row] view), of a 32-bit float type (a
+    bfloat16 tile is another), with at least one 1-D tile of rows, on ONE
+    data shard (a custom call has no partitioning rule, and `_sgd_train`
+    already reads its batch once), for a loss built on a `pointwise`. All
+    read off the array, the mesh and the loss, nothing a user sets; the one
+    place that decides, counted as `dense_epoch.one_pass` or
+    `dense_epoch.reduce` a dense fit. What it turns away keeps `dense_dot`
+    and `dense_grad` as they are: so every fit on the CPU, whose bit-parity
+    contracts between solo, fleet, chunked and whole-fit programs stand on
+    the reduce form."""
+    return (
+        isinstance(X, jax.Array)
+        and X.ndim == 2
+        and X.dtype == jnp.float32
+        and X.shape[0] >= dense_epoch.GROUP
+        and loss_func.pointwise is not None
+        and mesh_lib.num_data_shards(mesh) == 1
+        and mesh_lib.on_tpu(X)
+        and mesh_lib.rows_minor(X)
     )
 
 
@@ -371,9 +399,12 @@ def _pack_train_result(coeff, criteria, epochs, flag=None, pack_sharding=None):
 
 @partial(
     lazy_jit,
-    static_argnames=("loss_func", "batch", "has_weights", "check_labels"),
+    static_argnames=("loss_func", "batch", "has_weights", "check_labels", "one_pass", "interpret"),
 )
-def _sgd_train_flat(X, y, w, init_coeff, loss_func, batch, has_weights, n, hyper, check_labels):
+def _sgd_train_flat(
+    X, y, w, init_coeff, loss_func, batch, has_weights, n, hyper, check_labels,
+    one_pass=False, interpret=False,
+):
     """Single-data-shard variant of `_sgd_train` that slices each epoch's
     batch straight out of the FLAT row-major arrays with a dynamic slice.
 
@@ -383,11 +414,19 @@ def _sgd_train_flat(X, y, w, init_coeff, loss_func, batch, has_weights, n, hyper
     the result pack and (for classifiers) the label-validity check are
     fused into it. Rows are pre-padded to a batch multiple; absent
     weights are synthesized in-loop as (row_index < n) so padding rows
-    contribute nothing and no separate weights program runs."""
+    contribute nothing and no separate weights program runs.
+
+    With `one_pass` (`_can_one_pass`'s word, `_stage_flat` asks) nothing is
+    sliced: an epoch's loss is `dense_epoch.one_pass` over the whole table,
+    which reads batch k where it lies, once; the table is handed over the
+    other way round and the columns as one row, views of the same bytes
+    made once, outside the loop. `interpret` is for a table off the TPU."""
     num_batches = y.shape[0] // batch
     d = init_coeff.shape[0]
     dtype = _feature_dtype(X)
     max_iter, tol, lr, reg, elastic_net = _unpack_hyper(hyper, dtype)
+    if one_pass:
+        views = (X.T, y, w if has_weights else None)
 
     def cond(state):
         _, _, _, epoch, criteria = state
@@ -397,14 +436,22 @@ def _sgd_train_flat(X, y, w, init_coeff, loss_func, batch, has_weights, n, hyper
         coeff, grad, wsum, epoch, _ = state
         k = jnp.mod(epoch, num_batches)
         start = k * batch
-        Xk = _slice_rows(X, start, batch)
-        yk = lax.dynamic_slice_in_dim(y, start, batch, 0)
-        if has_weights:
-            wk = lax.dynamic_slice_in_dim(w, start, batch, 0)
+        if one_pass:
+            Xk, yk, wk = views
+            loss = partial(
+                dense_epoch.one_pass, loss_func.pointwise,
+                start=start, n=n, batch=batch, interpret=interpret,
+            )
         else:
-            wk = ((jnp.arange(batch) + start) < n).astype(dtype)
+            loss = loss_func
+            Xk = _slice_rows(X, start, batch)
+            yk = lax.dynamic_slice_in_dim(y, start, batch, 0)
+            if has_weights:
+                wk = lax.dynamic_slice_in_dim(w, start, batch, 0)
+            else:
+                wk = ((jnp.arange(batch) + start) < n).astype(dtype)
         carry, criteria = _epoch_step(
-            Xk, yk, wk, (coeff, grad, wsum, epoch), loss_func, lr, reg, elastic_net
+            Xk, yk, wk, (coeff, grad, wsum, epoch), loss, lr, reg, elastic_net
         )
         return carry + (criteria,)
 
@@ -1548,6 +1595,9 @@ class SGD:
         memledger.track((X_f, y_f, w_f), "streamSegments")
         from ..parallel import dispatch
 
+        one_pass = _can_one_pass(X_f, loss_func, mesh)
+        if not isinstance(X_f, tuple):
+            metrics.inc_counter("dense_epoch.one_pass" if one_pass else "dense_epoch.reduce")
         return partial(
             dispatch.timed_dispatch,
             _sgd_train_flat,
@@ -1561,6 +1611,10 @@ class SGD:
             jnp.asarray(n, jnp.int32),
             self._hyper(),
             validate_labels,
+            one_pass,
+            # the kernel's own code, interpreted, for a table that lies
+            # anywhere else (a test that says `on_tpu` of a CPU array)
+            one_pass and next(iter(X_f.devices())).platform != "tpu",
             start=0, end=self.max_iter,
         )
 
@@ -1852,6 +1906,9 @@ class SGD:
                 layout(stage(X[1]), n, num_batches, B, b_pad, None, csr_sharding),
             )
         else:
+            # laid-out batches are read by the reduce form, whatever loop
+            # reads them (`_can_one_pass` is asked by `_stage_flat` alone)
+            metrics.inc_counter("dense_epoch.reduce")
             X_b = layout(
                 stage(X),
                 n,

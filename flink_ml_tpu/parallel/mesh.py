@@ -219,8 +219,8 @@ def replicate(mesh: Mesh, array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# how a device keeps an array (read by the batch layout's exchange,
-# ops/optimizer.py)
+# how and where a device keeps an array (read by the batch layout's exchange
+# and by the dense epoch's choice of form, ops/optimizer.py)
 # ---------------------------------------------------------------------------
 # the TPU's tile over the two minor axes of an array of a 32-bit type:
 # 8 sublanes by 128 lanes (narrower types pack along the sublanes)
@@ -234,6 +234,13 @@ def rows_minor(arr: jax.Array) -> bool:
     contiguous run of memory and a reshape that splits the rows is not
     free. Read off the array; the CPU and a wide table keep rows major."""
     return arr.ndim == 2 and arr.format.layout.major_to_minor[-1] == 0
+
+
+def on_tpu(arr: jax.Array) -> bool:
+    """Whether every shard of the array lies on a TPU: where a Pallas TPU
+    kernel over it compiles, and anywhere else is interpreted. Read off the
+    array (`ops/optimizer._can_one_pass`)."""
+    return all(device.platform == "tpu" for device in arr.devices())
 
 
 # ---------------------------------------------------------------------------

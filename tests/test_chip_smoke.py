@@ -41,9 +41,25 @@ def test_result_line_holds_exactly_ok_and_the_device():
 
 
 def test_train_phase_toy():
-    out = chip_smoke.phase_train(rows=4096, batch=512, parity_rows=2000)
+    out = chip_smoke.phase_train(rows=4096, batch=512, parity_rows=2000, forms_rows=4096)
     assert out["rows"] == 4096 and out["lossRelDiff"] <= chip_smoke.LOSS_PARITY_RTOL
     assert out["batchesSpanEveryDevice"] is True
+    # off the chip the estimators keep the reduce form; the kernel ran interpreted, called directly
+    assert {name: form["onePass"] for name, form in out["denseForms"].items()} == {
+        "LogisticRegression": False, "LinearSVC": False, "LinearRegression": False,
+    }
+    assert all(form["sumsRel"] <= chip_smoke.DENSE_FORMS_RTOL for form in out["denseForms"].values())
+
+
+def test_dense_forms_toy_as_the_chip_takes_them(monkeypatch):
+    """What the chip runs: the estimators' fits on the one-read kernel (here
+    interpreted; batches of 96 rows start off the lanes) against the reduce form."""
+    from flink_ml_tpu.parallel import mesh as mesh_lib
+
+    monkeypatch.setattr(mesh_lib, "on_tpu", lambda arr: True)
+    monkeypatch.setattr(mesh_lib, "rows_minor", lambda arr: arr.ndim == 2)
+    forms = chip_smoke.dense_forms(22 * 96, 96, dim=10)
+    assert all(form["onePass"] and form["coefRel"] <= chip_smoke.DENSE_FORMS_RTOL for form in forms.values())
 
 
 def test_loops_sparse_and_one_device_phases_toy():

@@ -587,3 +587,54 @@ def test_compiled_for_four_v5e_the_sparse_epoch_reads_its_batch_where_it_lies(fo
     touched = bytes_touched(compiled_train(four_v5e, losses.SPARSE_BINARY_LOGISTIC_LOSS, 1_000_000, X_b), share)
     assert len(touched) == 2 and all(name.startswith("dynamic-slice") for name in touched)  # one a leaf
     assert max(touched.values()) <= 1.1 * batch_bytes
+
+
+# --- one chip: the flat loop's dense epoch as one read (ops/dense_epoch.py) ------
+
+
+def compiled_flat(four_v5e, rows, loss_func, one_pass, has_weights=False):
+    """`_sgd_train_flat` for a 100-wide float32 table on ONE described v5e."""
+    from jax.sharding import SingleDeviceSharding
+
+    chip = SingleDeviceSharding(four_v5e.devices.flat[0])
+
+    def on_chip(shape, dtype=np.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    train = lambda X, y, w, c, n, h: optimizer._sgd_train_flat(
+        X, y, w, c, loss_func, CELL_BATCH, has_weights, n, h, True, one_pass, False
+    )
+    weights = on_chip((rows if has_weights else 0,))
+    return jax.jit(train).lower(
+        on_chip((rows, 100)), on_chip((rows,)), weights, on_chip((100,)), on_chip((), np.int32), on_chip((5,))
+    ).compile()
+
+
+@pytest.mark.parametrize(
+    "rows, loss, has_weights",
+    [
+        (20_000_000, "BINARY_LOGISTIC_LOSS", False),  # the benchmark's cell
+        (1_000_000, "HINGE_LOSS", True),  # chip_smoke's; 1e6 rows are no whole tiles of anything
+        (1_000_000, "LEAST_SQUARE_LOSS", False),
+    ],
+    ids=["benchmark_cell", "hinge_weighted_1m", "least_square_1m"],
+)
+def test_compiled_for_a_v5e_the_one_read_epoch_copies_no_table(four_v5e, rows, loss, has_weights):
+    """The kernel is in the flat program (one `tpu_custom_call`, under the
+    kernel's name), it is handed the table the other way round as the same
+    bytes (a bitcast of the parameter, which the v5e keeps rows-minor), and
+    the program holds no temporary the size of the table or of a column; the
+    reduce form's program holds no kernel."""
+    one_read = compiled_flat(four_v5e, rows, getattr(losses, loss), True, has_weights)
+    text = one_read.as_text()
+    assert f"f32[{rows},100]{{0,1:T(8,128)}} parameter(0)" in text  # rows-minor, by the device's own default
+    calls = [(name, opcode) for name, opcode in instructions(one_read) if opcode == "custom-call"]
+    assert len(calls) == 1 and calls[0][0].startswith("dense_epoch_one_pass") and "tpu_custom_call" in text
+    assert re.search(rf"f32\[100,{rows}\]{{1,0:T\(8,128\)}} bitcast\(", text)
+    assert not re.search(rf"= \w+\[[\d,]*{rows}[\d,]*\]\S* (copy|transpose)\(", text)
+    memory = one_read.memory_analysis()
+    assert memory.argument_size_in_bytes >= rows * 104 * 4
+    assert memory.temp_size_in_bytes < 2 << 20  # a column of 1e6 rows is 4 MB, the table 416
+    if rows == 20_000_000:
+        reduce_form = compiled_flat(four_v5e, rows, getattr(losses, loss), False)
+        assert not any(opcode == "custom-call" for _, opcode in instructions(reduce_form))

@@ -82,6 +82,9 @@ def toy_fit(config_name: str, shards: int) -> dict:
         # the CPU keeps a table's rows major and turns the exchange away: say
         # of every table what the TPU says of a narrow one
         patch.setattr(mesh_lib, "rows_minor", lambda arr: arr.ndim == 2)
+        # ... and is no TPU, where the dense epoch keeps the reduce form: say
+        # that too, and the one-shard fit takes the kernel, interpreted
+        patch.setattr(mesh_lib, "on_tpu", lambda arr: True)
         # another test of this process may have run the same program at the
         # same shapes: the cold fit is this test's own
         jax.clear_caches()
