@@ -21,6 +21,10 @@ at a time), with every phase fatal:
   sparse     wide sparse LR at 1M rows, dim 1e6, 39 nnz on the lax
              gather/scatter path, one epoch checked against numpy at
              full width
+  online     (one device only) OnlineLogisticRegression over a short
+             device-born sparse stream, dim 2e6, 39 nnz, batches of 4,096:
+             a version a batch, nothing read back while they are folded,
+             the coefficient against numpy's FTRL-Proximal in float64
   serve      Pipeline([StandardScaler, LogisticRegression]) at d=100
              behind MicroBatchServer in continuous mode, equal to
              PipelineModel.transform; then the same from a populated AOT
@@ -571,6 +575,73 @@ def phase_sparse(rows=1_000_000, dim=1_000_000, nnz=39, batch=100_000):
 
 
 # ---------------------------------------------------------------------------
+# online: the unbounded loop over a sparse stream, state on the device
+# ---------------------------------------------------------------------------
+
+def _numpy_ftrl(batches, dim, alpha=0.1, beta=0.1):
+    """FTRL-Proximal with reg 0 as OnlineLogisticRegression ships it, float64:
+    per coordinate the mean gradient over the rows that hold it, and the
+    update only where a row does."""
+    w, z, n = np.zeros(dim), np.zeros(dim), np.zeros(dim)
+    for idx, val, y in batches:
+        p = 1.0 / (1.0 + np.exp(-np.sum(val * w[idx], axis=1)))
+        grad, count = np.zeros(dim), np.zeros(dim)
+        np.add.at(grad, idx, val * (p - y)[:, None])
+        np.add.at(count, idx, 1.0)
+        held = count > 0
+        g = grad[held] / count[held]
+        sigma = (np.sqrt(n[held] + g * g) - np.sqrt(n[held])) / alpha
+        z[held] += g - sigma * w[held]
+        n[held] += g * g
+        w[held] = -z[held] / ((beta + np.sqrt(n[held])) / alpha)
+    return w
+
+
+def phase_online(dim=2_000_000, nnz=39, batch=4096, batches=8):
+    import jax
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.linalg import DenseVector
+    from flink_ml_tpu.models.classification.onlinelogisticregression import OnlineLogisticRegression
+    from flink_ml_tpu.table import SparseBatch, StreamTable, Table
+
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(11), 3)
+    rows = batch * batches
+    # a tenth of the ids from 64 hot coordinates: repeats inside a batch
+    hot = jax.random.uniform(k1, (rows, nnz)) < 0.1
+    indices = jnp.where(
+        hot, jax.random.randint(k2, (rows, nnz), 0, 64), jax.random.randint(k1, (rows, nnz), 0, dim)
+    ).astype(jnp.int32)
+    values = jax.random.uniform(k2, (rows, nnz), dtype=jnp.float32)
+    y = (jax.random.uniform(k3, (rows,)) > 0.5).astype(jnp.float32)
+    cut = [tuple(a[k * batch : (k + 1) * batch] for a in (indices, values, y)) for k in range(batches)]
+    stream = StreamTable.from_batches(
+        [Table({"features": SparseBatch(dim, idx, val), "label": lab}) for idx, val, lab in cut]
+    )
+    model = (
+        OnlineLogisticRegression()
+        .set_global_batch_size(batch)
+        .set_initial_model_data(Table({"coefficient": [DenseVector(np.zeros(dim))]}))
+        .fit(stream)
+    )
+    before = _counters()
+    versions = [model.process_updates(max_batches=1) for _ in range(batches)]
+    check(versions == list(range(1, batches + 1)), f"a version a global batch: {versions}")
+    check(_counter_delta(before, "online.versions") == batches, "online.versions counts every version")
+    check(_counter_delta(before, "iteration.host_sync") == 0, "a host sync while batches were folded")
+    check(_counter_delta(before, "readback.count") == 0, "a readback while batches were folded")
+    check(_counter_delta(before, "h2d.bytes") == 0, "a device-born batch was uploaded")
+    check(isinstance(model.model_arrays()[0], jax.Array), "the published coefficient left the device")
+    coefficient = model.coefficient
+    check(coefficient.shape == (dim,) and _all_finite(coefficient), "online coefficient shape or values")
+    host = [(np.asarray(idx), np.asarray(val, np.float64), np.asarray(lab, np.float64)) for idx, val, lab in cut]
+    want = _numpy_ftrl(host, dim)
+    rel = rel_diff(coefficient, want)
+    check(rel <= CROSS_PATH_RTOL, f"online FTRL vs numpy float64: rel {rel:.2e}")
+    return {"dim": dim, "nnz": nnz, "batch": batch, "versions": versions[-1], "vsNumpyRel": rel}
+
+
+# ---------------------------------------------------------------------------
 # serve: continuous batching, then the same from a populated program bank
 # ---------------------------------------------------------------------------
 
@@ -755,6 +826,8 @@ def main() -> int:
     run("train", phase_train)
     _, loops_table, loops_coefficient = run("loops", phase_loops)
     run("sparse", phase_sparse)
+    if len(devices) == 1:
+        run("online", phase_online)
     run("serve", phase_serve)
     if len(devices) > 1:
         run("one_device", phase_one_device, loops_table, loops_coefficient)

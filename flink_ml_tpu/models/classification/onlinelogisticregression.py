@@ -9,6 +9,15 @@ tf.keras-style FTRL z/n update) and OnlineLogisticRegressionModel.java:133
 (modelDataVersion gauge, modelVersionCol output). Each global batch is one
 jitted gradient + FTRL step; versions publish per batch through the
 host-driven unbounded loop.
+
+The state (w, z, n) is float32 on the device from the first batch on, and a
+published version is a record of the device coefficient: nothing is read back
+a batch. A dense features column is folded by `_ftrl_step`, a sweep of all
+`d` coordinates; a `SparseBatch` column by `_ftrl_touched`, padded CSR end to
+end, which updates the coordinates the batch holds and leaves every other
+w, z, n as it is, to the bit (McMahan et al., KDD 2013, Algorithm 1: "for all
+i in I"; with g = 0 the sweep recomputes what it read). `ftrl.slots_updated`
+says which of the two ran.
 """
 
 from __future__ import annotations
@@ -18,6 +27,7 @@ from typing import Any, Dict, Iterator, List, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ...api import Estimator, KernelContext, Model, as_kernel_matrix
 from ...common.param import (
@@ -32,12 +42,14 @@ from ...common.param import (
     HasReg,
     HasWeightCol,
 )
+from ...ops.losses import sparse_dot
 from ...param import DoubleParam, ParamValidators
 from ...parallel.iteration import iterate_unbounded
-from ...table import StreamTable, Table, as_dense_matrix
+from ...table import SparseBatch, StreamTable, Table, as_dense_matrix
 from ...utils import read_write
 from ...utils.lazyjit import lazy_jit
 from ...utils.param_utils import update_existing_params
+from .._online import OnlineUpdates, kernel_constant, on_device, on_host, published, weakly
 
 
 class OnlineLogisticRegressionModelParams(
@@ -71,6 +83,20 @@ class OnlineLogisticRegressionParams(
         return self.set(self.BETA, value)
 
 
+def _ftrl_proximal(coeff, z, n, g, alpha, beta, l1, l2):
+    """The FTRL-Proximal update of (w, z, n) by the gradient g, elementwise
+    (OnlineLogisticRegression.UpdateModel.processElement)."""
+    sigma = (jnp.sqrt(n + g * g) - jnp.sqrt(n)) / alpha
+    z = z + g - sigma * coeff
+    n = n + g * g
+    new_coeff = jnp.where(
+        jnp.abs(z) <= l1,
+        0.0,
+        (jnp.sign(z) * l1 - z) / ((beta + jnp.sqrt(n)) / alpha + l2),
+    )
+    return new_coeff, z, n
+
+
 @lazy_jit
 def _ftrl_step(coeff, z, n, X, y, alpha, beta, l1, l2):
     """One global batch: mean per-dim gradient then the FTRL-Proximal update
@@ -81,15 +107,105 @@ def _ftrl_step(coeff, z, n, X, y, alpha, beta, l1, l2):
     # reference's sparse-aware denominator; dense rows count everywhere
     weight_sum = jnp.sum(X != 0.0, axis=0).astype(X.dtype)
     g = jnp.where(weight_sum > 0, grad_sum / jnp.maximum(weight_sum, 1.0), grad_sum)
-    sigma = (jnp.sqrt(n + g * g) - jnp.sqrt(n)) / alpha
-    z = z + g - sigma * coeff
-    n = n + g * g
-    new_coeff = jnp.where(
-        jnp.abs(z) <= l1,
-        0.0,
-        (jnp.sign(z) * l1 - z) / ((beta + jnp.sqrt(n)) / alpha + l2),
+    return _ftrl_proximal(coeff, z, n, g, alpha, beta, l1, l2)
+
+
+def _run_totals(first, *columns):
+    """Running sums down `columns` that start again wherever `first` is set:
+    at the last entry of a run of equal sorted ids, the run's totals."""
+
+    def combine(a, b):
+        return (a[0] | b[0], *(jnp.where(b[0], y, x + y) for x, y in zip(a[1:], b[1:])))
+
+    return lax.associative_scan(combine, (first, *columns))[1:]
+
+
+_SORTED_NO_REPEAT = dict(indices_are_sorted=True, unique_indices=True)
+
+
+def _touched_coordinates(coeff, indices, values, y):
+    """The coordinates a batch of padded-CSR rows holds (-1 ids are padding),
+    each once and in rising order at the front of arrays as long as the batch
+    has entries: (coordinate, mean gradient, coefficient, how many). The
+    entries are sorted by id; a run of equal ids is one coordinate, its
+    gradient sum and its count of holding rows the run's totals at the run's
+    last entry. A second sort brings those last entries to the front; what
+    is behind them reads past the model's end, each slot another id, so that
+    the whole array stays sorted and without a repeat."""
+    d = coeff.shape[0]
+    dot, safe, vals = sparse_dot(indices, values, coeff)
+    p = 1.0 / (1.0 + jnp.exp(-dot))
+    contrib = vals * (p - y.astype(coeff.dtype))[:, None]
+    # padding sorts behind every id, as one run past the end
+    coord = jnp.where(indices >= 0, indices, d).ravel()
+    coord, contrib, w = lax.sort((coord, contrib.ravel(), coeff[safe].ravel()), num_keys=1)
+    edge = coord[1:] != coord[:-1]
+    true = jnp.ones((1,), bool)
+    grad_sum, count = _run_totals(jnp.concatenate([true, edge]), contrib, jnp.ones_like(contrib))
+    writer = jnp.concatenate([edge, true]) & (coord < d)
+    past = d + lax.iota(coord.dtype, coord.shape[0])
+    coord, g, w = lax.sort((jnp.where(writer, coord, past), grad_sum / count, w), num_keys=1)
+    return coord, g, w, jnp.sum(writer)
+
+
+def _ftrl_update_at(z, n, coord, g, w, hyper):
+    """The FTRL-Proximal update of `_ftrl_step` at the coordinates `coord`
+    (sorted, no repeats; one past the model's end is dropped): the new
+    coefficients there, and z and n with them written in. Every other
+    coordinate keeps its z and n to the bit: nothing of `d` elements is
+    swept."""
+    z_at = z.at[coord].get(mode="clip", **_SORTED_NO_REPEAT)
+    n_at = n.at[coord].get(mode="clip", **_SORTED_NO_REPEAT)
+    w_new, z_new, n_new = _ftrl_proximal(w, z_at, n_at, g, hyper[0], hyper[1], hyper[2], hyper[3])
+    return (
+        w_new,
+        z.at[coord].set(z_new, mode="drop", **_SORTED_NO_REPEAT),
+        n.at[coord].set(n_new, mode="drop", **_SORTED_NO_REPEAT),
     )
-    return new_coeff, z, n
+
+
+def _ftrl_touched(z, n, coeff, indices, values, y, hyper):
+    """One global batch of padded-CSR rows: the same mean per-dim gradient and
+    FTRL-Proximal update as `_ftrl_step`, over the coordinates the batch
+    holds; every other coordinate keeps w, z, n to the bit. (z, n, w) in and
+    out, in that order: jax gives a donated argument to the first result of
+    its shape, and z and n are the donated ones.
+
+    What the v5e charges at 2e8 coordinates shapes it (PERF.md, PR 31): a
+    gather costs 16 ns an element, so z and n are read at the batch's
+    DISTINCT coordinates where those fill no more than a quarter of the
+    batch's entries (skewed click-log fields repeat: 159,744 entries hold
+    37,000 coordinates), and at all of the entries' slots otherwise. z and n
+    are written in place. The coefficient is not donated, because a published
+    record holds it: its scatter writes into a copy, and that copy is the
+    fresh buffer the next record needs."""
+    coord, g, w, distinct = _touched_coordinates(coeff, indices, values, y)
+    entries = coord.shape[0]
+    quarter = max(entries // 4, 1)
+
+    def update_of(size):
+        def update(z_, n_):
+            w_new, z_new, n_new = _ftrl_update_at(z_, n_, coord[:size], g[:size], w[:size], hyper)
+            return jnp.pad(w_new, (0, entries - size)), z_new, n_new
+
+        return update
+
+    w_new, z, n = lax.cond(distinct <= quarter, update_of(quarter), update_of(entries), z, n)
+    return z, n, coeff.at[coord].set(w_new, mode="drop", **_SORTED_NO_REPEAT)
+
+
+_touched_kernel: list = []
+
+
+def _ftrl_touched_step(coeff, z, n, indices, values, y, hyper):
+    """`_ftrl_touched` jitted once, z and n donated: (w, z, n) -> (w, z, n)."""
+    if not _touched_kernel:
+        from ...parallel.dispatch import supports_donation
+
+        donate = {"donate_argnames": ("z", "n")} if supports_donation() else {}
+        _touched_kernel.append(lazy_jit(_ftrl_touched, **donate))
+    z, n, coeff = _touched_kernel[0](z, n, coeff, indices, values, y, hyper)
+    return coeff, z, n
 
 
 def _serve_scores(coeff, version, X):
@@ -113,13 +229,14 @@ class _PublishedLR(NamedTuple):
     """One immutable published model version — the single-reference
     publication record (see `_PublishedKMeans`): swapping it is atomic,
     and a reader's snapshot is always a consistent (version, coefficient)
-    pair."""
+    pair. The coefficient is kept on the side it was born on (`_online`):
+    the training loop's is a device array that no later batch writes to."""
 
     version: int
-    coefficient: Optional[np.ndarray]
+    coefficient: Any  # float64 numpy, or the training loop's device array
 
 
-class OnlineLogisticRegressionModel(Model, OnlineLogisticRegressionModelParams):
+class OnlineLogisticRegressionModel(OnlineUpdates, Model, OnlineLogisticRegressionModelParams):
     """Serves through the FUSED pipeline path with the coefficient vector
     as a versioned runtime operand: a live `set_model_data`/
     `publish_model_arrays` is a zero-pause, zero-recompile pointer swap
@@ -131,11 +248,13 @@ class OnlineLogisticRegressionModel(Model, OnlineLogisticRegressionModelParams):
 
     def __init__(self):
         self._published = _PublishedLR(0, None)
-        self._updates: Optional[Iterator] = None
+        self._trained: Optional[Tuple[int, tuple]] = None
 
     @property
     def coefficient(self) -> Optional[np.ndarray]:
-        return self._published.coefficient
+        """The published coefficient on the host (a device record is read
+        back here, when asked, and not when it is published)."""
+        return on_host(self._published.coefficient)
 
     @coefficient.setter
     def coefficient(self, value) -> None:
@@ -150,11 +269,27 @@ class OnlineLogisticRegressionModel(Model, OnlineLogisticRegressionModelParams):
         self._publish(self._published.coefficient, int(value))
 
     def _publish(self, coefficient, version: int) -> None:
-        coefficient = (
-            None if coefficient is None else np.asarray(coefficient, dtype=np.float64)
-        )
-        self._published = _PublishedLR(int(version), coefficient)
+        self._published = _PublishedLR(int(version), published(coefficient))
         self.bump_model_data_version()
+
+    def _publish_state(self, version: int, coefficient) -> None:
+        self._publish(coefficient, version)
+
+    def _publish_trained(self, version: int, state) -> None:
+        """The estimator's loop publishes version `version`: the coefficient
+        of the state after `version` batches."""
+        self._trained = (int(version), state)
+        self._publish(state[0], version)
+
+    def training_state(self) -> Optional[Tuple[int, tuple]]:
+        """(version, (w, z, n)) as the estimator's loop last published them,
+        or None for a model no loop trains: copies on the device, the
+        caller's to keep (the loop's own z and n are given up to the next
+        batch's step)."""
+        if self._trained is None:
+            return None
+        version, state = self._trained
+        return version, tuple(jnp.copy(a) for a in state)
 
     def model_arrays(self) -> tuple:
         return (self._published.coefficient,)
@@ -173,39 +308,21 @@ class OnlineLogisticRegressionModel(Model, OnlineLogisticRegressionModelParams):
             self._publish(coefficient, version)
             return self
         (stream,) = inputs
-        self._updates = iter(stream)
+        self._take_stream(stream)
         return self
 
     def get_model_data(self) -> List[Table]:
         from ...linalg import DenseVector
 
+        pub = self._published  # one record read: a consistent (version, coeff)
         return [
             Table(
                 {
-                    "coefficient": [DenseVector(self.coefficient)],
-                    "modelVersion": [self.model_version],
+                    "coefficient": [DenseVector(on_host(pub.coefficient))],
+                    "modelVersion": [pub.version],
                 }
             )
         ]
-
-    def process_updates(self, max_batches: Optional[int] = None) -> int:
-        """Drain pending training batches, advancing the model version."""
-        # the reference's modelDataVersion gauge (OnlineLogisticRegressionModel.java:133)
-        from ...utils import metrics
-
-        metrics.set_gauge("OnlineLogisticRegressionModel.modelDataVersion", self.model_version)
-        if self._updates is None:
-            return self.model_version
-        processed = 0
-        for version, coeff in self._updates:
-            # ONE atomic publication per training batch (no torn
-            # coefficient-without-version state for a concurrent reader)
-            self._publish(coeff, version)
-            metrics.set_gauge("OnlineLogisticRegressionModel.modelDataVersion", version)
-            processed += 1
-            if max_batches is not None and processed >= max_batches:
-                break
-        return self.model_version
 
     # -- fused transform kernel (versioned runtime operands) -----------------
     def _kernel_constants(self) -> Dict[str, Any]:
@@ -215,8 +332,9 @@ class OnlineLogisticRegressionModel(Model, OnlineLogisticRegressionModelParams):
     def kernel_constants_for(self, arrays: tuple, version: int = 0) -> Dict[str, Any]:
         (coefficient,) = arrays
         return {
-            # f32 mirrors the device column dtype of the serving path
-            "coefficient": np.asarray(coefficient, dtype=np.float32),
+            # f32 mirrors the device column dtype of the serving path; a
+            # device record stays where it is
+            "coefficient": kernel_constant(coefficient),
             "version": np.int32(version),
         }
 
@@ -266,7 +384,7 @@ class OnlineLogisticRegressionModel(Model, OnlineLogisticRegressionModelParams):
             ]
         pub = self._published  # one record read: a consistent (version, coeff)
         X = as_dense_matrix(col)
-        dot = X @ pub.coefficient
+        dot = X @ on_host(pub.coefficient)
         prob = 1.0 / (1.0 + np.exp(-dot))
         pred = np.where(dot >= 0, 1.0, 0.0)
         raw = np.stack([1.0 - prob, prob], axis=1)
@@ -283,8 +401,9 @@ class OnlineLogisticRegressionModel(Model, OnlineLogisticRegressionModelParams):
         ]
 
     def _save_extra(self, path: str) -> None:
+        pub = self._published
         read_write.save_model_arrays(
-            path, coefficient=self.coefficient, modelVersion=np.int64(self.model_version)
+            path, coefficient=on_host(pub.coefficient), modelVersion=np.int64(pub.version)
         )
 
     def _load_extra(self, path: str) -> None:
@@ -323,45 +442,73 @@ class OnlineLogisticRegression(Estimator, OnlineLogisticRegressionParams):
         label_col = self.get_label_col()
         batch_size = self.get_global_batch_size()
 
-        def rebatch(batches) -> Iterator[Tuple[np.ndarray, np.ndarray]]:
-            buf_X: List[np.ndarray] = []
-            buf_y: List[np.ndarray] = []
+        def columns(batch) -> tuple:
+            """A mini-batch as the arrays a step takes, rows first: (X, y)
+            for a dense features column, (indices, values, y) for a
+            SparseBatch, which is never densified. Device arrays stay."""
+            col = batch.column(features_col)
+            y = batch.column(label_col)
+            if not on_device(y):
+                y = np.asarray(y, dtype=np.float64)
+            if isinstance(col, SparseBatch):
+                if col.size != d:
+                    raise ValueError(
+                        f"a sparse batch of size {col.size} for a model of {d} coefficients"
+                    )
+                return (col.indices, col.values, y)
+            return (as_dense_matrix(col), y)
+
+        def rebatch(batches) -> Iterator[tuple]:
+            """countWindowAll(globalBatchSize): regroup incoming rows into
+            exact global batches, in stream order. A batch that already has
+            globalBatchSize rows passes through as it is."""
+            held: List[tuple] = []
             buffered = 0
             for batch in batches:
-                buf_X.append(as_dense_matrix(batch.column(features_col)))
-                buf_y.append(np.asarray(batch.column(label_col), dtype=np.float64))
-                buffered += buf_X[-1].shape[0]
+                chunk = columns(batch)
+                if held and len(chunk) != len(held[0]):
+                    raise TypeError("a stream mixes dense and sparse feature batches")
+                if not held and chunk[-1].shape[0] == batch_size:
+                    yield chunk
+                    continue
+                held.append(chunk)
+                buffered += chunk[-1].shape[0]
                 while buffered >= batch_size:
-                    X = np.concatenate(buf_X)
-                    y = np.concatenate(buf_y)
-                    yield X[:batch_size], y[:batch_size]
-                    buf_X, buf_y = (
-                        ([X[batch_size:]], [y[batch_size:]])
-                        if X.shape[0] > batch_size
-                        else ([], [])
-                    )
-                    buffered = max(0, X.shape[0] - batch_size)
+                    joined = _join_rows(held)
+                    yield tuple(a[:batch_size] for a in joined)
+                    buffered -= batch_size
+                    held = [tuple(a[batch_size:] for a in joined)] if buffered else []
+
+        hyper = jnp.asarray([alpha, beta, l1, l2], jnp.float32)
 
         def step(state, batch):
-            coeff_, z, n = state
-            X, y = batch
-            return _ftrl_step(
-                jnp.asarray(coeff_),
-                jnp.asarray(z),
-                jnp.asarray(n),
-                jnp.asarray(X),
-                jnp.asarray(y),
-                alpha, beta, l1, l2,
-            )
+            """One global batch, by the kind of its features column."""
+            from ...obs import memledger
+            from ...utils import metrics
+
+            if not on_device(state[0]):  # a state restored from a checkpoint
+                state = _stage_state(state)
+            y = batch[-1]
+            metrics.inc_counter("ftrl.batches")
+            metrics.inc_counter("ftrl.rows", int(y.shape[0]))
+            if len(batch) == 2:
+                metrics.inc_counter("ftrl.slots_updated", d)
+                state = _ftrl_step(*state, batch[0], y, alpha, beta, l1, l2)
+            else:
+                indices, values, _ = batch
+                metrics.inc_counter("ftrl.slots_updated", int(indices.size))
+                state = _ftrl_touched_step(*state, indices, values, y, hyper)
+            return memledger.track(state, "online.state")
 
         from ... import config
         from ...parallel import prefetch as h2d
         from ...parallel.iteration import checkpoint_job_key
 
-        init = (coeff, np.zeros(d), np.zeros(d))
+        init = _initial_state(coeff)
         # shared input stager: the (X, y) upload of global batch b+1 runs
         # on the worker thread (accounted, h2d.*) while batch b's FTRL
-        # step executes — micro-batch H2D off the critical path. The
+        # step executes — micro-batch H2D off the critical path; a batch
+        # that is already on the device passes through untouched. The
         # window is a flow.BoundedChannel under config.
         # online_overload_policy: "block" (default) is lossless
         # backpressure; "shed_oldest" bounds memory AND model staleness
@@ -371,12 +518,51 @@ class OnlineLogisticRegression(Estimator, OnlineLogisticRegressionParams):
             policy=config.online_overload_policy,
             name="online.ingest",
         ).iterate(rebatch(stream))
-        raw_updates = iterate_unbounded(
-            staged, step, init, job_key=checkpoint_job_key(self)
-        )
-        updates = ((version, state[0]) for version, state in raw_updates)
         model = OnlineLogisticRegressionModel()
         model.coefficient = coeff
-        model.set_model_data(updates)
+        model._follow(
+            iterate_unbounded(
+                staged, step, init, job_key=checkpoint_job_key(self),
+                publish=weakly(model._publish_trained),
+            )
+        )
         update_existing_params(model, self)
         return model
+
+
+def _stage_state(state) -> tuple:
+    """Host arrays uploaded once, float32, ledgered as `online.state`."""
+    from ...parallel import prefetch as h2d
+
+    return h2d.stage_to_device(
+        tuple(np.asarray(a, dtype=np.float32) for a in state), category="online.state"
+    )
+
+
+def _initial_state(coeff) -> tuple:
+    """(w, z, n) before the first batch: the initial coefficient uploaded, z
+    and n born on the device as zeros."""
+    from ...obs import memledger
+
+    (w,) = _stage_state((coeff,))
+    return memledger.track((w, jnp.zeros_like(w), jnp.zeros_like(w)), "online.state")
+
+
+def _join_rows(chunks: List[tuple]) -> tuple:
+    """The chunks' columns joined along the rows, on the side they are on;
+    sparse chunks of unequal width are padded to the widest (-1, 0)."""
+    if len(chunks) == 1:
+        return chunks[0]
+    joined = []
+    for position, parts in enumerate(zip(*chunks)):
+        xp = jnp if any(on_device(a) for a in parts) else np
+        if parts[0].ndim == 2 and len(chunks[0]) == 3:
+            width = max(a.shape[1] for a in parts)
+            fill = -1 if position == 0 else 0
+            parts = [
+                a if a.shape[1] == width
+                else xp.pad(a, ((0, 0), (0, width - a.shape[1])), constant_values=fill)
+                for a in parts
+            ]
+        joined.append(xp.concatenate(parts))
+    return tuple(joined)

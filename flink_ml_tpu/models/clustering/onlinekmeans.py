@@ -37,6 +37,7 @@ from ...table import StreamTable, Table, as_dense_matrix
 from ...utils import read_write
 from ...utils.lazyjit import lazy_jit
 from ...utils.param_utils import update_existing_params
+from .._online import OnlineUpdates, kernel_constant, on_device, on_host, published, weakly
 from .kmeans import KMeansModelParams
 
 
@@ -101,14 +102,16 @@ class _PublishedKMeans(NamedTuple):
     reader (serve thread) that grabbed the reference keeps a consistent
     (version, centroids, weights) triple no matter how many swaps the
     trainer thread lands meanwhile. Torn (new centroids, old weights)
-    states are unrepresentable."""
+    states are unrepresentable. The arrays are kept on the side they were
+    born on (`_online`, the one record form of the online models): the
+    training loop's are device arrays, fresh ones every batch."""
 
     version: int
-    centroids: Optional[np.ndarray]
-    weights: Optional[np.ndarray]
+    centroids: Any  # float64 numpy, or the training loop's device arrays
+    weights: Any
 
 
-class OnlineKMeansModel(Model, KMeansModelParams):
+class OnlineKMeansModel(OnlineUpdates, Model, KMeansModelParams):
     """Serves predictions from the latest model version
     (OnlineKMeansModel.java; `model_version` mirrors the modelDataVersion
     gauge). Serves through the FUSED pipeline path: the centroid tensor is
@@ -122,14 +125,14 @@ class OnlineKMeansModel(Model, KMeansModelParams):
 
     def __init__(self):
         self._published = _PublishedKMeans(0, None, None)
-        self._updates: Optional[Iterator] = None
 
     # -- atomic publication --------------------------------------------------
     # centroids/weights/model_version stay as attributes for API compat,
-    # but all three read/write the ONE `_published` record.
+    # but all three read/write the ONE `_published` record; a device record
+    # is read back when the host asks for it, not when it is published.
     @property
     def centroids(self) -> Optional[np.ndarray]:
-        return self._published.centroids
+        return on_host(self._published.centroids)
 
     @centroids.setter
     def centroids(self, value) -> None:
@@ -138,7 +141,7 @@ class OnlineKMeansModel(Model, KMeansModelParams):
 
     @property
     def weights(self) -> Optional[np.ndarray]:
-        return self._published.weights
+        return on_host(self._published.weights)
 
     @weights.setter
     def weights(self, value) -> None:
@@ -155,10 +158,12 @@ class OnlineKMeansModel(Model, KMeansModelParams):
         self._publish(pub.centroids, pub.weights, int(value))
 
     def _publish(self, centroids, weights, version: int) -> None:
-        centroids = None if centroids is None else np.asarray(centroids, dtype=np.float64)
-        weights = None if weights is None else np.asarray(weights, dtype=np.float64)
-        self._published = _PublishedKMeans(int(version), centroids, weights)
+        self._published = _PublishedKMeans(int(version), published(centroids), published(weights))
         self.bump_model_data_version()
+
+    def _publish_state(self, version: int, state) -> None:
+        centroids, weights = state
+        self._publish(centroids, weights, version)
 
     def model_arrays(self) -> tuple:
         pub = self._published
@@ -174,41 +179,21 @@ class OnlineKMeansModel(Model, KMeansModelParams):
             self._publish(centroids, weights, self._published.version)
             return self
         (stream,) = inputs
-        self._updates = iter(stream)
+        self._take_stream(stream)
         return self
 
     def get_model_data(self) -> List[Table]:
         from ...linalg import DenseVector
 
+        pub = self._published  # one record read: centroids and weights of one version
         return [
             Table(
                 {
-                    "centroids": [[DenseVector(c) for c in self.centroids]],
-                    "weights": [DenseVector(self.weights)],
+                    "centroids": [[DenseVector(c) for c in on_host(pub.centroids)]],
+                    "weights": [DenseVector(on_host(pub.weights))],
                 }
             )
         ]
-
-    def process_updates(self, max_batches: Optional[int] = None) -> int:
-        """Drain pending training batches, advancing the model version —
-        the host-driven analogue of the unbounded feedback loop."""
-        # the reference's modelDataVersion gauge (OnlineKMeansModel.java:161-166)
-        from ...utils import metrics
-
-        metrics.set_gauge("OnlineKMeansModel.modelDataVersion", self.model_version)
-        if self._updates is None:
-            return self.model_version
-        processed = 0
-        for version, (centroids, weights) in self._updates:
-            # ONE atomic publication per training batch — a concurrent
-            # serve thread sees either the old or the new (version,
-            # centroids, weights) triple, never a mixture
-            self._publish(centroids, weights, version)
-            metrics.set_gauge("OnlineKMeansModel.modelDataVersion", version)
-            processed += 1
-            if max_batches is not None and processed >= max_batches:
-                break
-        return self.model_version
 
     # -- fused transform kernel (versioned runtime operand) ------------------
     def _kernel_constants(self) -> Dict[str, Any]:
@@ -217,8 +202,9 @@ class OnlineKMeansModel(Model, KMeansModelParams):
 
     def kernel_constants_for(self, arrays: tuple, version: int = 0) -> Dict[str, Any]:
         centroids, _ = arrays
-        # f32 cast mirrors the eager serve path (jnp.asarray(..., float32))
-        return {"centroids": np.asarray(centroids, dtype=np.float32)}
+        # f32 cast mirrors the eager serve path (jnp.asarray(..., float32));
+        # a device record stays where it is
+        return {"centroids": kernel_constant(centroids)}
 
     def _constant_sources(self) -> tuple:
         pub = self._published
@@ -248,7 +234,7 @@ class OnlineKMeansModel(Model, KMeansModelParams):
             # is sliced back off below
             X = h2d.pad_rows(X, n, h2d.next_bucket(n))
         assign = jit_find_closest(self.get_distance_measure())(
-            jnp.asarray(X, jnp.float32), jnp.asarray(self.centroids, jnp.float32)
+            jnp.asarray(X, jnp.float32), jnp.asarray(self._published.centroids, jnp.float32)
         )
         from ...utils.packing import packed_device_get
 
@@ -260,9 +246,10 @@ class OnlineKMeansModel(Model, KMeansModelParams):
         ]
 
     def _save_extra(self, path: str) -> None:
+        pub = self._published
         read_write.save_model_arrays(
-            path, centroids=self.centroids, weights=self.weights,
-            modelVersion=np.int64(self.model_version),
+            path, centroids=on_host(pub.centroids), weights=on_host(pub.weights),
+            modelVersion=np.int64(pub.version),
         )
 
     def _load_extra(self, path: str) -> None:
@@ -315,12 +302,18 @@ class OnlineKMeans(Estimator, OnlineKMeansParams):
 
         measure_name = self.get_distance_measure()
 
-        def step(state, X: np.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-            c, w = state
-            return _batch_update(
-                jnp.asarray(c), jnp.asarray(w),
-                jnp.asarray(X), jnp.asarray(decay), measure_name,
+        def step(state, X) -> Tuple[jnp.ndarray, jnp.ndarray]:
+            if not on_device(state[0]):  # a state restored from a checkpoint
+                state = stage_state(state)
+            return _batch_update(*state, X, decay_dev, measure_name)
+
+        def stage_state(state):
+            """(centroids, weights) uploaded once, float32."""
+            return h2d.stage_to_device(
+                tuple(np.asarray(a, dtype=np.float32) for a in state), category="online.state"
             )
+
+        decay_dev = jnp.asarray(decay)
 
         from ... import config
         from ...parallel.iteration import checkpoint_job_key
@@ -338,15 +331,17 @@ class OnlineKMeans(Estimator, OnlineKMeansParams):
             policy=config.online_overload_policy,
             name="online.ingest",
         ).iterate(rebatch(stream))
-        updates = iterate_unbounded(
-            staged,
-            step,
-            (centroids, weights),
-            job_key=checkpoint_job_key(self),
-        )
         model = OnlineKMeansModel()
         model.centroids = centroids
         model.weights = weights
-        model.set_model_data(updates)
+        model._follow(
+            iterate_unbounded(
+                staged,
+                step,
+                stage_state((centroids, weights)),
+                job_key=checkpoint_job_key(self),
+                publish=weakly(model._publish_state),
+            )
+        )
         update_existing_params(model, self)
         return model

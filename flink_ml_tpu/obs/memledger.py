@@ -103,6 +103,7 @@ CATEGORIES = (
     "streamSegments",
     "serving",
     "fleet",
+    "online.state",
     "scratch",
 )
 

@@ -207,14 +207,22 @@ class Phase(Span):
     record where a sink is configured. `dur_ns` and `start_ns` hold the
     reads, for a call site that keeps a timer of its own; one that also puts
     an event of its own on the timeline passes `marks=False`, and the
-    phase leaves its begin and end marks off the host lane."""
+    phase leaves its begin and end marks off the host lane. The online
+    loop's phases (`online.batch` with `online.ingest`, `online.launch` and
+    `online.publish` inside it) are counted once a global batch; a phase
+    that turns out to hold no work (the wait that found the stream at its
+    end) is taken back with `void()` and leaves the counters as they were."""
 
-    __slots__ = ("dur_ns", "_annotation", "_sunk", "_marks")
+    __slots__ = ("dur_ns", "_annotation", "_sunk", "_marks", "_void")
 
     def __init__(self, name: str, marks: bool = True):
         self.name = name
         self.attrs = {}
         self._marks = marks
+        self._void = False
+
+    def void(self) -> None:
+        self._void = True
 
     @property
     def start_ns(self) -> int:
@@ -239,8 +247,9 @@ class Phase(Span):
     def __exit__(self, exc_type, exc, tb):
         end_ns = time.perf_counter_ns()
         self.dur_ns = end_ns - self._start_ns
-        metrics.inc_counter(self.name + ".ns", self.dur_ns)
-        metrics.inc_counter(self.name + ".n")
+        if not self._void:
+            metrics.inc_counter(self.name + ".ns", self.dur_ns)
+            metrics.inc_counter(self.name + ".n")
         if self._sunk:
             self._close(end_ns, exc_type)
         if self._annotation is not None:

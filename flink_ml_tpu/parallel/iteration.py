@@ -22,6 +22,7 @@ from __future__ import annotations
 import json
 import os
 import re
+from collections import deque
 from dataclasses import dataclass
 from typing import Any, Callable, Iterable, Optional, Tuple
 
@@ -381,6 +382,21 @@ def scan_epochs(body: BodyFn, init_carry, num_epochs: int):
 # unbounded (online) iteration
 # ---------------------------------------------------------------------------
 
+_STREAM_END = object()
+
+
+def _wait_for(state) -> None:
+    """Block until `state`'s device arrays are whole (no readback). A leaf a
+    later step was given by donation is that step's to finish, and is passed
+    over."""
+    leaves = [
+        leaf for leaf in jax.tree_util.tree_leaves(state)
+        if isinstance(leaf, jax.Array) and not leaf.is_deleted()
+    ]
+    # tpulint: disable=host-sync-leak -- the fence of the online loop: a wait, nothing is read back; timed as the phase online.fence
+    jax.block_until_ready(leaves)
+
+
 def iterate_unbounded(
     batches: Iterable,
     step: Callable[[Any, Any], Any],
@@ -389,6 +405,7 @@ def iterate_unbounded(
     checkpoint_dir: Optional[str] = None,
     checkpoint_interval: Optional[int] = None,
     job_key: Optional[str] = None,
+    publish: Optional[Callable[[int, Any], None]] = None,
 ) -> Iterable[Tuple[int, Any]]:
     """Host-driven online loop (Iterations.iterateUnboundedStreams:118-131).
 
@@ -396,7 +413,22 @@ def iterate_unbounded(
     a new model version — the analogue of the online estimators' feedback
     loop with `countWindowAll` global batches and the `modelDataVersion`
     gauge (OnlineKMeans.java:44-60, OnlineKMeansModel.java:166). Yields
-    (model_version, state) after every batch.
+    (model_version, state) after every batch; with `publish`, the version
+    is published by the loop itself, before it is yielded.
+
+    A batch is four phases (`obs.tracing.phase`, always counted, `fml.*`
+    events of a profile): `online.batch` from the moment the loop asks for
+    the batch until its version is published, and inside it `online.ingest`
+    (the wait for the stager), `online.launch` (`step`: the dispatch of the
+    device program, which the loop does not wait for) and `online.publish`
+    (`publish`, counted as `online.versions`). Nothing here reads a device
+    value back. The host may run `config.iteration_dispatch_depth` batches
+    ahead of the device and no further: after a batch is published the loop
+    waits (`online.fence`, inside `online.batch`) until the state of that
+    many batches ago is whole on the device. Left to the runtime's own limit
+    on programs in flight, every step in flight holds its fresh outputs, and
+    a learner whose step returns a 0.8 GB coefficient filled the chip's
+    memory with them (PERF.md, PR 31).
 
     Checkpoint/resume: with a checkpoint dir (explicit args or the
     process-wide `config.iteration_checkpoint_dir`), the (state, version)
@@ -411,6 +443,7 @@ def iterate_unbounded(
     """
     from ..ckpt import faults
     from ..ckpt import snapshot as _snapshot
+    from ..utils import metrics
 
     if checkpoint_dir is None:
         from .. import config
@@ -431,28 +464,54 @@ def iterate_unbounded(
             state, version, _ = restored
             # republish the restored model immediately so a serving model
             # reaches the checkpointed version before the next live batch
+            if publish is not None:
+                publish(version, state)
             yield version, state
     skip = version
-    for batch in batches:
-        if skip > 0:  # replayed prefix already folded into the checkpoint
-            skip -= 1
-            continue
-        with tracing.span("iteration.epoch", epoch=version, mode="unbounded"):
-            state = step(state, batch)
-        version += 1
-        if listener is not None:
-            listener.on_epoch_watermark_incremented(version, state)
-        if checkpoint_dir is not None and version % interval == 0:
-            # the version IS the stream offset in global batches — stored
-            # in meta so a resume against a non-replayed source is caught
-            _snapshot.save_job_snapshot(
-                checkpoint_dir,
-                job_key,
-                {"model": state},
-                epoch=version,
-                meta={"streamOffset": version},
-            )
-        faults.tick("batch")
+    source = iter(batches)
+    from .. import config as _config
+
+    depth = max(1, _config.iteration_dispatch_depth)
+    in_flight: deque = deque(maxlen=depth + 1)
+    while True:
+        with tracing.phase("online.batch") as whole:
+            with tracing.phase("online.ingest") as ingest:
+                batch = next(source, _STREAM_END)
+                if batch is _STREAM_END or skip > 0:
+                    # no batch of this loop's: the stream's end, or a replayed
+                    # prefix already folded into the checkpoint
+                    ingest.void()
+                    whole.void()
+            if batch is _STREAM_END:
+                break
+            if skip > 0:
+                skip -= 1
+                continue
+            with tracing.phase("online.launch"):
+                with tracing.span("iteration.epoch", epoch=version, mode="unbounded"):
+                    state = step(state, batch)
+            version += 1
+            if listener is not None:
+                listener.on_epoch_watermark_incremented(version, state)
+            if checkpoint_dir is not None and version % interval == 0:
+                # the version IS the stream offset in global batches — stored
+                # in meta so a resume against a non-replayed source is caught
+                _snapshot.save_job_snapshot(
+                    checkpoint_dir,
+                    job_key,
+                    {"model": state},
+                    epoch=version,
+                    meta={"streamOffset": version},
+                )
+            faults.tick("batch")
+            if publish is not None:
+                with tracing.phase("online.publish"):
+                    publish(version, state)
+                metrics.inc_counter("online.versions")
+            in_flight.append(state)
+            if len(in_flight) > depth:
+                with tracing.phase("online.fence"):
+                    _wait_for(in_flight.popleft())
         yield version, state
     if checkpoint_dir is not None:
         # the stream completed: clear the checkpoint so a NEW job reusing

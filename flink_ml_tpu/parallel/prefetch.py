@@ -153,6 +153,15 @@ def stage_to_device(tree, sharding=None, category: Optional[str] = None):
 
     import jax
 
+    if sharding is None and all(
+        isinstance(leaf, jax.Array) for leaf in jax.tree_util.tree_leaves(tree)
+    ):
+        # already resident (a device-born stream's batch): nothing crosses the
+        # host link and nothing is copied, so there is nothing to place, to
+        # admit or to count; a `category` still ledgers the residency
+        if category is not None:
+            memledger.track(tree, category)
+        return tree
     nbytes = _host_nbytes(tree)
     memledger.admit(_admit_nbytes(tree, sharding), category)
     t0 = time.perf_counter()

@@ -81,3 +81,8 @@ def test_serve_phase_toy():
 def test_failed_check_raises():
     with pytest.raises(RuntimeError, match="chip_smoke check failed"):
         chip_smoke.check(False, "x")
+
+
+def test_online_phase_toy():
+    out = chip_smoke.phase_online(dim=5000, nnz=6, batch=256, batches=4)
+    assert out["versions"] == 4 and out["vsNumpyRel"] <= chip_smoke.CROSS_PATH_RTOL

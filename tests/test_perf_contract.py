@@ -4,8 +4,9 @@
 the XLA modules of the training programs (`train_programs` in
 `perf/configs/*.json`; `perf/metrics/epoch_roofline.py` raises on the chip
 when a trace holds none of them), the counters of `utils.metrics` that the
-readers under `perf/metrics/` take from `run["counters"]`, and the fit phases
-behind the `fit_*_ms` readers (`docs/observability.md` "Fit phases"). A
+readers under `perf/metrics/` take from `run["counters"]`, and the phases
+behind the `fit_*_ms` and `stream_*_ms` readers (`docs/observability.md`
+"Fit phases", "Online phases"). A
 rename in the package is found here, on the CPU, before it costs a chip run:
 the name stays, or it changes in a `benchmark` PR together with the file
 under `perf/` that reads it. The cases are collected from the benchmark's own
@@ -25,8 +26,9 @@ import pytest
 import jax
 
 from flink_ml_tpu import Table
+from flink_ml_tpu.linalg import DenseVector
 from flink_ml_tpu.parallel import mesh as mesh_lib
-from flink_ml_tpu.table import SparseBatch
+from flink_ml_tpu.table import SparseBatch, StreamTable
 from flink_ml_tpu.utils import metrics
 
 ROOT = Path(__file__).resolve().parent.parent
@@ -50,7 +52,10 @@ def toy_fit(config_name: str, shards: int) -> dict:
     gave them, and the counters the fit moved."""
     config = CONFIGS[config_name]
     module, _, cls = config["stage"]["class"].rpartition(".")
-    stage = getattr(importlib.import_module(module), cls)().set_max_iter(MAX_ITER)
+    stage = getattr(importlib.import_module(module), cls)()
+    online = hasattr(stage, "set_initial_model_data")
+    if not online:
+        stage.set_max_iter(MAX_ITER)
     lloyd = hasattr(stage, "set_k")
     if lloyd:
         stage.set_k(K)
@@ -71,6 +76,18 @@ def toy_fit(config_name: str, shards: int) -> dict:
     else:
         features = by_rows(values)
     label = by_rows((values.sum(axis=1) > DIM / 2).astype(np.float32))
+    table = Table({"features": features, "label": label})
+    if online:
+        # a stream configuration: the table's rows as a stream of toy sparse
+        # batches, one of the global size and one cut and joined, folded to its end
+        cuts = [0, BATCH, BATCH + BATCH // 2, 3 * BATCH]
+        stream = StreamTable.from_batches(
+            [
+                Table({"features": SparseBatch(40, indices[a:b], values[a:b]), "label": np.asarray(label)[a:b]})
+                for a, b in zip(cuts, cuts[1:])
+            ]
+        )
+        stage.set_initial_model_data(Table({"coefficient": [DenseVector(np.zeros(40))]}))
     lowered = []
 
     def on_lowering(event, duration, fun_name=None, **_):
@@ -91,7 +108,10 @@ def toy_fit(config_name: str, shards: int) -> dict:
         jax.monitoring.register_event_duration_secs_listener(on_lowering)
         before = metrics.snapshot()
         try:
-            stage.fit(Table({"features": features, "label": label}))
+            if online:
+                stage.fit(stream).process_updates()
+            else:
+                stage.fit(table)
         finally:
             jax.monitoring.unregister_event_duration_listener(on_lowering)
         counters = metrics.snapshot_delta(before, metrics.snapshot())["counters"]
@@ -103,10 +123,19 @@ def toy_fit_of_cell(cell_name: str) -> dict:
     return toy_fit(cell["config"], 4 if cell["chips"] > 1 else 1)
 
 
-def documented_phases() -> list:
+def documented_phases(section: str = "Fit phases", family: str = "fit") -> list:
     text = (ROOT / "docs" / "observability.md").read_text()
-    section = text.split("## Fit phases", 1)[1].split("\n## ", 1)[0]
-    return sorted(set(re.findall(r"^\| `(fit\.[a-z]+)` \|", section, flags=re.M)))
+    section = text.split("## " + section, 1)[1].split("\n## ", 1)[0]
+    tables = [t for t in section.split("\n\n") if t.startswith("| phase |")]  # not the counters' tables
+    return sorted(set(re.findall(rf"^\| `({family}\.[a-z]+)` \|", "\n".join(tables), flags=re.M)))
+
+
+def stream_cells() -> list:
+    """The cells whose configuration is an online estimator's."""
+    return sorted(
+        name for name, cell in CELLS.items()
+        if CONFIGS[cell["config"]]["stage"]["class"].rpartition(".")[2].startswith("Online")
+    )
 
 
 def counters_read() -> list:
@@ -154,9 +183,31 @@ def test_documented_phase_is_emitted_once_a_fit(phase):
     )
 
 
-@pytest.mark.parametrize("cell", sorted(CELLS))
+@pytest.mark.parametrize("phase", documented_phases("Online phases", "online"))
+def test_documented_online_phase_is_emitted_once_a_batch(phase):
+    """Once for every global batch the loop folds, and not for the wait that
+    finds the stream at its end."""
+    assert stream_cells()
+    for cell in stream_cells():
+        counters = toy_fit_of_cell(cell)["counters"]
+        assert counters.get(phase + ".n") == counters["ftrl.batches"] == 3, (
+            f"docs/observability.md lists the online phase {phase}; a toy stream of {cell} "
+            f"counted {counters.get(phase + '.n')} of it over {counters['ftrl.batches']} batches: {FOLLOW}"
+        )
+
+
+def test_a_toy_stream_syncs_with_the_host_for_no_batch():
+    for cell in stream_cells():
+        counters = toy_fit_of_cell(cell)["counters"]
+        assert counters["online.versions"] == 3
+        assert not any(name.startswith(("iteration.host_sync", "readback.")) for name in counters), counters
+
+
+@pytest.mark.parametrize("cell", METRICS["fit_prelaunch_ms"]["workloads"])
 def test_every_fit_has_an_extract_phase(cell):
     """The guard the three `fit_*_ms` readers share: they report nothing
-    where not every fit of the window counted `fit.extract`."""
+    where not every fit of the window counted `fit.extract`. Held in the cells
+    those readers list (a stream cell's fit has no such phases)."""
+    assert METRICS["fit_launch_ms"]["workloads"] == METRICS["fit_finish_ms"]["workloads"] == METRICS["fit_prelaunch_ms"]["workloads"]
     counters = toy_fit_of_cell(cell)["counters"]
     assert counters.get("fit.extract.n") == counters.get("fit.total.n") == 1, cell

@@ -59,6 +59,9 @@ def test_ring_concurrent_writers_lose_nothing():
         t.join()
     events, truncated = timeline.snapshot_events()
     assert truncated == 0
+    # the writers' lane alone: the HBM ledger samples the `memory` lane whenever
+    # an earlier test file's device arrays are collected, which may be now
+    events = [e for e in events if e["lane"] == "flow"]
     assert len(events) == n_threads * per_thread
     by_writer = {}
     for e in events:
