@@ -45,7 +45,7 @@ from ..parallel import mesh as mesh_lib
 from ..parallel import prefetch as h2d
 from ..utils import metrics
 from ..utils.lazyjit import lazy_jit
-from . import dense_epoch
+from . import dense_epoch, sparse_epoch
 from .losses import LossFunc
 
 
@@ -399,11 +399,11 @@ def _pack_train_result(coeff, criteria, epochs, flag=None, pack_sharding=None):
 
 @partial(
     lazy_jit,
-    static_argnames=("loss_func", "batch", "has_weights", "check_labels", "one_pass", "interpret"),
+    static_argnames=("loss_func", "batch", "has_weights", "check_labels", "one_pass", "interpret", "plan"),
 )
 def _sgd_train_flat(
     X, y, w, init_coeff, loss_func, batch, has_weights, n, hyper, check_labels,
-    one_pass=False, interpret=False,
+    one_pass=False, interpret=False, plan=None, dictionaries=None,
 ):
     """Single-data-shard variant of `_sgd_train` that slices each epoch's
     batch straight out of the FLAT row-major arrays with a dynamic slice.
@@ -420,7 +420,14 @@ def _sgd_train_flat(
     sliced: an epoch's loss is `dense_epoch.one_pass` over the whole table,
     which reads batch k where it lies, once; the table is handed over the
     other way round and the columns as one row, views of the same bytes
-    made once, outside the loop. `interpret` is for a table off the TPU."""
+    made once, outside the loop. `interpret` is for a table off the TPU.
+
+    With `plan` (`sparse_epoch.plan_fit`'s widths, `_stage_flat` asks) a
+    sparse epoch's loss is `sparse_epoch.planned_loss` over the sliced batch
+    and the plan's `dictionaries`, in `loss_func`'s place."""
+    sliced_loss = loss_func
+    if plan is not None:
+        sliced_loss = partial(sparse_epoch.planned_loss(loss_func, plan), dictionaries=dictionaries)
     num_batches = y.shape[0] // batch
     d = init_coeff.shape[0]
     dtype = _feature_dtype(X)
@@ -443,7 +450,7 @@ def _sgd_train_flat(
                 start=start, n=n, batch=batch, interpret=interpret,
             )
         else:
-            loss = loss_func
+            loss = sliced_loss
             Xk = _slice_rows(X, start, batch)
             yk = lax.dynamic_slice_in_dim(y, start, batch, 0)
             if has_weights:
@@ -1546,8 +1553,9 @@ class SGD:
         padded to a batch multiple (the only case that copies). Host inputs
         are placed on the mesh's device (a 1-device mesh may deliberately
         pin a fit to a non-default chip); already-device-resident inputs
-        stay where they are. Returns the launch, a call without arguments
-        that gives the packed result device vector."""
+        stay where they are. A sparse table is planned here, once a fit,
+        where `sparse_epoch.plan_fit` admits it. Returns the launch, a call
+        without arguments that gives the packed result device vector."""
         n = int(np.shape(X[0] if isinstance(X, tuple) else X)[0])
         B = int(self.global_batch_size)
         num_batches = max(1, -(-n // B))
@@ -1596,7 +1604,10 @@ class SGD:
         from ..parallel import dispatch
 
         one_pass = _can_one_pass(X_f, loss_func, mesh)
-        if not isinstance(X_f, tuple):
+        plan = dictionaries = None
+        if isinstance(X_f, tuple):
+            plan, dictionaries = sparse_epoch.plan_fit(X_f, loss_func, mesh, B)
+        else:
             metrics.inc_counter("dense_epoch.one_pass" if one_pass else "dense_epoch.reduce")
         return partial(
             dispatch.timed_dispatch,
@@ -1615,6 +1626,8 @@ class SGD:
             # the kernel's own code, interpreted, for a table that lies
             # anywhere else (a test that says `on_tpu` of a CPU array)
             one_pass and next(iter(X_f.devices())).platform != "tpu",
+            plan,
+            dictionaries,
             start=0, end=self.max_iter,
         )
 

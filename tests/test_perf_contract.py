@@ -72,7 +72,15 @@ def toy_fit(config_name: str, shards: int) -> dict:
     values = rng.random((ROWS, DIM)).astype(np.float16 if lloyd else np.float32)
     if "nnz" in config["data"]:
         indices = np.sort(rng.integers(0, 40, (ROWS, DIM)).astype(np.int32), axis=1)
-        features = SparseBatch(40, by_rows(indices), by_rows(values))
+        size = 40
+        if not online:
+            # rows written field by field, a column of every class of
+            # `ops/sparse_epoch.py`'s plan: one id, a few, and ids spread
+            # over a dimension wider than any dictionary
+            size = 1 << 16
+            indices[:, 0], indices[:, 1] = 0, 1 + indices[:, 1] % 3
+            indices[:, 2:] = rng.integers(4, size, (ROWS, DIM - 2))
+        features = SparseBatch(size, by_rows(indices), by_rows(values))
     else:
         features = by_rows(values)
     label = by_rows((values.sum(axis=1) > DIM / 2).astype(np.float32))
