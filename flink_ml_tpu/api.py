@@ -145,6 +145,12 @@ class AlgoOperator(Stage):
     kernel_supports_sparse: bool = False
     # True when kernel_output_cols are SparseBatch (downstream gating)
     kernel_emits_sparse: bool = False
+    # True when the kernel does no floating-point arithmetic (selections,
+    # comparisons, casts of whole numbers): nothing the compiler contracts
+    # across its boundary can change a bit of the next stage's result, so a
+    # fused program needs no barrier behind it and its outputs need not be
+    # written out between two stages
+    kernel_exact: bool = False
 
     @abc.abstractmethod
     def transform(self, *inputs: Table) -> List[Table]:
@@ -188,6 +194,30 @@ class AlgoOperator(Stage):
         if hasattr(self, "get_output_cols"):
             cols.extend(self.get_output_cols() or ())
         return cols
+
+    def kernel_output_sparse(self, sparse_inputs: bool) -> bool:
+        """Whether the kernel's output columns are SparseBatch, given whether
+        one of its inputs is: `kernel_emits_sparse` but for a stage whose
+        output follows its inputs (the assembler)."""
+        return self.kernel_emits_sparse
+
+    def kernel_static(self) -> Optional[tuple]:
+        """What `transform_kernel` reads off the stage while it is traced,
+        beside the params: the part of the model data that shapes the program
+        (an encoder's category sizes), as a hashable. Two stages of one class
+        with equal params and equal `kernel_static` trace to one program, so
+        `Pipeline.fit` runs a later fit's transforms with the program an
+        earlier fit compiled. None says the stage does not vouch for that,
+        and its programs stay its own: the default of a Model, whose kernel
+        may read its arrays as constants. A plain Transformer holds nothing
+        but params."""
+        return ()
+
+    def kernel_ran(self, out_cols: Dict[str, Any]) -> None:
+        """Called once a run of a program that holds this stage's kernel,
+        with the columns it returned: a stage that counts something a
+        transform counts it here, because the kernel's own body runs only
+        while it is traced."""
 
     def kernel_ready(self, cols: Dict[str, Any]) -> bool:
         """Runtime veto hook: `cols` maps this stage's kernel input names to
@@ -271,6 +301,9 @@ class Model(Transformer):
 
     # True: model tensors ride the fused path as swappable runtime operands
     swap_capable: bool = False
+
+    def kernel_static(self) -> Optional[tuple]:
+        return None
 
     def set_model_data(self, *inputs: Table) -> "Model":
         raise NotImplementedError(f"{type(self).__name__} does not support set_model_data")

@@ -110,7 +110,6 @@ def run_sgd(
             indices, values, dim = X
             X = (indices, values)
             loss_func = sparse_variant(loss_func.name)
-            init_coeff = np.zeros(dim, dtype=np.float64)
             # a mesh with a model axis declares the feature-sharded intent:
             # wide sparse estimator fits take the 2D (data × model) layout
             # automatically (coeff + optimizer carries as model-axis slices,
@@ -120,6 +119,11 @@ def run_sgd(
             optimizer.shard_features = (
                 mesh_lib.MODEL_AXIS in mesh_lib.default_mesh().axis_names
             )
+            # the model starts at zero where its table lives, in the engine's
+            # own dtype: a one-hot model is tens of millions wide, and zeros
+            # made on the host would be cast and uploaded before every fit
+            on_device = isinstance(indices, jax.Array) and not optimizer.shard_features
+            init_coeff = (jnp if on_device else np).zeros(dim, dtype=optimizer.dtype)
         else:
             init_coeff = np.zeros(X.shape[1], dtype=np.float64)
     result = optimizer.optimize_async(

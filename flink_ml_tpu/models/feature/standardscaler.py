@@ -66,6 +66,9 @@ class StandardScalerModel(Model, StandardScalerParams):
     def _constant_sources(self):
         return (self.mean, self.std)
 
+    def kernel_static(self):
+        return ()  # mean and scale are operands of the program
+
     def _kernel_constants(self):
         # scale derived in host f64 exactly as the eager path computes it
         return {"mean": self.mean, "scale": np.where(self.std > 0, self.std, 1.0)}

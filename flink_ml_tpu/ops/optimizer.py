@@ -995,6 +995,15 @@ class SGD:
             category="optimizer",
         )
 
+    def _device_init(self, init_coeff):
+        """The start coefficient as a device array of the engine's dtype. One
+        that is there already stays there (a wide model's zeros are made on
+        the device: uploaded from the host, 135 MB of them held a one-hot fit's
+        train program back 150 ms, a sixth of the fit); a host one is cast and uploaded."""
+        if isinstance(init_coeff, jax.Array):
+            return init_coeff if init_coeff.dtype == self.dtype else init_coeff.astype(self.dtype)
+        return jnp.asarray(np.asarray(init_coeff, self.dtype))
+
     def _hyper(self) -> np.ndarray:
         """The packed f32 hyper-parameter vector every kernel consumes —
         ONE host→device upload per dispatch instead of five scalars (see
@@ -1085,7 +1094,7 @@ class SGD:
                 X_b,
                 y_b,
                 w_b,
-                jnp.asarray(np.asarray(init_coeff, self.dtype)),
+                self._device_init(init_coeff),
                 loss_func,
                 self._hyper(),
                 validate_labels,
@@ -1111,11 +1120,12 @@ class SGD:
         else:
             d_pad = None
         X_b, y_b, w_b = self._batchify(mesh, X, y, weights, d_pad)
-        init = np.asarray(init_coeff, self.dtype)
         if self.shard_features:
             init = h2d.stage_to_device(
-                init, mesh_lib.model_sharding(mesh), category="optimizer"
+                np.asarray(init_coeff, self.dtype), mesh_lib.model_sharding(mesh), category="optimizer"
             )
+        else:
+            init = self._device_init(init_coeff)
         if self.checkpoint_dir is not None:
 
             def host_driven():
@@ -1615,7 +1625,7 @@ class SGD:
             X_f,
             y_f,
             w_f,
-            jnp.asarray(np.asarray(init_coeff, self.dtype)),
+            self._device_init(init_coeff),
             loss_func,
             B,
             has_weights,

@@ -30,6 +30,7 @@ from .api import AlgoOperator, Estimator, KernelContext, Model, Stage
 from .obs import tracing
 from .table import SparseBatch, Table
 from .utils import metrics, read_write
+from .utils.lazyjit import keyed_jit
 
 
 def _transform_one(stage: Stage, table: Table) -> Table:
@@ -101,6 +102,7 @@ class FusedSegment:
         feed: Dict[str, Any] = {}
         for stage in self.stages:
             view: Dict[str, Any] = {}
+            sparse_inputs = False
             for name in stage.kernel_input_cols():
                 if name in produced:
                     col = produced[name]
@@ -115,15 +117,19 @@ class FusedSegment:
                     return None
                 if kind == "sparse" and not stage.kernel_supports_sparse:
                     return None
+                sparse_inputs = sparse_inputs or kind == "sparse"
                 view[name] = col
             if not stage.kernel_ready(view):
                 return None
-            out_marker = _SPARSE if stage.kernel_emits_sparse else _DENSE
+            out_marker = _SPARSE if stage.kernel_output_sparse(sparse_inputs) else _DENSE
             for name in stage.kernel_output_cols():
                 produced[name] = out_marker
         return feed
 
-    def _run(self, consts_list, cols):
+    def _run(self, consts_list, cols, keep: Optional[frozenset] = None):
+        """The composed kernels over `cols`. With `keep`, only the columns it
+        names are returned: what the program does not return it need not
+        write, and an input it would only hand back is in the table already."""
         import jax
         import jax.numpy as jnp
 
@@ -134,8 +140,13 @@ class FusedSegment:
             # ACROSS stages (e.g. FMA-fusing one stage's affine into the
             # next stage's reduction), or fused outputs drift a last-ulp
             # from the per-stage eager path — the bit-parity guarantee is
-            # per-stage compilation regions inside ONE device program
-            cols = jax.lax.optimization_barrier(cols)
+            # per-stage compilation regions inside ONE device program.
+            # Behind a stage that rounds nothing (`kernel_exact`) there is
+            # nothing to pin, and its columns fuse into the next stage
+            if not stage.kernel_exact:
+                cols = jax.lax.optimization_barrier(cols)
+        if keep is not None:
+            cols = {name: col for name, col in cols.items() if name in keep}
         # guards pack into ONE program output vector: the eventual drain is
         # a single device_get with no host-side packing dispatches
         self._guard_messages = list(ctx.guards)
@@ -175,8 +186,29 @@ class FusedSegment:
             self._traced = _traced(self._run)
         return self._traced
 
+    def shared_program(self, keep: frozenset):
+        """(the program, the segment whose trace it holds) for this segment's
+        kernels returning `keep`, one for all segments of equal stages: every
+        stage vouches (`kernel_static`) that its class, params and static
+        model data are all its kernel reads while traced. (None, None) where
+        one does not. `Pipeline.fit` builds a segment a fit; without this
+        every fit would trace and compile its own."""
+        from . import compilebank
+
+        identity = self.bank_kernel_id()
+        statics = [stage.kernel_static() for stage in self.stages]
+        if identity is None or any(static is None for static in statics):
+            return None, None
+        key = _ProgramKey((identity, compilebank.static_token(statics), tuple(sorted(keep))), self)
+        program = _shared_programs(key)
+        return program, program.__dict__.setdefault("owner", self)
+
     def execute(
-        self, table: Table, feed: Dict[str, Any], pending: List[Tuple[Tuple[str, ...], Any]]
+        self,
+        table: Table,
+        feed: Dict[str, Any],
+        pending: List[Tuple[Tuple[str, ...], Any]],
+        keep: Optional[frozenset] = None,
     ) -> Table:
         # model constants are RUNTIME OPERANDS of the jitted program, not
         # baked trace constants: fetched per dispatch (memoized uploads —
@@ -186,7 +218,14 @@ class FusedSegment:
         # here — the batch in flight keeps exactly the version it was
         # dispatched with, however many swaps land during its compute.
         consts_list = [stage.device_constants() for stage in self.stages]
-        out = self._execute_banked(consts_list, feed)
+        program = None
+        if keep is not None:
+            program, owner = self.shared_program(keep)
+        if program is not None:
+            out = program(consts_list, feed)
+            self._guard_messages = owner._guard_messages
+        else:
+            out = self._execute_banked(consts_list, feed)
         if out is None:
             if self._jit is None:
                 import jax
@@ -195,8 +234,12 @@ class FusedSegment:
                 self._jit = jax.jit(self._traced_run())
             out = self._jit(consts_list, feed)
         out_cols, guard_vec = out
+        if keep is not None:  # a program of the segment's own returns every column
+            out_cols = {name: col for name, col in out_cols.items() if name in keep}
         if self._guard_messages:
             pending.append((tuple(self._guard_messages), guard_vec))
+        for stage in self.stages:
+            stage.kernel_ran(out_cols)
         return table.with_columns(out_cols)
 
     def _execute_banked(self, consts_list, feed):
@@ -231,13 +274,42 @@ class FusedSegment:
         return result if handled else None
 
 
+class _ProgramKey:
+    """Key of a segment's program among `_shared_programs`: equal by what the
+    trace reads (classes, params, static model data, the columns returned),
+    and holding the segment that is traced when the key is new."""
+
+    __slots__ = ("token", "segment")
+
+    def __init__(self, token, segment: FusedSegment):
+        self.token, self.segment = token, segment
+
+    def __hash__(self):
+        return hash(self.token)
+
+    def __eq__(self, other):
+        return isinstance(other, _ProgramKey) and self.token == other.token
+
+
+def _pipeline_prep_of(key: _ProgramKey):
+    segment, keep = key.segment, frozenset(key.token[2])
+
+    def _pipeline_prep(consts_list, cols):
+        return segment._run(consts_list, cols, keep)
+
+    return _pipeline_prep
+
+
+_shared_programs = keyed_jit(_pipeline_prep_of)
+
+
 class _FusionPlan:
     """Partition of a stage list into fused segments and eager runs."""
 
-    def __init__(self, stages: Sequence[Stage]):
+    def __init__(self, stages: Sequence[Stage], first: int = 0):
         self.runs: List[Tuple[str, Any]] = []  # ("fused", seg) | ("eager", i, stage)
         buf: List[Tuple[int, Stage]] = []
-        for i, stage in enumerate(stages):
+        for i, stage in enumerate(stages, first):
             if _stage_is_fusable(stage):
                 buf.append((i, stage))
             else:
@@ -411,6 +483,82 @@ class PipelineModel(Model):
         return cls(stages)
 
 
+# the params that name a column a stage WRITES; every other param whose name
+# ends in Col or Cols names a column it reads
+_WRITTEN_COLUMN_PARAMS = ("outputCol", "outputCols", "predictionCol", "rawPredictionCol", "modelVersionCol")
+
+
+def _columns_read(stage: Stage) -> Optional[set]:
+    """The columns a stage's params say it reads (inputCol(s), featuresCol,
+    labelCol, weightCol, categoricalCols), or None for a stage that names
+    none: what such a stage reads (a SQL statement's columns) is not known."""
+    names: set = set()
+    for param, value in stage.get_param_map().items():
+        if param.name.endswith(("Col", "Cols")) and param.name not in _WRITTEN_COLUMN_PARAMS and value:
+            names.update([value] if isinstance(value, str) else value)
+    return names or None
+
+
+def _through(
+    waiting: Sequence[Stage], first: int, table: Table, given: Table, readers: Sequence[Stage]
+) -> Table:
+    """The training table of a `Pipeline.fit` through the fitted stages that
+    wait for it (`first` is the first one's place in the pipeline), for the
+    stages that will still read it, `readers`. A fused segment returns the
+    columns it writes that a later stage reads; what this fit made and no
+    reader reads is dropped from the table; every guard is read back at the
+    end."""
+    if not waiting:
+        return table
+    from . import config
+    from .table import register_device_pytrees
+
+    register_device_pytrees()
+    reads = [_columns_read(stage) for stage in (*waiting, *readers)]
+
+    def read_from(place: int) -> Optional[set]:
+        """What the stages from `place` of the pipeline on read; None: unknown."""
+        later = reads[place - first :]
+        return None if any(names is None for names in later) else set().union(*later)
+
+    pending: List[Tuple[Tuple[str, ...], Any]] = []
+
+    def eager(stage: Stage, table: Table) -> Table:
+        _drain_guards(pending)
+        return _transform_one(stage, table)  # a `stage.transform` span of its own
+
+    for run in _FusionPlan(waiting, first).runs:
+        segment = run[1] if run[0] == "fused" and config.pipeline_fusion != "off" else None
+        feed = None if segment is None else segment.ready_feed(table)
+        if feed is not None:
+            # what a later stage of the segment reads of an earlier one's the
+            # program keeps to itself
+            written = {name for stage in segment.stages for name in stage.kernel_output_cols()}
+            live = read_from(segment.indices[-1] + 1)
+            keep = frozenset(written if live is None else written & live)
+            with tracing.span(
+                "pipeline.segment",
+                index=segment.start,
+                stages=",".join(type(s).__name__ for s in segment.stages),
+                numStages=len(segment.stages),
+                op="fit",
+                fused=True,
+            ):
+                table = segment.execute(table, feed, pending, keep)
+        else:
+            for stage in run[1].stages if run[0] == "fused" else run[2:]:
+                table = eager(stage, table)
+    _drain_guards(pending)
+    live = read_from(first + len(waiting))
+    if live is not None:
+        made = [
+            name for name in table.column_names
+            if name not in live and (name not in given or table.column(name) is not given.column(name))
+        ]
+        table = table.drop(*made)
+    return table
+
+
 class Pipeline(Estimator):
     """Sequential Estimator (builder/Pipeline.java:79-107)."""
     checkpointable = False
@@ -424,39 +572,47 @@ class Pipeline(Estimator):
         return self._stages
 
     def fit(self, *inputs: Table) -> PipelineModel:
+        """Every estimator fitted on the training table as the stages before
+        it leave it. The table goes through fitted stages only when the next
+        estimator asks for it, through all that wait at once: device columns
+        as fused programs (`FusedSegment`, shared from fit to fit) that write
+        only the columns a later stage names, their guards read back once
+        before the last estimator's fit. Columns this fit made and no later
+        stage names are let go as it proceeds."""
         if len(inputs) != 1:
             raise ValueError("Pipeline.fit expects exactly 1 input table")
-        table = inputs[0]
-
-        last_estimator_idx = -1
-        for i, stage in enumerate(self._stages):
-            if isinstance(stage, Estimator):
-                last_estimator_idx = i
-
+        given = table = inputs[0]
+        stages = self._stages
+        last = max((i for i, stage in enumerate(stages) if isinstance(stage, Estimator)), default=-1)
         model_stages: List[Stage] = []
-        with metrics.timed("pipeline.fit"):
-            for i, stage in enumerate(self._stages):
-                # one span per stage slot covering the stage's fit AND its
-                # transform of the training data for downstream stages —
-                # the per-stage cost of this Pipeline.fit, which a bare
-                # stage.fit span would understate
-                with tracing.span(
-                    "pipeline.stage",
-                    index=i,
-                    stage=type(stage).__name__,
-                    op="fit",
-                ):
+        waiting: List[Stage] = []  # fitted, and the training table not yet through them
+
+        def fit_stage(i: int, stage: Stage, table: Table) -> None:
+            # one span per stage slot; the training table's way through the
+            # fitted stages lies between them (`pipeline.segment` spans)
+            with tracing.span("pipeline.stage", index=i, stage=type(stage).__name__, op="fit"):
+                model_stages.append(stage.fit(table) if isinstance(stage, Estimator) else stage)
+
+        with metrics.timed("pipeline.fit"), tracing.phase("pipeline.fit"):
+            with tracing.phase("pipeline.prep"):
+                read_before = metrics.get_counter("readback.bytes")
+                for i, stage in enumerate(stages[: max(last, 0)]):
                     if isinstance(stage, Estimator):
-                        model: Stage = stage.fit(table)
-                    else:
-                        model = stage
-                    model_stages.append(model)
-                    if i < last_estimator_idx:
-                        if not isinstance(model, AlgoOperator):
-                            raise TypeError(
-                                f"Intermediate stage {type(stage).__name__} cannot transform data"
-                            )
-                        table = _transform_one(model, table)
+                        table = _through(waiting, i - len(waiting), table, given, stages[i : last + 1])
+                        waiting = []
+                    fit_stage(i, stage, table)
+                    if not isinstance(model_stages[-1], AlgoOperator):
+                        raise TypeError(
+                            f"Intermediate stage {type(stage).__name__} cannot transform data"
+                        )
+                    waiting.append(model_stages[-1])
+                if last >= 0:
+                    table = _through(waiting, last - len(waiting), table, given, stages[last : last + 1])
+                metrics.inc_counter(
+                    "pipeline.prep.readback_bytes", metrics.get_counter("readback.bytes") - read_before
+                )
+            for i in range(max(last, 0), len(stages)):
+                fit_stage(i, stages[i], table)
         return PipelineModel(model_stages)
 
     def save(self, path: str) -> None:

@@ -5,8 +5,8 @@ the XLA modules of the training programs (`train_programs` in
 `perf/configs/*.json`; `perf/metrics/epoch_roofline.py` raises on the chip
 when a trace holds none of them), the counters of `utils.metrics` that the
 readers under `perf/metrics/` take from `run["counters"]`, and the phases
-behind the `fit_*_ms` and `stream_*_ms` readers (`docs/observability.md`
-"Fit phases", "Online phases"). A
+behind the `fit_*_ms`, `stream_*_ms` and `pipeline_prep_ms` readers
+(`docs/observability.md` "Fit phases", "Online phases", "Pipeline phases"). A
 rename in the package is found here, on the CPU, before it costs a chip run:
 the name stays, or it changes in a `benchmark` PR together with the file
 under `perf/` that reads it. The cases are collected from the benchmark's own
@@ -85,6 +85,11 @@ def toy_fit(config_name: str, shards: int) -> dict:
         features = by_rows(values)
     label = by_rows((values.sum(axis=1) > DIM / 2).astype(np.float32))
     table = Table({"features": features, "label": label})
+    if "pipeline" in config:
+        # a pipeline configuration: its feature stages in front of the
+        # estimator, over raw columns of their names (a numeric matrix, index
+        # columns of a few categories each, the last index in every one)
+        stage, table = pipeline_and_raw_table(config, stage, by_rows, rng, label)
     if online:
         # a stream configuration: the table's rows as a stream of toy sparse
         # batches, one of the global size and one cut and joined, folded to its end
@@ -124,6 +129,27 @@ def toy_fit(config_name: str, shards: int) -> dict:
             jax.monitoring.unregister_event_duration_listener(on_lowering)
         counters = metrics.snapshot_delta(before, metrics.snapshot())["counters"]
     return {"lowered": lowered, "counters": counters}
+
+
+def pipeline_and_raw_table(config: dict, estimator, by_rows, rng, label):
+    """The configuration's feature stages, built as the benchmark's generator
+    builds them, in front of `estimator`, and a toy raw table of their columns."""
+    import importlib.util
+
+    from flink_ml_tpu import Pipeline
+
+    path = ROOT / "perf" / "generators" / "pipeline_fit_loop.py"
+    spec = importlib.util.spec_from_file_location("perf_generators_pipeline_fit_loop", path)
+    generator = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(generator)
+    stages, columns = [generator.make_stage(spec) for spec in config["pipeline"]], {"label": label}
+    scaler, encoder = stages[0], stages[1]
+    columns[scaler.get_input_col()] = by_rows(rng.random((ROWS, config["data"]["integer_fields"])).astype(np.float32))
+    for f, name in enumerate(encoder.get_input_cols()):
+        column = rng.integers(0, 3 + f, ROWS).astype(np.int32)
+        column[f] = 2 + f
+        columns[name] = by_rows(column)
+    return Pipeline([*stages, estimator]), Table(columns)
 
 
 def toy_fit_of_cell(cell_name: str) -> dict:
@@ -173,11 +199,41 @@ def test_train_program_is_launched_under_the_name_the_configuration_gives(config
     )
 
 
+def dense_assembly() -> dict:
+    """The counters a dense assembly moves: the side of `assembled_sparse_share`
+    that its cell exists to show absent, so that no cell's toy fit can hold
+    the name."""
+    from flink_ml_tpu.models.feature.vectorassembler import VectorAssembler
+
+    before = metrics.snapshot()
+    VectorAssembler().set_input_cols("a", "b").set_output_col("o").transform(
+        Table({"a": np.ones((4, 2), np.float32), "b": np.ones(4, np.float32)})
+    )
+    return metrics.snapshot_delta(before, metrics.snapshot())["counters"]
+
+
+OTHER_SIDE = {"assembler.dense_out": dense_assembly}
+
+
 @pytest.mark.parametrize("metric,counter", counters_read())
 def test_counter_a_metric_reads_is_counted_by_a_fit(metric, counter):
     cells = METRICS.get(metric, {}).get("workloads") or sorted(CELLS)
+    if counter in OTHER_SIDE:
+        assert not any(toy_fit_of_cell(cell)["counters"].get(counter, 0) for cell in cells)
+        assert OTHER_SIDE[counter]()[counter] > 0, f"perf/metrics/{metric}.py reads the counter {counter}: {FOLLOW}"
+        return
     assert any(toy_fit_of_cell(cell)["counters"].get(counter, 0) > 0 for cell in cells), (
         f"perf/metrics/{metric}.py reads the counter {counter}, which a toy fit of none of {cells} moved: {FOLLOW}"
+    )
+
+
+@pytest.mark.parametrize(
+    "config,name", [(c["name"], p) for c in CONFIGS.values() for p in c.get("prep_programs", [])]
+)
+def test_prep_program_is_launched_under_the_name_the_configuration_gives(config, name):
+    assert name in toy_fit(config, 1)["lowered"], (
+        f"perf/configs/{config}.json names {name} under prep_programs, whose device time "
+        f"perf/metrics/prep_roofline.py sums, but a toy fit ran {toy_fit(config, 1)['lowered']}: {FOLLOW}"
     )
 
 
@@ -186,9 +242,48 @@ def test_documented_phase_is_emitted_once_a_fit(phase):
     """Once in every fit that goes through it (`fit.layout`: not on one
     shard), and in one cell's fit at least."""
     counted = {cell: toy_fit_of_cell(cell)["counters"].get(phase + ".n", 0) for cell in CELLS}
+    for cell in pipeline_cells():
+        # `fit.total` is every estimator's, once a fitted stage: a pipeline's
+        # fit counts one for itself and one for each estimator in front of
+        # the trainer; the other phases are the trainer's alone
+        if phase == "fit.total":
+            counted[cell] -= 1 + estimators_of(CONFIGS[CELLS[cell]["config"]])
     assert set(counted.values()) in ({1}, {0, 1}), (
         f"docs/observability.md lists the phase {phase}; toy fits counted {counted} of it: {FOLLOW}"
     )
+
+
+def pipeline_cells() -> list:
+    return sorted(name for name, cell in CELLS.items() if "pipeline" in CONFIGS[cell["config"]])
+
+
+def estimators_of(config: dict) -> int:
+    """The estimators among a pipeline configuration's feature stages."""
+    from flink_ml_tpu.api import Estimator
+
+    classes = [spec["class"].rpartition(".") for spec in config["pipeline"]]
+    return sum(issubclass(getattr(importlib.import_module(m), c), Estimator) for m, _, c in classes)
+
+
+@pytest.mark.parametrize("phase", documented_phases("Pipeline phases", "pipeline"))
+def test_documented_pipeline_phase_is_emitted_once_a_pipeline_fit(phase):
+    assert pipeline_cells()
+    for cell in CELLS:
+        counted = toy_fit_of_cell(cell)["counters"].get(phase + ".n", 0)
+        assert counted == (1 if cell in pipeline_cells() else 0), (
+            f"docs/observability.md lists the pipeline phase {phase}; a toy fit of {cell} counted {counted}: {FOLLOW}"
+        )
+
+
+def test_a_toy_pipeline_fit_keeps_its_columns_on_the_device():
+    for cell in pipeline_cells():
+        counters = toy_fit_of_cell(cell)["counters"]
+        fields = len(CONFIGS[CELLS[cell]["config"]]["data"]["cardinalities"])
+        assert counters["onehot.fit.device"] == fields and not counters.get("onehot.fit.host")
+        assert counters["assembler.sparse_out"] == 1
+        assert counters["pipeline.prep.readback_bytes"] < 4096
+        # moments, sizes, guards, the plan's counts, the packed result: whatever the fields
+        assert counters["iteration.host_sync"] == 5
 
 
 @pytest.mark.parametrize("phase", documented_phases("Online phases", "online"))
