@@ -30,7 +30,14 @@ from ...common.param import (
     HasPredictionCol,
     HasSeed,
 )
-from ...ops.distance import DistanceMeasure, first_minimum, jit_find_closest
+from ...ops.distance import (
+    ALL_PIECES,
+    ONE_PIECE,
+    DistanceMeasure,
+    first_minimum,
+    in_bfloat16,
+    jit_find_closest,
+)
 from ...param import IntParam, ParamValidators, StringParam
 from ...parallel import collectives
 from ...parallel import mesh as mesh_lib
@@ -83,23 +90,29 @@ def _num_blocks(n: int, k: int, d: int) -> int:
     return -(-n // _block_rows(n, k, d))
 
 
-def _block_step(Xb, counted, centroids, measure):
+def _block_step(Xb, counted, centroids, measure, point_pieces=ALL_PIECES):
     """THE Lloyd block step, shared by every KMeans fit: each row of Xb
     (b, d) is assigned to its closest centroid (the lowest index on a tie),
     and the rows that `counted` (b,) marks add to the block's (sums (k, d),
     counts (k,)); a row not counted goes to a segment past the last cluster,
-    which is dropped. The sums are a segment-sum: on the v5e a fit of 2.7M x
-    784 rows against 4,096 centroids took 4.75 s with it and 5.65 s with
-    `one_hot.T @ X` at `HIGHEST` (PERF.md, PR 27), and it adds float32 to
-    float32 with no product to state a precision for."""
+    which is dropped. The distances' cross term is a float32 product
+    whatever `point_pieces` says; what it decides is the number of bfloat16
+    passes (`ops/distance.py`, "Precision"): six where nothing is known of
+    the points, three where the caller has seen that every value of the
+    table is exact in bfloat16, the three left out being products of zeros.
+    No term that holds anything is dropped. The sums are a segment-sum: on
+    the v5e a fit of 2.7M x 784 rows against 4,096 centroids took 4.75 s
+    with it and 5.65 s with `one_hot.T @ X` at `HIGHEST` (PERF.md, PR 27),
+    and it adds float32 to float32 with no product to state a precision
+    for."""
     k = centroids.shape[0]
-    assign = jnp.where(counted, first_minimum(measure.closeness(Xb, centroids)), k)
+    assign = jnp.where(counted, first_minimum(measure.closeness(Xb, centroids, point_pieces)), k)
     sums = jax.ops.segment_sum(Xb, assign, k + 1)[:k]
     counts = jax.ops.segment_sum(jnp.ones_like(assign, Xb.dtype), assign, k + 1)[:k]
     return sums, counts
 
 
-def _one_block(X, w, i, block, centroids, measure):
+def _one_block(X, w, i, block, centroids, measure, point_pieces=ALL_PIECES):
     """(sums, counts) of block i of the rows X (n, d), `block` rows each. n
     need not be a whole number of blocks: the last block is moved back to
     end at row n, and the rows the block before it has counted are masked
@@ -108,13 +121,13 @@ def _one_block(X, w, i, block, centroids, measure):
     n = X.shape[0]
     if block == n:
         counted = jnp.ones((n,), bool) if w is None else w > 0
-        return _block_step(X, counted, centroids, measure)
+        return _block_step(X, counted, centroids, measure, point_pieces)
     start = jnp.minimum(i * block, n - block)
     Xb = lax.dynamic_slice_in_dim(X, start, block, 0)
     counted = start + jnp.arange(block) >= i * block
     if w is not None:
         counted = counted & (lax.dynamic_slice_in_dim(w, start, block, 0) > 0)
-    return _block_step(Xb, counted, centroids, measure)
+    return _block_step(Xb, counted, centroids, measure, point_pieces)
 
 
 def _accumulate_batch_impl(X, w, centroids, measure_name):
@@ -180,7 +193,7 @@ def _new_centroids(centroids, sums, counts):
     )
 
 
-def _lloyd_loop(X, w, init_centroids, max_iter, measure_name, axis):
+def _lloyd_loop(X, w, init_centroids, max_iter, measure_name, axis, point_pieces=ALL_PIECES):
     """`max_iter` Lloyd iterations over the rows held here as ONE flat loop:
     step t is block t mod blocks of iteration t div blocks, and the step
     that ends an iteration adds the shards' partials (`axis`; None where the
@@ -201,7 +214,7 @@ def _lloyd_loop(X, w, init_centroids, max_iter, measure_name, axis):
 
     def step(state):
         t, centroids, last_counts, partials = state
-        s, c = _one_block(X, w, t % blocks, block, centroids, measure)
+        s, c = _one_block(X, w, t % blocks, block, centroids, measure, point_pieces)
         partials = (partials[0] + s, partials[1] + c)
 
         def end_of_iteration():
@@ -221,34 +234,35 @@ def _lloyd_loop(X, w, init_centroids, max_iter, measure_name, axis):
     return centroids, counts
 
 
-def _lloyd_train_impl(X, weights, init_centroids, max_iter, measure_name, mesh):
+def _lloyd_train_impl(X, weights, init_centroids, max_iter, measure_name, mesh, point_pieces=ALL_PIECES):
     """The full Lloyd fit as one XLA program (`_lloyd_loop`). `mesh` is None
     where every device holds all the rows it is given (one shard, or a
     replicated table); else the rows are shared over the mesh's data axis
     and each shard walks the blocks of its own. Data and max_iter are
     runtime arguments so repeated fits with the same shapes reuse the
-    compiled executable."""
+    compiled executable. `point_pieces` is what the caller knows of ALL the
+    table's values (`_point_pieces`); the default knows nothing."""
     if mesh is None:
-        return _lloyd_loop(X, weights, init_centroids, max_iter, measure_name, None)
+        return _lloyd_loop(X, weights, init_centroids, max_iter, measure_name, None, point_pieces)
 
     def local(init_centroids, max_iter, X, w):
-        return _lloyd_loop(X, w, init_centroids, max_iter, measure_name, mesh_lib.DATA_AXIS)
+        return _lloyd_loop(X, w, init_centroids, max_iter, measure_name, mesh_lib.DATA_AXIS, point_pieces)
 
     return _on_row_shards(local, mesh, (init_centroids, max_iter), X, weights)
 
 
-def _lloyd_fit_impl(X, weights, init_centroids, max_iter, measure_name, mesh):
+def _lloyd_fit_impl(X, weights, init_centroids, max_iter, measure_name, mesh, point_pieces=ALL_PIECES):
     """`_lloyd_train_impl` with its result packed for ONE readback:
     [centroids.ravel | counts]."""
     centroids, counts = _lloyd_train_impl(
-        X, weights, init_centroids, max_iter, measure_name, mesh
+        X, weights, init_centroids, max_iter, measure_name, mesh, point_pieces
     )
     return jnp.concatenate([centroids.ravel(), counts])
 
 
 # Nothing is donated: the table is the caller's where it is trained in
 # place, and no output has its shape anyway.
-_lloyd_fit = lazy_jit(_lloyd_fit_impl, static_argnames=("measure_name", "mesh"))
+_lloyd_fit = lazy_jit(_lloyd_fit_impl, static_argnames=("measure_name", "mesh", "point_pieces"))
 
 
 def _lloyd_fleet_train_impl(X, weights, init_centroids, max_iters, measure_name, pack_sharding, mesh):
@@ -420,6 +434,34 @@ def _sample_without_replacement(rng: np.random.RandomState, n: int, k: int) -> n
     return np.asarray(out, dtype=np.int64)
 
 
+def _exact_in_bfloat16_impl(X):
+    """Whether EVERY value of the table is its own bfloat16: finite, and at
+    most 8 significant bits (pixel bytes, byte descriptors, small whole
+    numbers). One pass over all rows, one boolean."""
+    return jnp.all(jnp.isfinite(X) & (in_bfloat16(X) == X))
+
+
+_exact_in_bfloat16 = lazy_jit(_exact_in_bfloat16_impl)
+
+
+def _point_pieces(X_dev) -> int:
+    """How many bfloat16 pieces the staged float32 table's values have, for
+    the train program's cross term: `ONE_PIECE` where the table lies on a
+    TPU and a look at all of its rows says so, `ALL_PIECES` where it does
+    not, and where nothing is looked at (the CPU, whose float32 product is
+    one pass whatever the points are). The look is one small program and one
+    boolean read back, a host sync of the fit; every fit looks again, since
+    nothing says that a table is the one the last fit saw, and no sample can
+    promise what the short product stands on."""
+    from ...obs import tracing
+
+    if not mesh_lib.on_tpu(X_dev):
+        return ALL_PIECES
+    exact = bool(jax.device_get(_exact_in_bfloat16(X_dev)))
+    tracing.account_host_sync("look")
+    return ONE_PIECE if exact else ALL_PIECES
+
+
 @partial(lazy_jit, static_argnames=("n_pad", "sharding"))
 def _stage_points(X, n_pad, sharding):
     """A device-born table brought to what the fit needs, in HBM and in one
@@ -506,14 +548,17 @@ class KMeans(Estimator, KMeansParams):
             shards = mesh_lib.num_data_shards(mesh)
             row_mesh = mesh if shards > 1 else None
             max_iter_dev = jnp.asarray(max_iter, jnp.int32)
+            overlapped = config.collective_overlap and row_mesh is not None
+            # the overlap schedule keeps the six passes: nothing is looked at
+            point_pieces = ALL_PIECES if overlapped else _point_pieces(X_dev)
 
-        if config.collective_overlap and row_mesh is not None:
+        if overlapped:
             # overlap-scheduled Lloyd: epoch e's centroid-partial reduce
             # rides the chunked collective under epoch e+1's distance
             # matmul (parallel/overlap.py; the same block step)
             from ...parallel import overlap
 
-            def train(X, w, init, max_iter, measure, mesh):
+            def train(X, w, init, max_iter, measure, mesh, point_pieces):
                 return overlap.overlapped_lloyd_train(mesh, X, w, init, max_iter, measure)
 
         else:
@@ -524,6 +569,7 @@ class KMeans(Estimator, KMeansParams):
         if dispatch.whole_fit_enabled():
             dispatch.account_whole_fit("lloyd")
         metrics.inc_counter("lloyd.iterations", max_iter)
+        metrics.inc_counter("lloyd.product.short" if point_pieces == ONE_PIECE else "lloyd.product.full")
         metrics.inc_counter(
             "lloyd.blocks", max_iter * shards * _num_blocks(X_dev.shape[0] // shards, k, d)
         )
@@ -532,7 +578,7 @@ class KMeans(Estimator, KMeansParams):
         # `iteration.run` span carries the per-run summary
         with tracing.span("iteration.run", mode="device", epochs=max_iter):
             packed = dispatch.timed_dispatch(
-                train, X_dev, w_dev, init_centroids, max_iter_dev, measure, row_mesh,
+                train, X_dev, w_dev, init_centroids, max_iter_dev, measure, row_mesh, point_pieces,
                 start=0, end=max_iter,
             )
             host = _read_packed(packed)  # the fit's one readback

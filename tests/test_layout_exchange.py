@@ -638,3 +638,68 @@ def test_compiled_for_a_v5e_the_one_read_epoch_copies_no_table(four_v5e, rows, l
     if rows == 20_000_000:
         reduce_form = compiled_flat(four_v5e, rows, getattr(losses, loss), False)
         assert not any(opcode == "custom-call" for _, opcode in instructions(reduce_form))
+
+
+# --- one chip: the Lloyd loop's cross term by the pieces its points have (ops/distance.py) ------
+
+
+def compiled_lloyd(four_v5e, point_pieces):
+    """`_lloyd_fit_impl` at the k-means cell's size, 2.7M x 784 rows against
+    4,096 centroids, on ONE described v5e."""
+    from jax.sharding import SingleDeviceSharding
+
+    from flink_ml_tpu.models.clustering import kmeans as km
+
+    chip = SingleDeviceSharding(four_v5e.devices.flat[0])
+    fit = lambda X, init, max_iter: km._lloyd_fit_impl(X, None, init, max_iter, "euclidean", None, point_pieces)
+    return jax.jit(fit).lower(
+        jax.ShapeDtypeStruct((2_700_000, 784), np.float32, sharding=chip),
+        jax.ShapeDtypeStruct((4096, 784), np.float32, sharding=chip),
+        jax.ShapeDtypeStruct((), np.int32, sharding=chip),
+    ).compile()
+
+
+def products(compiled):
+    """[(operand element types, the instruction's line)] of every matrix
+    product of a compiled program, which the TPU's compiler writes as a
+    convolution inside a fusion."""
+    text = compiled.as_text()
+    found = []
+    for line in text.splitlines():
+        m = re.search(r" convolution\(%([\w.\-]+), %([\w.\-]+)\)", line)
+        if m:
+            types = [re.search(rf"%{re.escape(name)} = (\w+)\[", text).group(1) for name in m.groups()]
+            found.append((types, line))
+    return found
+
+
+def test_compiled_for_a_v5e_the_short_cross_term_is_bfloat16_products_and_no_float32_one(four_v5e):
+    """The points known to be one piece: the block's bfloat16 rows against
+    the centroids' three bfloat16 pieces in ONE product (the pieces a second
+    contracted axis of three, added in the unit's accumulators), no float32
+    operand and no six-pass product anywhere; the pieces are rounded by an op
+    the compiler keeps; no temporary near the table's size. Not known to be:
+    the one float32 product at `highest`, as it was."""
+    from flink_ml_tpu.ops.distance import ALL_PIECES, ONE_PIECE
+
+    short = compiled_lloyd(four_v5e, ONE_PIECE)
+    ((types, line),) = products(short)
+    assert types == ["bf16", "bf16"] and "window={size=3}" in line and "operand_precision" not in line
+    assert "= f32[6656,4096," in line and "highest" not in short.as_text()
+    assert len(re.findall(r" reduce-precision\(", short.as_text())) == 2  # hi, and mid of what hi left
+    memory = short.memory_analysis()
+    assert memory.argument_size_in_bytes >= 2_700_000 * 784 * 4
+    assert memory.temp_size_in_bytes < 256 << 20
+    ((types, line),) = products(compiled_lloyd(four_v5e, ALL_PIECES))
+    assert types == ["f32", "f32"] and "operand_precision={highest,highest}" in line
+
+
+def test_compiled_for_a_v5e_the_look_is_one_pass_with_no_temporary(four_v5e):
+    from jax.sharding import SingleDeviceSharding
+
+    from flink_ml_tpu.models.clustering import kmeans as km
+
+    table = jax.ShapeDtypeStruct((2_700_000, 784), np.float32, sharding=SingleDeviceSharding(four_v5e.devices.flat[0]))
+    look = jax.jit(km._exact_in_bfloat16_impl).lower(table).compile()
+    assert " reduce-precision(" in look.as_text() and " convert(" not in look.as_text()
+    assert look.memory_analysis().temp_size_in_bytes < 1 << 20

@@ -68,8 +68,12 @@ def toy_fit(config_name: str, shards: int) -> dict:
         return jax.device_put(arr, mesh_lib.data_sharding(mesh, arr.ndim))
 
     # a float16 table is copied once for the Lloyd loop, which the cell's
-    # float32 table is not: `lloyd.table_copy` is held by a tick, not an absence
-    values = rng.random((ROWS, DIM)).astype(np.float16 if lloyd else np.float32)
+    # float32 table is not: `lloyd.table_copy` is held by a tick, not an absence;
+    # its values are pixel bytes as the cell's are, so that the fit's look
+    # finds them exact in bfloat16 and takes the short cross term
+    values = rng.random((ROWS, DIM)).astype(np.float32)
+    if lloyd:
+        values = rng.integers(0, 256, (ROWS, DIM)).astype(np.float16)
     if "nnz" in config["data"]:
         indices = np.sort(rng.integers(0, 40, (ROWS, DIM)).astype(np.int32), axis=1)
         size = 40
@@ -212,7 +216,19 @@ def dense_assembly() -> dict:
     return metrics.snapshot_delta(before, metrics.snapshot())["counters"]
 
 
-OTHER_SIDE = {"assembler.dense_out": dense_assembly}
+def lloyd_fit_of_general_floats() -> dict:
+    """The counters a k-means fit of a table that is not exact in bfloat16
+    moves (and every fit on the CPU): the side of `lloyd_short_product_share`
+    that the cell's pixel table never takes."""
+    from flink_ml_tpu.models.clustering.kmeans import KMeans
+
+    before = metrics.snapshot()
+    table = Table({"features": np.random.default_rng(3).random((64, DIM)).astype(np.float32)})
+    KMeans().set_k(K).set_max_iter(MAX_ITER).fit(table)
+    return metrics.snapshot_delta(before, metrics.snapshot())["counters"]
+
+
+OTHER_SIDE = {"assembler.dense_out": dense_assembly, "lloyd.product.full": lloyd_fit_of_general_floats}
 
 
 @pytest.mark.parametrize("metric,counter", counters_read())
