@@ -58,6 +58,11 @@ results = []
 collector = flow.spawn(lambda: results.extend(server.results()), name="example.collect")
 
 
+# model bytes the process held before this store staged any (none in a run of
+# its own; a fitted pipeline kept by an earlier fit of the same process)
+held_before = memledger.live_bytes("model")
+
+
 def submit_round_robin(count):
     peak = 0
     for i in range(count):
@@ -68,7 +73,7 @@ def submit_round_robin(count):
                 break
             except ServerOverloaded:
                 time.sleep(0.002)
-        peak = max(peak, memledger.live_bytes("model"))
+        peak = max(peak, memledger.live_bytes("model") - held_before)
     return peak
 
 

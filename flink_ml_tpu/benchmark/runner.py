@@ -21,8 +21,6 @@ import json
 import re
 import sys
 import time
-
-import numpy as np
 from typing import Dict, List, Optional
 
 from ..api import AlgoOperator, Estimator, Model
@@ -141,16 +139,15 @@ def run_benchmark(name: str, entry: Dict) -> Dict:
 
     delta = metrics.snapshot_delta(metrics_before, metrics.snapshot())
     # dispatch-wall attribution (obs/timeline.py): the work phases' wall
-    # split into host-dispatch time (the `iteration.dispatch` funnel —
-    # every chunk/fused-program launch rides it) and the GAP the host was
-    # not dispatching: device execution + readback + idle latency.
+    # split into host-dispatch time (the `fit.launch` phase of the dispatch
+    # funnel — every chunk/fused-program launch rides it) and the GAP the
+    # host was not dispatching: device execution + readback + idle latency.
     # `dispatchGapMs ~ wallMs - hostDispatchMs` is THE item-2 progress
     # metric: the resident-program work must grow hostDispatch's share of
     # a shrinking wall. gapCount = dispatch->drain cycles (one per chunk).
     work_ms = (phases.get("fit", 0.0) + phases.get("transform", 0.0)) * 1000.0
-    disp_timer = delta["timers"].get("iteration.dispatch", {})
-    host_dispatch_ms = float(disp_timer.get("totalMs", 0.0))
-    gap_count = int(disp_timer.get("count", 0))
+    host_dispatch_ms = delta["counters"].get("fit.launch.ns", 0) / 1e6
+    gap_count = int(delta["counters"].get("fit.launch.n", 0))
     return {
         "name": name,
         "totalTimeMs": elapsed_ms,
@@ -344,11 +341,8 @@ def _block_until_ready(tables: List[Table]) -> None:
                 if isinstance(arr, jax.Array):
                     probes.append(arr[(0,) * arr.ndim].astype(jnp.float32))
     if probes:
-        t0 = time.perf_counter()
-        # tpulint: disable=host-sync-leak -- this IS the timing barrier: one probe readback, accounted via account_readback below
-        host = np.asarray(jnp.stack(probes))
-        # the barrier is itself a readback — account it like any other
-        tracing.account_readback(host.nbytes, time.perf_counter() - t0, len(probes))
+        # the barrier is itself a blocking read: through the funnel, like any other
+        tracing.sync("barrier", jnp.stack(probes), arrays=len(probes))
 
 
 def execute_benchmarks(config: Dict) -> Dict[str, Dict]:

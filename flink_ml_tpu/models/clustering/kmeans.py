@@ -457,8 +457,7 @@ def _point_pieces(X_dev) -> int:
 
     if not mesh_lib.on_tpu(X_dev):
         return ALL_PIECES
-    exact = bool(jax.device_get(_exact_in_bfloat16(X_dev)))
-    tracing.account_host_sync("look")
+    exact = bool(tracing.sync("look", _exact_in_bfloat16(X_dev)))
     return ONE_PIECE if exact else ALL_PIECES
 
 

@@ -164,8 +164,7 @@ class Bucketizer(Transformer, BucketizerParams):
             # when a row is actually invalid
             from ...obs import tracing
 
-            tracing.account_host_sync("transform")
-            if bool(combined.any()):
+            if bool(tracing.sync("transform", combined.any())):
                 invalid_mask |= np.asarray(combined)
         out = table.with_columns(updates)
         if invalid_mask.any():

@@ -36,7 +36,6 @@ See docs/observability.md for the full surface and a worked example.
 
 from . import hist, timeline  # noqa: F401
 from .tracing import (  # noqa: F401
-    account_host_sync,
     add_attr,
     configure,
     current_span,
@@ -47,4 +46,5 @@ from .tracing import (  # noqa: F401
     phase,
     set_dispatch_depth,
     span,
+    sync,
 )

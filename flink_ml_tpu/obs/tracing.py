@@ -27,12 +27,16 @@ A few phases a fit (`phase()`: `fit.total`, `fit.extract`, `fit.stage` and
 wait for a sink: always counted (`<name>.ns`, `<name>.n`), and `fml.<name>`
 host events of whatever `jax.profiler` trace is being taken, so that an idle
 gap of the device can be named by what the host was doing in it
-(`report.render_device_profile`).
+(`report.render_device_profile`). Every blocking read of the device goes
+through one funnel of the same form, `sync(kind, x)`: the wait apart from
+the copy. The outermost fit of a thread is counted once (`fit.outer`), and
+the cycle collector's pauses have a name (`host.gc`).
 """
 
 from __future__ import annotations
 
 import contextvars
+import gc
 import itertools
 import json
 import os
@@ -248,13 +252,16 @@ class Phase(Span):
         end_ns = time.perf_counter_ns()
         self.dur_ns = end_ns - self._start_ns
         if not self._void:
-            metrics.inc_counter(self.name + ".ns", self.dur_ns)
-            metrics.inc_counter(self.name + ".n")
+            self._count()
         if self._sunk:
             self._close(end_ns, exc_type)
         if self._annotation is not None:
             self._annotation.__exit__(exc_type, exc, tb)
         return False
+
+    def _count(self) -> None:
+        metrics.inc_counter(self.name + ".ns", self.dur_ns)
+        metrics.inc_counter(self.name + ".n")
 
 
 phase = Phase  # spelled like `span`
@@ -319,35 +326,105 @@ def emit_completed(name: str, start_ns: int, dur_s: float, **attrs) -> None:
 
 
 # ---------------------------------------------------------------------------
-# device/runtime accounting: readbacks, XLA compiles
+# device/runtime accounting: blocking reads, collectives, XLA compiles, pauses
 # ---------------------------------------------------------------------------
 
-def account_readback(nbytes: int, seconds: float, arrays: int = 1) -> None:
-    """Fold one device→host transfer into the registry (+ a trace span).
-    Called by the explicit readback funnels (`utils.packing`, the benchmark
-    runner's phase barriers) — the paths every fit/transform readback rides."""
-    metrics.inc_counter("readback.count")
-    metrics.inc_counter("readback.bytes", int(nbytes))
-    metrics.record_time("readback", seconds)
+class _Fits(threading.local):
+    """The fits open on this thread (a pipeline's fit holds its stages'):
+    how many deep, and the ordinal of the outermost one, the one identifier
+    every span of one fit shares."""
+
+    depth = 0
+    ordinal = 0
+
+
+_fits = _Fits()
+_fit_ordinals = itertools.count(1)
+
+
+class _SyncStep(Phase):
+    """One of the two steps of `sync`, `sync.<kind>.wait` or
+    `sync.<kind>.copy`: a phase that counts its time alone (the funnel
+    counts the sync), and adds it to the open fit's sum."""
+
+    __slots__ = ("_sum",)
+
+    def __init__(self, kind: str, step: str):
+        Phase.__init__(self, "sync." + kind + "." + step, marks=False)
+        self._sum = "fit.sync." + step + ".ns"
+        if _enabled:
+            self.attrs = {"category": "readback"}
+            if _fits.depth:
+                self.attrs["fit"] = _fits.ordinal
+
+    def _count(self) -> None:
+        metrics.inc_counter(self.name + ".ns", self.dur_ns)
+        if _fits.depth:
+            metrics.inc_counter(self._sum, self.dur_ns)
+
+
+def sync(kind: str, x, arrays: int = 1, copy: bool = True):
+    """THE funnel of a blocking read: the host waits for the device value
+    `x` and takes it, in two steps, each one pair of clock reads. The
+    **wait** (`jax.block_until_ready`: the device's work and the runtime's
+    notice of it) and then the **copy** (`np.asarray(jax.device_get(x))`:
+    what is left of the transfer once the result is ready, and the host
+    copy; explicit, so that it passes `jax.transfer_guard("disallow")`).
+    The transfer is requested before the wait (`copy_to_host_async`), so it
+    starts when the device has the result, not a round trip later. Returns
+    the host array.
+
+    Always counted, as a `Phase` is: `sync.<kind>.wait.ns`,
+    `sync.<kind>.copy.ns`, `sync.<kind>.n`, `sync.<kind>.bytes`, and inside
+    a fit the sums `fit.sync.wait.ns`, `fit.sync.copy.ns`, `fit.sync.bytes`
+    (with `fit.outer.ns`: a fit's wall = its own host time + wait + copy).
+    While a profile is taken the steps are `fml.sync.<kind>.wait` and
+    `fml.sync.<kind>.copy` on its host plane, inside whatever phase holds
+    the call; under a sink they are span records (`category=readback`,
+    and inside a fit `fit` = the ordinal of the outermost one), and on the
+    timeline the `readback` lane's events. Every sync is also one
+    `iteration.host_sync` / `iteration.host_sync.<kind>` and one
+    `readback.count` / `readback.bytes` (of `arrays` arrays packed).
+
+    `copy=False` is a wait alone (the online loop's fence): nothing is
+    read, so it ticks no `iteration.host_sync*` and no `readback.*`, and
+    returns None; `x` may then be any pytree of device arrays."""
+    import jax
+    import numpy as np
+
+    with _SyncStep(kind, "wait") as wait:
+        if copy:
+            # asked for before the wait, as `jax.device_get` asks: the transfer
+            # queues behind the program and not behind the host's notice of its end
+            x.copy_to_host_async()
+        # tpulint: disable=host-sync-leak -- THE accounted funnel: the wait of a blocking read, timed apart from its copy
+        jax.block_until_ready(x)
+    metrics.inc_counter("sync." + kind + ".n")
     if timeline.enabled():
-        end_ns = time.perf_counter_ns()
+        timeline.record_complete(timeline.LANE_READBACK, wait.name, wait.start_ns, wait.dur_ns)
+    if not copy:
+        return None
+    with _SyncStep(kind, "copy") as copied:
+        host = np.asarray(jax.device_get(x))
+        if _enabled:
+            copied.attrs["bytes"] = host.nbytes
+            copied.attrs["arrays"] = arrays
+    metrics.inc_counter("sync." + kind + ".bytes", host.nbytes)
+    if _fits.depth:
+        metrics.inc_counter("fit.sync.bytes", host.nbytes)
+    # every sync blocks the host on the device: a loop that syncs
+    # O(maxIter) times instead of O(maxIter/K) is a jump of these counters
+    metrics.inc_counter("iteration.host_sync")
+    metrics.inc_counter("iteration.host_sync." + kind)
+    metrics.inc_counter("readback.count")
+    metrics.inc_counter("readback.bytes", host.nbytes)
+    if timeline.enabled():
+        timeline.record_instant(timeline.host_lane(), "host_sync." + kind)
         timeline.record_complete(
-            timeline.LANE_READBACK,
-            "readback",
-            end_ns - int(seconds * 1e9),
-            int(seconds * 1e9),
-            bytes=int(nbytes),
-            arrays=arrays,
+            timeline.LANE_READBACK, copied.name, copied.start_ns, copied.dur_ns,
+            bytes=host.nbytes, arrays=arrays,
         )
-    if _enabled:
-        emit_completed(
-            "readback",
-            time.perf_counter_ns() - int(seconds * 1e9),
-            seconds,
-            category="readback",
-            bytes=int(nbytes),
-            arrays=arrays,
-        )
+    return host
 
 
 def account_collective(
@@ -391,22 +468,8 @@ def account_collective(
         event(f"collective.{op}", **attrs)
 
 
-def account_host_sync(kind: str = "drain", count: int = 1) -> None:
-    """Fold one blocking host↔device synchronization point into the
-    registry: a convergence-scalar drain, a packed fit-result readback, a
-    checkpoint carry pull. `host_sync_count` is THE dispatch-pipeline
-    regression metric — every sync blocks the host on the device, so a
-    loop that syncs O(maxIter) times instead of O(maxIter/K) is visible
-    as a counter jump in any BENCH delta."""
-    metrics.inc_counter("iteration.host_sync", count)
-    metrics.inc_counter(f"iteration.host_sync.{kind}", count)
-    if timeline.enabled():
-        timeline.record_instant(timeline.host_lane(), f"host_sync.{kind}")
-
-
 def set_dispatch_depth(depth: int) -> None:
-    """Record the in-flight dispatch depth a pipelined loop ran at (gauge;
-    embedded in BENCH entry deltas next to host_sync_count)."""
+    """Record the in-flight dispatch depth a pipelined loop ran at (gauge)."""
     metrics.set_gauge("iteration.dispatch_depth", depth)
 
 
@@ -416,9 +479,10 @@ _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 def install_jax_hooks() -> bool:
     """Register a `jax.monitoring` listener translating backend-compile
-    events into `jit.compiles`/`jit.compile` metrics and `category=compile`
-    spans. Idempotent; deferred until jax is already imported so this
-    module never pays the jax import itself."""
+    events into `jit.compiles`/`jit.compile.ns`/`jit.compile` metrics (a
+    pause by compilation has a length as well as a count) and
+    `category=compile` spans. Idempotent; deferred until jax is already
+    imported so this module never pays the jax import itself."""
     global _jax_hooks_installed
     if _jax_hooks_installed:
         return True
@@ -438,6 +502,7 @@ def install_jax_hooks() -> bool:
         # tests/test_modelstore.py) honest when the bank satisfies a
         # program without a compile.
         metrics.inc_counter("jit.compiles")
+        metrics.inc_counter("jit.compile.ns", int(duration * 1e9))
         metrics.record_time("jit.compile", duration)
         from . import hist
 
@@ -456,6 +521,47 @@ def install_jax_hooks() -> bool:
 
 
 # ---------------------------------------------------------------------------
+# the cycle collector's pauses
+# ---------------------------------------------------------------------------
+
+_gc_start_ns = 0
+_gc_annotation = None
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    """`gc.callbacks` entry (jax installs one of its own the same way):
+    every collection adds to `host.gc.ns` and `host.gc.n`, a full one
+    (generation 2: tens of milliseconds over a large heap) also to
+    `host.gc.full.n`, and is `fml.host.gc` on the clock of a profile being
+    taken and an event of the timeline's host lane; younger generations get
+    neither. It emits no span record: a collection can start inside
+    `_emit`, under its lock."""
+    global _gc_start_ns, _gc_annotation
+    if phase == "start":
+        if info["generation"] == 2 and _TraceAnnotation is not None and _TraceAnnotation.is_enabled():
+            _gc_annotation = _TraceAnnotation(PHASE_PREFIX + "host.gc")
+            _gc_annotation.__enter__()
+        _gc_start_ns = time.perf_counter_ns()
+        return
+    start_ns, _gc_start_ns = _gc_start_ns, 0
+    if not start_ns:  # installed while a collection ran
+        return
+    dur_ns = time.perf_counter_ns() - start_ns
+    metrics.inc_counter("host.gc.ns", dur_ns)
+    metrics.inc_counter("host.gc.n")
+    if info["generation"] == 2:
+        metrics.inc_counter("host.gc.full.n")
+        if _gc_annotation is not None:
+            _gc_annotation.__exit__(None, None, None)
+            _gc_annotation = None
+        if timeline.enabled():
+            timeline.record_complete(timeline.host_lane(), "host.gc", start_ns, dur_ns)
+
+
+gc.callbacks.append(_on_gc)
+
+
+# ---------------------------------------------------------------------------
 # automatic stage instrumentation (wired from api.Stage.__init_subclass__)
 # ---------------------------------------------------------------------------
 
@@ -465,20 +571,37 @@ def _wrap_stage_method(fn, op: str):
     from . import memledger
 
     @functools.wraps(fn)
-    def wrapper(self, *args, **kwargs):
-        if op == "fit":
-            # the fit.total phase and the per-fit HBM watermark
-            # (hbm.peak.fit) are always on, like the metrics registry:
-            # no sink required
-            with Phase("fit.total"), memledger.fit_peak_scope():
-                if not _enabled:
-                    return fn(self, *args, **kwargs)
-                with Span("stage." + op, {"stage": type(self).__name__}):
-                    return fn(self, *args, **kwargs)
+    def traced(self, *args, **kwargs):
         if not _enabled:
             return fn(self, *args, **kwargs)
         with Span("stage." + op, {"stage": type(self).__name__}):
             return fn(self, *args, **kwargs)
+
+    wrapper = traced
+    if op == "fit":
+
+        @functools.wraps(fn)
+        def wrapper(self, *args, **kwargs):
+            # the fit.total phase and the per-fit HBM watermark
+            # (hbm.peak.fit) are always on, like the metrics registry: no
+            # sink required. `fit.total` counts every estimator, a
+            # pipeline's stages too; the outermost fit of the thread is
+            # also `fit.outer`, once, from the same clock reads, and opens
+            # the ordinal its syncs' spans carry
+            fits = _fits
+            outermost = fits.depth == 0
+            if outermost:
+                fits.ordinal = next(_fit_ordinals)
+            fits.depth += 1
+            total = Phase("fit.total")
+            try:
+                with total, memledger.fit_peak_scope():
+                    return traced(self, *args, **kwargs)
+            finally:
+                fits.depth -= 1
+                if outermost:
+                    metrics.inc_counter("fit.outer.ns", total.dur_ns)
+                    metrics.inc_counter("fit.outer.n")
 
     wrapper._obs_instrumented = True
     return wrapper

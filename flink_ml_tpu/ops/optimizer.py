@@ -905,16 +905,14 @@ def read_train_result(async_result):
 
 
 def _read_packed(packed) -> np.ndarray:
-    """The fit's one packed readback, as the `fit.readback` phase (it holds
-    the wait for the device): an explicit device_get, because the
-    transfer-guard readback-budget tests run fits under
-    jax.transfer_guard("disallow") to catch stray implicit pulls. On a
-    timeline it is the readback lane's event, from the same clock reads."""
-    with tracing.phase("fit.readback", marks=False) as readback:
-        host = np.asarray(jax.device_get(packed))
-    tracing.account_host_sync("fit")
-    tracing.account_readback(host.nbytes, readback.dur_ns / 1e9)
-    return host
+    """The fit's one packed readback, as the `fit.readback` phase around the
+    funnel's sync of kind `fit`: the wait for the train program apart from
+    the result's copy (an explicit device_get, because the transfer-guard
+    readback-budget tests run fits under jax.transfer_guard("disallow") to
+    catch stray implicit pulls). On a timeline the two steps are the
+    readback lane's events; the phase marks no host lane."""
+    with tracing.phase("fit.readback", marks=False):
+        return tracing.sync("fit", packed)
 
 
 @dataclass

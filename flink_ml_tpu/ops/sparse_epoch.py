@@ -153,11 +153,10 @@ def width_of(count: int) -> int:
 def column_plan(indices) -> Tuple[Optional[Tuple[int, ...]], Optional[jax.Array]]:
     """(each column's width, the dictionaries) of a staged table's ids, or
     (None, None) where every column keeps the gather. One small program and
-    one readback of a count a column: a host sync of the fit, counted as
-    one."""
+    one readback of a count a column: a host sync of the fit (kind `plan`),
+    whose wait is the program's pass over the table."""
     counts, dictionaries = _column_dictionaries(indices)
-    counts = np.asarray(jax.device_get(counts))
-    tracing.account_host_sync("plan")
+    counts = tracing.sync("plan", counts)
     widths = tuple(width_of(int(count)) for count in counts)
     if not any(widths):
         return None, None

@@ -29,8 +29,8 @@ Carry donation: the chunk programs ping-pong the carry in place in HBM
 caller does not need to retain the pre-chunk carry (checkpoint boundaries
 and listener callbacks retain; everything else donates).
 
-Every blocking drain is accounted as `iteration.host_sync` (obs/tracing),
-so BENCH deltas surface dispatch regressions.
+Every blocking drain goes through the timed funnel `tracing.sync("drain",
+...)` and counts as `iteration.host_sync` (obs/tracing).
 
 Drain boundaries are also the job-checkpoint hook points: a drained chunk
 whose end lands on a checkpoint boundary (`next_boundary` clamps chunk
@@ -45,8 +45,6 @@ from __future__ import annotations
 import time
 from collections import deque
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
-
-import numpy as np
 
 from ..obs import hist, timeline, tracing
 from ..utils import metrics
@@ -111,7 +109,7 @@ def account_whole_fit(kind: str = "fit") -> None:
 
 def account_whole_fit_fallback(reason: str) -> None:
     """Count a whole-fit-eligible loop falling back to the chunked path,
-    labelled with WHY (`dispatch.whole_fit_fallback.<reason>`) — the BENCH
+    labelled with WHY (`dispatch.whole_fit_fallback.<reason>`) — the benchmark
     runner surfaces the totals, so a config change that silently knocks
     fits off the resident path shows up as a counter jump."""
     metrics.inc_counter("dispatch.whole_fit_fallback")
@@ -230,9 +228,9 @@ def clear_runner_cache() -> None:
 def timed_dispatch(step: Callable, *args, start: int = None, end: int = None):
     """THE accounted chunk-dispatch funnel: every chunk program launch in
     the iteration runtime rides through here, so the host-side dispatch
-    cost is one timer (`iteration.dispatch` — the `hostDispatchMs` BENCH
-    field), the always-counted `fit.launch` phase (`fml.fit.launch` in a
-    profile; one a chunk where a fit is launched in chunks) and one
+    cost is the always-counted `fit.launch` phase (`fit.launch.ns` and
+    `.n`; `fml.fit.launch` in a profile; one a chunk where a fit is
+    launched in chunks; the benchmark runner's `hostDispatchMs`) and one
     timeline `dispatch`-lane event, and the dispatch-wall
     attribution (`obs.timeline.dispatch_attribution`) can split every
     fit's wall into dispatch + device + readback + idle-gap. On an async
@@ -247,9 +245,9 @@ def timed_dispatch(step: Callable, *args, start: int = None, end: int = None):
     from . import supervisor
 
     supervisor.pulse_boundary(supervisor.PHASE_DISPATCH)
-    # the launch is also the always-counted `fit.launch` phase: one pair of
-    # clock reads feeds the phase, the timer and the timeline's one event
-    # of it (the dispatch lane's, below: the phase marks no host lane)
+    # the launch is the always-counted `fit.launch` phase: one pair of
+    # clock reads feeds the phase and the timeline's one event of it (the
+    # dispatch lane's, below: the phase marks no host lane)
     with tracing.phase("fit.launch", marks=False) as launch:
         try:
             out = step(*args)
@@ -264,7 +262,6 @@ def timed_dispatch(step: Callable, *args, start: int = None, end: int = None):
                 raise wrapped from e
             raise
     t0, dur_ns = launch.start_ns, launch.dur_ns
-    metrics.record_time("iteration.dispatch", dur_ns / 1e9)
     supervisor.note_progress(dur_ns / 1e9)
     if timeline.enabled():
         attrs = {}
@@ -295,7 +292,8 @@ class DrainQueue:
     """Bounded-depth queue of dispatched chunks awaiting their convergence
     readback. `push` drains the oldest entry once more than `depth` chunks
     are in flight; `drain_all` empties it. Every drain is one blocking
-    packed-scalar readback, accounted as `iteration.host_sync`."""
+    packed-scalar readback through the funnel (`tracing.sync("drain", ...)`:
+    `iteration.host_sync`)."""
 
     def __init__(self, depth: int):
         self.depth = max(1, int(depth))
@@ -326,8 +324,6 @@ class DrainQueue:
         return out
 
     def _drain_one(self) -> Tuple[InFlight, int, float]:
-        import jax
-
         from . import supervisor
 
         entry, pushed_ns = self._q.popleft()
@@ -335,10 +331,7 @@ class DrainQueue:
         # the supervised mid-collective boundary sits right before it
         supervisor.pulse_boundary(supervisor.PHASE_COLLECTIVE)
         t0_ns = time.perf_counter_ns()
-        t0 = time.perf_counter()
-        host = np.asarray(jax.device_get(entry.packed))
-        tracing.account_host_sync("drain")
-        tracing.account_readback(host.nbytes, time.perf_counter() - t0)
+        host = tracing.sync("drain", entry.packed)
         end_ns = time.perf_counter_ns()
         # chunk wall: dispatch push -> drained scalar on host, the
         # per-chunk latency distribution of the dispatch pipeline — and
@@ -364,16 +357,12 @@ class DrainQueue:
 def drain_packed(packed) -> Tuple[int, float]:
     """Blocking readback of one packed [epoch, criteria] pair (the
     depth-1 / tail path), with the same accounting as DrainQueue."""
-    import jax
-
     from . import supervisor
 
     supervisor.pulse_boundary(supervisor.PHASE_COLLECTIVE)
-    t0 = time.perf_counter()
-    host = np.asarray(jax.device_get(packed))
-    tracing.account_host_sync("drain")
-    tracing.account_readback(host.nbytes, time.perf_counter() - t0)
-    supervisor.note_progress(time.perf_counter() - t0)
+    t0_ns = time.perf_counter_ns()
+    host = tracing.sync("drain", packed)
+    supervisor.note_progress((time.perf_counter_ns() - t0_ns) / 1e9)
     return int(host[0]), float(host[1])
 
 

@@ -32,6 +32,7 @@ import numpy as np
 from jax import lax
 
 from ..obs import tracing
+from ..utils import metrics
 
 BodyFn = Callable[[Any, jax.Array], Tuple[Any, jax.Array]]
 
@@ -103,7 +104,7 @@ def save_iteration_checkpoint(
     leaves = jax.tree_util.tree_leaves(carry)
     # one packed D2H transfer for the whole carry (a per-leaf np.asarray
     # pull is one blocking readback PER LEAF); counted as a checkpoint
-    # host sync so BENCH deltas separate snapshot cost from drain cost
+    # host sync, so that the counters separate snapshot cost from drain cost
     leaves = packed_device_get(*leaves, sync_kind="checkpoint")
     os.makedirs(path, exist_ok=True)
     target = _checkpoint_file(path, job_key)
@@ -183,7 +184,7 @@ def iterate_bounded(
 
 
 def _iterate_on_device(body: BodyFn, init_carry, max_iter: int, tol: Optional[float]):
-    from ..utils import metrics, packing
+    from ..utils import packing
 
     tol_value = -jnp.inf if tol is None else jnp.asarray(float(tol), jnp.float32)
 
@@ -251,7 +252,6 @@ def _iterate_host_driven(
     from .. import config
     from ..ckpt import faults
     from ..ckpt import snapshot as _snapshot
-    from ..utils import metrics
     from . import dispatch
 
     carry, epoch, criteria = init_carry, 0, float("inf")
@@ -386,15 +386,19 @@ _STREAM_END = object()
 
 
 def _wait_for(state) -> None:
-    """Block until `state`'s device arrays are whole (no readback). A leaf a
-    later step was given by donation is that step's to finish, and is passed
-    over."""
+    """Block until `state`'s device arrays are whole: the funnel's sync of
+    kind `fence`, a wait with no copy, so no `iteration.host_sync*` and no
+    `readback.*`. A leaf a later step was given by donation is that step's
+    to finish, and is passed over. Where every leaf was ready before the
+    wait was asked for, the device had run out of queued work:
+    `online.fence.dry`."""
     leaves = [
         leaf for leaf in jax.tree_util.tree_leaves(state)
         if isinstance(leaf, jax.Array) and not leaf.is_deleted()
     ]
-    # tpulint: disable=host-sync-leak -- the fence of the online loop: a wait, nothing is read back; timed as the phase online.fence
-    jax.block_until_ready(leaves)
+    if all(leaf.is_ready() for leaf in leaves):
+        metrics.inc_counter("online.fence.dry")
+    tracing.sync("fence", leaves, copy=False)
 
 
 def iterate_unbounded(
@@ -443,7 +447,6 @@ def iterate_unbounded(
     """
     from ..ckpt import faults
     from ..ckpt import snapshot as _snapshot
-    from ..utils import metrics
 
     if checkpoint_dir is None:
         from .. import config

@@ -113,7 +113,7 @@ def _admit_nbytes(tree, sharding) -> int:
 
 def account_h2d(nbytes: int, arrays: int = 1, seconds: Optional[float] = None) -> None:
     """Fold one host→device transfer into the registry — the upload-side
-    sibling of `obs.tracing.account_readback`. When the caller measured
+    sibling of the readback funnel `obs.tracing.sync`. When the caller measured
     the staging call (`seconds`), the transfer also lands on the
     timeline's `h2d` lane (on an async backend that duration is the
     submit cost, not the wire time)."""

@@ -30,12 +30,11 @@ def packed_device_get(*arrays, sync_kind: str = "readback") -> List[np.ndarray]:
     back); device inputs are flattened into one concatenated transfer and
     restored to their original shapes AND dtypes on the host. A call with
     any device input is one blocking host↔device synchronization point and
-    is accounted as ``iteration.host_sync.<sync_kind>`` — callers on named
-    paths (fit results, checkpoint snapshots) pass their kind."""
+    goes through the timed funnel ``obs.tracing.sync(sync_kind, ...)`` (the
+    wait apart from the copy; ``iteration.host_sync.<sync_kind>``) — callers
+    on named paths (fit results, checkpoint snapshots) pass their kind."""
     import jax
     import jax.numpy as jnp
-
-    import time
 
     from ..obs import tracing
 
@@ -46,12 +45,9 @@ def packed_device_get(*arrays, sync_kind: str = "readback") -> List[np.ndarray]:
             out[i] = np.asarray(a)
     if not device_idx:
         return out
-    tracing.account_host_sync(sync_kind)
     if len(device_idx) == 1:
         i = device_idx[0]
-        t0 = time.perf_counter()
-        out[i] = np.asarray(jax.device_get(arrays[i]))
-        tracing.account_readback(out[i].nbytes, time.perf_counter() - t0)
+        out[i] = tracing.sync(sync_kind, arrays[i])
         return out
     devs = [arrays[i] for i in device_idx]
     shapes = [a.shape for a in devs]
@@ -61,11 +57,7 @@ def packed_device_get(*arrays, sync_kind: str = "readback") -> List[np.ndarray]:
     for d in dtypes[1:]:
         dt = jnp.promote_types(dt, d)
     packed = jnp.concatenate([jnp.ravel(a).astype(dt) for a in devs])
-    t0 = time.perf_counter()
-    host = np.asarray(jax.device_get(packed))
-    tracing.account_readback(
-        host.nbytes, time.perf_counter() - t0, arrays=len(device_idx)
-    )
+    host = tracing.sync(sync_kind, packed, arrays=len(device_idx))
     off = 0
     for i, shape, size, dtype in zip(device_idx, shapes, sizes, dtypes):
         out[i] = host[off : off + size].reshape(shape).astype(dtype)

@@ -164,14 +164,12 @@ def test_fit_phases_counted_with_no_sink(monkeypatch, devices, layouts):
     assert sum(counters[p + ".ns"] for p in FOUR_PHASES) <= counters["fit.total.ns"]
     assert epochs == 7
     assert counters["iteration.host_sync"] == 1
-    # the launch and the readback feed their old timers from the same reads
-    assert snap["timers"]["iteration.dispatch"]["count"] == 1
-    assert snap["timers"]["iteration.dispatch"]["totalMs"] == pytest.approx(
-        counters["fit.launch.ns"] / 1e6
-    )
-    assert snap["timers"]["readback"]["totalMs"] == pytest.approx(
-        counters["fit.readback.ns"] / 1e6
-    )
+    # the launch and the readback are read from the counters: the timers
+    # that held the same clock reads a second time are gone
+    assert "iteration.dispatch" not in snap["timers"] and "readback" not in snap["timers"]
+    assert counters["fit.launch.n"] == 1 and counters["sync.fit.n"] == 1
+    # the readback phase is the parent of the fit's sync: wait and copy lie inside it
+    assert 0 < counters["sync.fit.wait.ns"] + counters["sync.fit.copy.ns"] <= counters["fit.readback.ns"]
     assert "span.fit.total" not in snap["timers"]  # no sink: no span record
 
 
@@ -206,7 +204,8 @@ def test_launch_and_readback_are_on_the_timeline_once():
     assert not {"fit.launch", "fit.readback"} & on_host
     by_lane = lambda lane: [e for e in events if e["lane"] == lane]
     assert len(by_lane(timeline.LANE_DISPATCH)) == 1
-    assert len(by_lane(timeline.LANE_READBACK)) == 1
+    # the sync's two steps, once each
+    assert [e["name"] for e in by_lane(timeline.LANE_READBACK)] == ["sync.fit.wait", "sync.fit.copy"]
     assert metrics.get_counter("fit.launch.n") == 1
 
 
@@ -227,7 +226,11 @@ def test_phase_annotations_lie_in_the_profile(tmp_path):
     (host,) = [p for p in profile["planes"] if p["name"] == "/host:CPU"]
     events = {name: (start, start + dur) for name, start, dur in host["lines"][0]["events"]}
     # (eight devices here: the batched route, so the layout is there too)
-    assert set(events) == {"fml." + p for p in FOUR_PHASES} | {"fml.fit.layout", "fml.fit.total"}
+    assert set(events) == {"fml." + p for p in FOUR_PHASES} | {
+        "fml.fit.layout", "fml.fit.total", "fml.sync.fit.wait", "fml.sync.fit.copy"
+    }
+    readback, wait, copy = events["fml.fit.readback"], events["fml.sync.fit.wait"], events["fml.sync.fit.copy"]
+    assert readback[0] <= wait[0] < wait[1] <= copy[0] < copy[1] <= readback[1]
     total, launch = events["fml.fit.total"], events["fml.fit.launch"]
     assert total[0] <= launch[0] < launch[1] <= total[1]
     stage, layout = events["fml.fit.stage"], events["fml.fit.layout"]
@@ -260,6 +263,288 @@ def test_phase_counts_a_block_that_raises():
             raise ValueError("boom")
     assert metrics.get_counter("bench.raised.n") == 1
     assert metrics.get_counter("bench.raised.ns") == raised.dur_ns >= 1_000_000
+
+
+# ---------------------------------------------------------------------------
+# the funnel of a blocking read: wait apart from copy, for every kind
+# ---------------------------------------------------------------------------
+
+def _sync_fit(monkeypatch, tmp_path):
+    _fit_one_lr()
+
+
+def _sparse_lr_table(rows=512, dim=1 << 16, width=6):
+    import jax
+
+    from flink_ml_tpu import Table
+    from flink_ml_tpu.table import SparseBatch
+
+    rng = np.random.default_rng(6)
+    indices = np.sort(rng.integers(4, dim, (rows, width)).astype(np.int32), axis=1)
+    indices[:, 0], indices[:, 1] = 0, 1 + indices[:, 1] % 3  # one id, a few: a plan to take
+    values = (rng.random(indices.shape) + 0.5).astype(np.float32)
+    label = (rng.random(rows) > 0.5).astype(np.float32)
+    features = SparseBatch(dim, jax.device_put(indices), jax.device_put(values))
+    return Table({"features": features, "label": jax.device_put(label)})
+
+
+def _sync_plan(monkeypatch, tmp_path):
+    import jax
+
+    from flink_ml_tpu.models.classification.logisticregression import LogisticRegression
+    from flink_ml_tpu.parallel import mesh as mesh_lib
+
+    monkeypatch.setattr(mesh_lib, "on_tpu", lambda arr: True)  # the CPU plans nothing
+    mesh = mesh_lib.create_mesh((mesh_lib.DATA_AXIS,), devices=jax.devices()[:1])
+    with mesh_lib.use_mesh(mesh):
+        LogisticRegression().set_max_iter(3).set_global_batch_size(128).fit(_sparse_lr_table())
+
+
+def _sync_look(monkeypatch, tmp_path):
+    import jax
+
+    from flink_ml_tpu import Table
+    from flink_ml_tpu.models.clustering.kmeans import KMeans
+    from flink_ml_tpu.parallel import mesh as mesh_lib
+
+    monkeypatch.setattr(mesh_lib, "on_tpu", lambda arr: True)  # the CPU looks at nothing
+    mesh = mesh_lib.create_mesh((mesh_lib.DATA_AXIS,), devices=jax.devices()[:1])
+    X = np.random.default_rng(4).integers(0, 256, (256, 8)).astype(np.float32)
+    with mesh_lib.use_mesh(mesh):
+        KMeans().set_k(4).set_seed(3).set_max_iter(3).fit(Table({"features": jax.device_put(X)}))
+
+
+def _sync_drain(monkeypatch, tmp_path):
+    from flink_ml_tpu import config
+
+    # a checkpointed fit with the resident program off: launched in chunks, drained a chunk
+    monkeypatch.setattr(config, "iteration_checkpoint_dir", str(tmp_path))
+    with config.whole_fit_mode("off"):
+        _fit_one_lr()
+
+
+def _sync_transform(monkeypatch, tmp_path):
+    from flink_ml_tpu import Table
+    from flink_ml_tpu.models.clustering.kmeans import KMeans
+
+    X = np.random.default_rng(4).random((64, 4)).astype(np.float32)
+    model = KMeans().set_k(2).set_seed(3).set_max_iter(2).fit(Table({"features": X}))
+    tracing.drain_ring()
+    metrics.reset()
+    model.transform(Table({"features": X}))  # a host table: the prediction is read back
+
+
+def _sync_fence(monkeypatch, tmp_path):
+    from flink_ml_tpu import Table
+    from flink_ml_tpu.linalg import DenseVector
+    from flink_ml_tpu.models.classification.onlinelogisticregression import OnlineLogisticRegression
+    from flink_ml_tpu.table import StreamTable
+
+    rng = np.random.default_rng(2)
+    batches = [
+        Table({"features": rng.random((32, 4)), "label": (rng.random(32) > 0.5).astype(np.float64)})
+        for _ in range(4)
+    ]
+    stage = OnlineLogisticRegression().set_global_batch_size(32)
+    stage.set_initial_model_data(Table({"coefficient": [DenseVector(np.zeros(4))]}))
+    stage.fit(StreamTable.from_batches(batches)).process_updates()
+
+
+# kind -> (a toy route that takes it, the span around its steps, inside a fit)
+SYNCS = {
+    "fit": (_sync_fit, "fit.readback", True),
+    "plan": (_sync_plan, "fit.stage", True),
+    "look": (_sync_look, "fit.stage", True),
+    "drain": (_sync_drain, None, True),
+    "transform": (_sync_transform, "stage.transform", False),
+    "fence": (_sync_fence, "online.fence", False),
+}
+
+
+@pytest.mark.parametrize("kind", SYNCS)
+def test_sync_of_every_kind_is_counted_and_nested(kind, monkeypatch, tmp_path):
+    """Every blocking read goes through the one funnel: its wait and its copy
+    are counted apart, summed into the open fit, and are span records under
+    the phase that holds the call; a fence is a wait alone and no host sync."""
+    route, parent, in_fit = SYNCS[kind]
+    tracing.configure(ring_size=4096)
+    route(monkeypatch, tmp_path)
+    counters = metrics.snapshot()["counters"]
+    records = tracing.drain_ring()
+    by_id = {r["spanId"]: r for r in records}
+    n = counters["sync." + kind + ".n"]
+    assert n >= 1 and counters["sync." + kind + ".wait.ns"] > 0
+    waits = [r for r in records if r["name"] == "sync." + kind + ".wait"]
+    copies = [r for r in records if r["name"] == "sync." + kind + ".copy"]
+    assert len(waits) == n
+    if kind == "fence":
+        assert not copies and "sync.fence.copy.ns" not in counters and "sync.fence.bytes" not in counters
+        assert not any(name.startswith(("iteration.host_sync", "readback.")) for name in counters), counters
+        assert counters["online.fence.n"] == n  # every fence is one wait
+        assert counters["sync.fence.wait.ns"] <= counters["online.fence.ns"]
+        assert counters.get("online.fence.dry", 0) <= n  # a fence that found its state whole, by the CPU's timing
+    else:
+        assert len(copies) == n and counters["sync." + kind + ".copy.ns"] > 0
+        assert counters["sync." + kind + ".bytes"] == sum(r["attrs"]["bytes"] for r in copies) > 0
+        assert counters["iteration.host_sync." + kind] == n
+        kinds = {name.split(".")[1] for name in counters if name.startswith("sync.")}
+        assert counters["iteration.host_sync"] == counters["readback.count"] == sum(
+            counters["sync." + k + ".n"] for k in kinds
+        )
+        assert counters["readback.bytes"] == sum(counters["sync." + k + ".bytes"] for k in kinds)
+    for step in waits + copies:
+        assert step["attrs"]["category"] == "readback"
+        if parent is not None:
+            assert by_id[step["parentId"]]["name"] == parent, step
+        assert ("fit" in step["attrs"]) == in_fit
+    for wait, copy in zip(waits, copies):
+        assert wait["parentId"] == copy["parentId"]
+        assert wait["startUs"] + wait["durUs"] <= copy["startUs"] + 1e-3
+    if in_fit:
+        # one outermost fit: every step carries its ordinal, and the fit's
+        # sums are the kinds' sums, whatever the kinds
+        assert counters["fit.outer.n"] == 1
+        assert len({step["attrs"]["fit"] for step in waits + copies}) == 1
+        for step in ("wait", "copy"):
+            assert counters["fit.sync." + step + ".ns"] == sum(
+                v for name, v in counters.items() if name.startswith("sync.") and name.endswith(step + ".ns")
+            )
+        assert counters["fit.sync.wait.ns"] + counters["fit.sync.copy.ns"] < counters["fit.outer.ns"]
+        assert counters["fit.outer.ns"] == counters["fit.total.ns"]
+    else:
+        # (the stream's `fit` returns before its loop folds a batch)
+        assert not any(name.startswith("fit.sync.") for name in counters)
+
+
+def test_a_fence_of_a_ready_state_is_dry():
+    """The online loop's fence says when the state it waits for was whole
+    before it asked (the device had run out of queued work), and is a sync
+    that reads nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.parallel import iteration
+
+    iteration._wait_for({"w": jax.block_until_ready(jnp.zeros(4)), "version": 3})
+    counters = metrics.snapshot()["counters"]
+    assert counters["online.fence.dry"] == 1 and counters["sync.fence.n"] == 1
+    assert set(counters) == {"online.fence.dry", "sync.fence.n", "sync.fence.wait.ns"}
+
+
+def test_outermost_fit_is_counted_once_a_pipeline_fit():
+    """`fit.total` counts every estimator of a pipeline and the pipeline;
+    `fit.outer` the pipeline alone, from the clock reads of its `fit.total`,
+    and its syncs are those of all its stages."""
+    from flink_ml_tpu import Pipeline, Table
+    from flink_ml_tpu.models.classification.logisticregression import LogisticRegression
+    from flink_ml_tpu.models.feature.minmaxscaler import MinMaxScaler
+    from flink_ml_tpu.models.feature.standardscaler import StandardScaler
+
+    rng = np.random.default_rng(5)
+    X = rng.standard_normal((256, 4)).astype(np.float32)
+    table = Table({"features": X, "label": (X[:, 0] > 0).astype(np.float32)})
+    tracing.configure(ring_size=4096)
+    Pipeline(
+        [
+            StandardScaler().set_input_col("features").set_output_col("scaled"),
+            MinMaxScaler().set_input_col("scaled").set_output_col("unit"),
+            LogisticRegression().set_features_col("unit").set_max_iter(3).set_global_batch_size(64),
+        ]
+    ).fit(table)
+    counters = metrics.snapshot()["counters"]
+    assert counters["fit.total.n"] == 4 and counters["fit.outer.n"] == 1
+    totals = sorted(r["durUs"] for r in tracing.drain_ring() if r["name"] == "fit.total")
+    assert counters["fit.outer.ns"] == pytest.approx(totals[-1] * 1e3)  # the longest: the pipeline's
+    assert counters["fit.outer.ns"] < counters["fit.total.ns"]
+    assert counters["fit.sync.wait.ns"] == sum(
+        v for name, v in counters.items() if name.startswith("sync.") and name.endswith(".wait.ns")
+    )
+
+
+def test_a_fit_that_raises_closes_its_depth():
+    from flink_ml_tpu import Table
+    from flink_ml_tpu.models.clustering.kmeans import KMeans
+
+    with pytest.raises(Exception):
+        KMeans().set_k(8).fit(Table({"features": np.ones((2, 2), np.float32)}))  # fewer rows than k
+    assert tracing._fits.depth == 0
+    assert metrics.get_counter("fit.outer.n") == metrics.get_counter("fit.total.n") == 1
+    _fit_one_lr()
+    assert metrics.get_counter("fit.outer.n") == 2  # the next fit is an outermost one again
+
+
+def test_sync_overhead_under_twice_the_bare_read():
+    """A sync of a ready array with nothing listening (no sink, no
+    profile) against the bare read it makes: two steps of a phase's cost
+    each and ten counter adds over `copy_to_host_async`,
+    `block_until_ready` and `device_get` of four bytes."""
+    import jax
+    import jax.numpy as jnp
+
+    assert not tracing.enabled()
+    ready = jnp.ones((), jnp.float32).block_until_ready()
+    tracing.sync("bench", ready)
+
+    def bare():
+        ready.copy_to_host_async()
+        jax.block_until_ready(ready)
+        return np.asarray(jax.device_get(ready))
+
+    n = 5_000
+    best = {"sync": float("inf"), "bare": float("inf")}
+    for _ in range(5):  # best-of-5 shields the bound from CI scheduling noise
+        for name, read in (("sync", lambda: tracing.sync("bench", ready)), ("bare", bare)):
+            t0 = time.perf_counter()
+            for _ in range(n):
+                read()
+            best[name] = min(best[name], (time.perf_counter() - t0) / n)
+    # (~18 µs against ~12 on a quiet CPU; held as a ratio, which a loaded machine keeps)
+    over = best["sync"] - best["bare"]
+    assert over < 2 * best["bare"], f"an unheard sync costs {over * 1e9:.0f}ns over the bare read's {best['bare'] * 1e9:.0f}ns"
+    assert metrics.get_counter("sync.bench.n") == 5 * n + 1
+    assert metrics.get_counter("sync.bench.bytes") == 4 * (5 * n + 1)
+
+
+# ---------------------------------------------------------------------------
+# host pauses: the cycle collector, compilation
+# ---------------------------------------------------------------------------
+
+def test_collections_are_counted_and_full_ones_named(tmp_path):
+    """Every collection adds to `host.gc.ns` / `.n`; a full one is also
+    `host.gc.full.n`, `fml.host.gc` on a profile's clock and an event of the
+    timeline's host lane. A young collection is neither."""
+    import gc
+
+    import jax
+
+    from flink_ml_tpu.obs import timeline
+
+    gc.collect(0)
+    counters = metrics.snapshot()["counters"]
+    assert counters["host.gc.n"] == 1 and counters["host.gc.ns"] > 0 and "host.gc.full.n" not in counters
+    with tracing.phase("bench.phase"):  # binds the annotation class
+        pass
+    timeline.configure(ring_size=256)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with tracing.phase("bench.around"):
+            gc.collect(1)
+            gc.collect()
+        events, _ = timeline.snapshot_events()
+    finally:
+        jax.profiler.stop_trace()
+        timeline.configure()
+    counters = metrics.snapshot()["counters"]
+    assert counters["host.gc.n"] >= 3 and counters["host.gc.full.n"] == 1
+    assert [e["lane"].startswith("host:") for e in events if e["name"] == "host.gc"] == [True]
+    profile = report.load_device_profile(str(tmp_path))
+    (host,) = [p for p in profile["planes"] if p["name"] == "/host:CPU"]
+    events = {name: (start, start + dur) for name, start, dur in host["lines"][0]["events"]}
+    assert set(events) == {"fml.bench.around", "fml.host.gc"}
+    around, pause = events["fml.bench.around"], events["fml.host.gc"]
+    assert around[0] <= pause[0] < pause[1] <= around[1]
 
 
 def test_ring_buffer_bounded():
@@ -353,9 +638,11 @@ def test_readback_accounting():
     snap = metrics.snapshot()
     assert snap["counters"]["readback.count"] == 1
     assert snap["counters"]["readback.bytes"] == (8 + 4) * 4
-    spans = [r for r in tracing.drain_ring() if r["name"] == "readback"]
-    assert spans and spans[0]["attrs"]["category"] == "readback"
-    assert spans[0]["attrs"]["arrays"] == 2
+    spans = {r["name"]: r for r in tracing.drain_ring()}
+    steps = [spans["sync.readback.wait"], spans["sync.readback.copy"]]
+    assert all(r["attrs"]["category"] == "readback" for r in steps)
+    assert spans["sync.readback.copy"]["attrs"]["arrays"] == 2
+    assert spans["sync.readback.copy"]["attrs"]["bytes"] == (8 + 4) * 4
 
 
 def test_jit_compile_counters():
@@ -372,6 +659,8 @@ def test_jit_compile_counters():
     assert snap["counters"]["jit.kernels"] == kernels_before + 1
     assert snap["counters"].get("jit.compiles", 0) >= before + 1
     assert "jit.compile" in snap["timers"]
+    # a pause by compilation has a length as well as a count, from the same event
+    assert snap["counters"]["jit.compile.ns"] == pytest.approx(snap["timers"]["jit.compile"]["totalMs"] * 1e6, rel=1e-6)
 
 
 # ---------------------------------------------------------------------------

@@ -135,17 +135,22 @@ def toy_fit(config_name: str, shards: int) -> dict:
     return {"lowered": lowered, "counters": counters}
 
 
+def perf_module(kind: str, name: str):
+    """The benchmark's file perf/<kind>/<name>.py as a module, as `perf/run.py` loads it."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(f"perf_{kind}_{name}", ROOT / "perf" / kind / (name + ".py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
 def pipeline_and_raw_table(config: dict, estimator, by_rows, rng, label):
     """The configuration's feature stages, built as the benchmark's generator
     builds them, in front of `estimator`, and a toy raw table of their columns."""
-    import importlib.util
-
     from flink_ml_tpu import Pipeline
 
-    path = ROOT / "perf" / "generators" / "pipeline_fit_loop.py"
-    spec = importlib.util.spec_from_file_location("perf_generators_pipeline_fit_loop", path)
-    generator = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(generator)
+    generator = perf_module("generators", "pipeline_fit_loop")
     stages, columns = [generator.make_stage(spec) for spec in config["pipeline"]], {"label": label}
     scaler, encoder = stages[0], stages[1]
     columns[scaler.get_input_col()] = by_rows(rng.random((ROWS, config["data"]["integer_fields"])).astype(np.float32))
@@ -231,9 +236,42 @@ def lloyd_fit_of_general_floats() -> dict:
 OTHER_SIDE = {"assembler.dense_out": dense_assembly, "lloyd.product.full": lloyd_fit_of_general_floats}
 
 
+def collection_inside_a_fit() -> dict:
+    """The counters a pass of the cycle collector moves while a fit phase is
+    open: a toy fit may or may not hold one."""
+    import gc
+
+    from flink_ml_tpu.obs import tracing
+
+    before = metrics.snapshot()
+    with tracing.phase("fit.total"):
+        gc.collect()
+    return metrics.snapshot_delta(before, metrics.snapshot())["counters"]
+
+
+def fence_of_a_ready_state() -> dict:
+    """The counters the online loop's fence moves where the state it waits
+    for was whole before it asked: the device had run out of queued work. A
+    toy stream's fence finds its state ready or not by the CPU's own timing."""
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.parallel import iteration
+
+    before = metrics.snapshot()
+    iteration._wait_for({"w": jax.block_until_ready(jnp.zeros(4))})
+    return metrics.snapshot_delta(before, metrics.snapshot())["counters"]
+
+
+# counters that no toy fit is sure to move, by what is sure to move them
+ON_DEMAND = {"host.gc.ns": collection_inside_a_fit, "online.fence.dry": fence_of_a_ready_state}
+
+
 @pytest.mark.parametrize("metric,counter", counters_read())
 def test_counter_a_metric_reads_is_counted_by_a_fit(metric, counter):
     cells = METRICS.get(metric, {}).get("workloads") or sorted(CELLS)
+    if counter in ON_DEMAND:
+        assert ON_DEMAND[counter]()[counter] > 0, f"perf/metrics/{metric}.py reads the counter {counter}: {FOLLOW}"
+        return
     if counter in OTHER_SIDE:
         assert not any(toy_fit_of_cell(cell)["counters"].get(counter, 0) for cell in cells)
         assert OTHER_SIDE[counter]()[counter] > 0, f"perf/metrics/{metric}.py reads the counter {counter}: {FOLLOW}"
@@ -320,6 +358,60 @@ def test_a_toy_stream_syncs_with_the_host_for_no_batch():
         counters = toy_fit_of_cell(cell)["counters"]
         assert counters["online.versions"] == 3
         assert not any(name.startswith(("iteration.host_sync", "readback.")) for name in counters), counters
+        # its one wait is the fence's, through the funnel: a wait and no copy
+        # (three batches, two in flight: the third fences the first)
+        assert counters["sync.fence.n"] == counters["online.fence.n"] == 1
+        assert 0 < counters["sync.fence.wait.ns"] <= counters["online.fence.ns"]
+        assert not any(name.startswith("sync.") and not name.startswith("sync.fence.") for name in counters)
+        assert counters.get("online.fence.dry", 0) <= 1
+
+
+FIT_CELLS = METRICS["fit_host_self_ms"]["workloads"]
+
+
+@pytest.mark.parametrize("cell", FIT_CELLS)
+def test_a_fits_wall_is_its_own_time_its_waits_and_its_copies(cell):
+    """The identity of `fit_host_self_ms` + `fit_wait_ms` + `fit_d2h_ms`, in a
+    toy fit of every fit cell: one outermost fit (a pipeline's holds its
+    stages'), every blocking read of it through the funnel and inside it, and
+    the three parts the fit's wall to the nanosecond."""
+    assert METRICS["fit_wait_ms"]["workloads"] == METRICS["fit_d2h_ms"]["workloads"] == FIT_CELLS
+    assert sorted(FIT_CELLS) == sorted(set(CELLS) - set(stream_cells()))
+    counters = toy_fit_of_cell(cell)["counters"]
+    assert counters["fit.outer.n"] == 1
+    nested = 1 + estimators_of(CONFIGS[CELLS[cell]["config"]]) if cell in pipeline_cells() else 0
+    assert counters["fit.total.n"] == 1 + nested
+    if not nested:
+        assert counters["fit.outer.ns"] == counters["fit.total.ns"]
+    kinds = sorted({name.split(".")[1] for name in counters if name.startswith("sync.")})
+    assert "fit" in kinds and "fence" not in kinds
+    wait = sum(counters[f"sync.{kind}.wait.ns"] for kind in kinds)
+    copy = sum(counters[f"sync.{kind}.copy.ns"] for kind in kinds)
+    assert (counters["fit.sync.wait.ns"], counters["fit.sync.copy.ns"]) == (wait, copy)
+    assert counters["fit.sync.bytes"] == counters["readback.bytes"] == sum(counters[f"sync.{kind}.bytes"] for kind in kinds)
+    assert counters["iteration.host_sync"] == counters["readback.count"] == sum(counters[f"sync.{kind}.n"] for kind in kinds)
+    own = counters["fit.outer.ns"] - wait - copy
+    assert own > 0
+    run = {"counters": counters, "window": {"begin": 0.0, "end": 1.0}}
+    parts = [perf_module("metrics", name).read(run) for name in ("fit_host_self_ms", "fit_wait_ms", "fit_d2h_ms")]
+    assert parts == [own / 1e6, wait / 1e6, copy / 1e6]
+    assert round(sum(parts) * 1e6) == counters["fit.outer.ns"]
+    assert perf_module("metrics", "host_gc_ms_per_s").read(run) == counters.get("host.gc.ns", 0) / 1e6
+
+
+def test_a_toy_pipeline_fit_is_one_outermost_fit_of_four():
+    """`fit.total` counts the pipeline and each of its estimators, `fit.outer`
+    the pipeline alone, so the readers that divide by it report in the
+    pipeline cell; its syncs are its stages' and its trainer's."""
+    for cell in pipeline_cells():
+        counters = toy_fit_of_cell(cell)["counters"]
+        assert counters["fit.outer.n"] == 1 and counters["fit.total.n"] == 4
+        assert counters["fit.outer.ns"] < counters["fit.total.ns"]
+        assert counters["fit.outer.ns"] >= counters["pipeline.fit.ns"]
+        # the scaler's moments, the encoder's sizes and the packed result, the guards, the plan's counts
+        kinds = {"readback": 1, "fit": 2, "transform": 1, "plan": 1}
+        assert {name.split(".")[1] for name in counters if name.startswith("sync.")} == set(kinds)
+        assert {kind: counters[f"sync.{kind}.n"] for kind in kinds} == kinds
 
 
 @pytest.mark.parametrize("cell", METRICS["fit_prelaunch_ms"]["workloads"])

@@ -289,7 +289,7 @@ class TestWholeFit2D:
         before = metrics.snapshot()
         coeff, _, epochs = _fit(mesh_2d, indices, values, y, 24, max_iter=5)
         delta = metrics.snapshot_delta(before, metrics.snapshot())
-        assert delta["timers"]["iteration.dispatch"]["count"] == 1
+        assert delta["counters"]["fit.launch.n"] == 1
         assert epochs == 5
         assert coeff.shape == (24,)
 
