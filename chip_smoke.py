@@ -30,7 +30,9 @@ at a time), with every phase fatal:
              PipelineModel.transform; then the same from a populated AOT
              program bank with zero traces
   one_device (several devices only) the loops phase's table fitted on a
-             one-device mesh against its default-mesh fit
+             one-device mesh against its default-mesh fit; then one pass
+             over the same rows as a device table sharded by rows, which
+             walks the shares and lays nothing out, against the same
 
 On several devices every phase runs on the default mesh, the sparse
 phase adds one fit on mesh.create_mesh_2d(2), and the train phase checks
@@ -749,7 +751,26 @@ def phase_one_device(table, default_mesh_coefficient, batch=100_000):
         one = _logreg(batch).fit(table).coefficient
     rel = rel_diff(one, default_mesh_coefficient)
     check(rel <= ONE_DEVICE_RTOL, f"one-device vs default-mesh coefficients: rel {rel:.2e}")
-    return {"oneDeviceVsDefaultMeshRel": rel}
+    # a fit that reads no batch twice, over a device table sharded by rows in
+    # whole batches (two a share), trains every batch where it lies
+    from flink_ml_tpu.table import Table
+
+    mesh = mesh_lib.default_mesh()
+    shards = mesh_lib.num_data_shards(mesh)
+    X, y = table.column("features"), table.column("label")
+    walk_batch = X.shape[0] // (2 * shards)
+    rows = 2 * shards * walk_batch
+    by_rows = lambda a: jax.device_put(a[:rows], mesh_lib.data_sharding(mesh, a.ndim))  # noqa: E731
+    one_pass = _logreg(walk_batch, max_iter=2 * shards)
+    before = _counters()
+    walked = one_pass.fit(Table({"features": by_rows(X), "label": by_rows(y)})).coefficient
+    check(_counter_delta(before, "layout.walk") == 1, "a one-pass fit of a row-sharded device table did not walk")
+    check(_counter_delta(before, "layout.walk.legs") == shards, "the walk did not visit every share")
+    with mesh_lib.use_mesh(mesh1):
+        one = one_pass.fit(Table({"features": X[:rows], "label": y[:rows]})).coefficient
+    walk_rel = rel_diff(walked, one)
+    check(walk_rel <= ONE_DEVICE_RTOL, f"walked vs one-device coefficients: rel {walk_rel:.2e}")
+    return {"oneDeviceVsDefaultMeshRel": rel, "walkedVsOneDeviceRel": walk_rel}
 
 
 # ---------------------------------------------------------------------------

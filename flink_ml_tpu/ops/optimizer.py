@@ -221,6 +221,50 @@ def _can_one_pass(X, loss_func, mesh) -> bool:
     )
 
 
+def _can_walk(X, y, weights, batch, max_iter, dtype, mesh) -> bool:
+    """Whether a fit on several data shards trains every batch on the shard
+    that holds it (`SGD._stage_walk`) and lays nothing out. It does where no
+    batch is read twice, `max_iter` at most the table's batches (one pass or
+    a part of one: a layout built in the fit would be used once), over a
+    dense float table sharded by rows over a mesh of the data axis alone,
+    every share in this process and whole batches (so every global batch,
+    contiguous rows, lies whole on one shard), with y, and a weight column
+    where there is one, device columns sharded the same way, all of the
+    engine's dtype (a cast is a copy of the table). All read off shapes,
+    dtypes, the sharding, the mesh and `max_iter`, nothing a user sets; and
+    the one place that decides, counted as `layout.walk` a walked fit. What it
+    turns away keeps the batched route as it was: a fit of several passes, a
+    straddling batch, a sparse or a host table pay the layout once and train
+    data-parallel (`_can_exchange` then says which form lays the table out).
+    The routes decided before it in `_stage_async` (the overlap schedule, one
+    shard, feature sharding, a checkpoint directory) and the fleet's
+    `replicate_data` never ask."""
+    shards = mesh_lib.num_data_shards(mesh)
+    columns = [y] if weights is None else [y, weights]
+    if not (
+        shards > 1
+        and mesh.devices.size == shards
+        and isinstance(X, jax.Array)
+        and X.ndim == 2
+        and all(isinstance(c, jax.Array) and c.ndim == 1 for c in columns)
+    ):
+        return False
+    n = X.shape[0]
+    return (
+        jnp.issubdtype(X.dtype, jnp.floating)
+        and n > 0
+        and n % (shards * batch) == 0
+        and max_iter <= n // batch
+        and X.is_fully_addressable
+        and X.sharding.is_equivalent_to(mesh_lib.data_sharding(mesh, 2), 2)
+        and all(
+            c.shape[0] == n and c.sharding.is_equivalent_to(mesh_lib.data_sharding(mesh, 1), 1)
+            for c in columns
+        )
+        and all(arr.dtype == dtype for arr in [X] + columns)
+    )
+
+
 def _exchange_batches_impl(arr, batch, mesh):
     """The batch layout of a row-sharded table [rows, width] as one explicit
     exchange: every shard cuts each of its own batches (it holds whole
@@ -397,13 +441,49 @@ def _pack_train_result(coeff, criteria, epochs, flag=None, pack_sharding=None):
     return jnp.concatenate(parts)
 
 
+def _pack_leg_carry(state, ok):
+    """A leg's end state (coeff, grad, wsum, epoch, criteria) and the label
+    flag so far (1 where none is kept) as ONE vector of 2 * dim + 4 numbers,
+    in `_pack_train_result`'s type: the next leg's device is handed one
+    array."""
+    coeff, grad, wsum, epoch, criteria = state
+    dt = jnp.promote_types(coeff.dtype, jnp.float32)
+    tail = jnp.stack([wsum.astype(dt), epoch.astype(dt), criteria.astype(dt), ok.astype(dt)])
+    return jnp.concatenate([coeff.astype(dt), grad.astype(dt), tail])
+
+
+def _first_leg_carry(init_coeff, dtype):
+    """`_pack_leg_carry`'s vector of a fit's start, as `_sgd_train_flat`
+    starts without one: no gradient, no weight, epoch 0, a loss of infinity,
+    every label fine so far. Made on the host for a host coefficient (one
+    upload with the first leg's launch), where it lies for a device one."""
+    xp = jnp if isinstance(init_coeff, jax.Array) else np
+    coeff = xp.asarray(init_coeff).astype(dtype).astype(np.promote_types(dtype, np.float32))
+    return xp.concatenate([coeff, xp.zeros_like(coeff), xp.asarray([0.0, 0.0, np.inf, 1.0], coeff.dtype)])
+
+
+def _unpack_leg_carry(carry, dtype):
+    """(state, flag so far) of `_pack_leg_carry`'s vector, the state in the
+    loop's own types (an epoch count is exact in float32 below 2^24, as
+    `_hyper`'s max_iter is)."""
+    d = (carry.shape[0] - 4) // 2
+    state = (
+        carry[:d].astype(dtype),
+        carry[d : 2 * d].astype(dtype),
+        carry[2 * d].astype(dtype),
+        carry[2 * d + 1].astype(jnp.int32),
+        carry[2 * d + 2].astype(jnp.float32),
+    )
+    return state, carry[2 * d + 3].astype(jnp.float32)
+
+
 @partial(
     lazy_jit,
     static_argnames=("loss_func", "batch", "has_weights", "check_labels", "one_pass", "interpret", "plan"),
 )
 def _sgd_train_flat(
     X, y, w, init_coeff, loss_func, batch, has_weights, n, hyper, check_labels,
-    one_pass=False, interpret=False, plan=None, dictionaries=None,
+    one_pass=False, interpret=False, plan=None, dictionaries=None, carry=None,
 ):
     """Single-data-shard variant of `_sgd_train` that slices each epoch's
     batch straight out of the FLAT row-major arrays with a dynamic slice.
@@ -424,12 +504,24 @@ def _sgd_train_flat(
 
     With `plan` (`sparse_epoch.plan_fit`'s widths, `_stage_flat` asks) a
     sparse epoch's loss is `sparse_epoch.planned_loss` over the sliced batch
-    and the plan's `dictionaries`, in `loss_func`'s place."""
+    and the plan's `dictionaries`, in `loss_func`'s place.
+
+    With `carry` the program is one leg of a walked fit (`SGD._stage_walk`):
+    the table is one shard's share of whole batches, the loop takes up where
+    the leg before left off (`carry` is its end state, `_pack_leg_carry`'s
+    vector; the first leg's is made from the start coefficient, and
+    `init_coeff` is None), `hyper`'s max_iter is the epoch this leg ends at,
+    and the end state is handed on in place of the result (`_finish_walk`
+    makes that of the last leg's). One program serves every leg: a kernel's
+    lowering costs a process 0.3 s a program. No first row is asked for: a fit
+    that reads no batch twice has batch k = epoch, and the shares hold equally
+    many, so `epoch mod the share's batches` is the batch's place in the
+    share. Without `carry` all of it folds away and the program is the
+    one-shard fit's."""
     sliced_loss = loss_func
     if plan is not None:
         sliced_loss = partial(sparse_epoch.planned_loss(loss_func, plan), dictionaries=dictionaries)
     num_batches = y.shape[0] // batch
-    d = init_coeff.shape[0]
     dtype = _feature_dtype(X)
     max_iter, tol, lr, reg, elastic_net = _unpack_hyper(hyper, dtype)
     if one_pass:
@@ -462,17 +554,34 @@ def _sgd_train_flat(
         )
         return carry + (criteria,)
 
-    init_state = (
-        jnp.asarray(init_coeff, dtype),
-        jnp.zeros((d,), dtype),
-        jnp.asarray(0.0, dtype),
-        jnp.asarray(0, jnp.int32),
-        jnp.asarray(jnp.inf, jnp.float32),
-    )
-    coeff, grad, wsum, epochs, criteria = lax.while_loop(cond, body, init_state)
-    coeff = _update_model(coeff, grad, wsum, lr, reg, elastic_net)
+    if carry is None:
+        init_state = (
+            jnp.asarray(init_coeff, dtype),
+            jnp.zeros((init_coeff.shape[0],), dtype),
+            jnp.asarray(0.0, dtype),
+            jnp.asarray(0, jnp.int32),
+            jnp.asarray(jnp.inf, jnp.float32),
+        )
+    else:
+        init_state, ok_so_far = _unpack_leg_carry(carry, dtype)
+    coeff, grad, wsum, epochs, criteria = state = lax.while_loop(cond, body, init_state)
+    if carry is None:
+        coeff = _update_model(coeff, grad, wsum, lr, reg, elastic_net)
     flag = _binomial_labels_ok(y) if check_labels else None
+    if carry is not None:
+        return _pack_leg_carry(state, ok_so_far if flag is None else jnp.minimum(flag, ok_so_far))
     return _pack_train_result(coeff, criteria, epochs, flag)
+
+
+@partial(lazy_jit, static_argnames=("dtype", "has_flag"))
+def _finish_walk(carry, hyper, dtype, has_flag):
+    """A walked fit's result of its last leg's end state, on that leg's
+    device: the one extra update after termination and the pack, as the
+    one-shard program ends."""
+    (coeff, grad, wsum, epochs, criteria), flag = _unpack_leg_carry(carry, dtype)
+    _, _, lr, reg, elastic_net = _unpack_hyper(hyper, dtype)
+    coeff = _update_model(coeff, grad, wsum, lr, reg, elastic_net)
+    return _pack_train_result(coeff, criteria, epochs, flag if has_flag else None)
 
 
 @partial(lazy_jit, static_argnames=("loss_func", "check_labels", "pack_sharding"))
@@ -1108,6 +1217,15 @@ class SGD:
                 mesh, init_coeff, X, y, weights, loss_func, validate_labels
             )
             return lambda: ("packed", launch(), d, validate_labels)
+        if (
+            not self.shard_features
+            and self.checkpoint_dir is None
+            and _can_walk(X, y, weights, int(self.global_batch_size), self.max_iter, self.dtype, mesh)
+        ):
+            launch = self._stage_walk(
+                mesh, init_coeff, X, y, weights, loss_func, validate_labels
+            )
+            return lambda: ("packed", launch(), d, validate_labels)
         if self.shard_features:
             # zero-pad the feature dim to divide over the model axis; padded
             # coefficients start 0, get zero gradients, and stay 0
@@ -1638,6 +1756,88 @@ class SGD:
             dictionaries,
             start=0, end=self.max_iter,
         )
+
+    def _stage_walk(self, mesh, init_coeff, X, y, weights, loss_func, validate_labels):
+        """The walk (`_can_walk` admits the fit): the epochs fall into legs,
+        runs whose batches lie on one shard, and leg by leg the one-shard
+        flat program runs over that shard's share where it lies
+        (`addressable_shards[i].data`: no copy, no layout, no collective)
+        from the end state of the leg before, which reaches the share's
+        device as one vector of 2 * dim + 4 numbers by a device-to-device put.
+        `_finish_walk` makes the fit's result of the last leg's, on its device.
+        Every leg is enqueued before anything is read, inside one
+        `fit.launch`: one blocking read a fit, as on every whole-fit route. A
+        fit that `tol` stops early runs its later legs for no epoch. Where
+        the labels are checked in the program every shard has a leg, those
+        the epochs do not reach for no epoch: no label goes unseen, on this
+        route as on the others. One chip works at a time; SGD's epochs are
+        sequential whatever the layout, and what the batched route buys with
+        every chip on every batch costs a layout of the whole table first.
+        Each share is a one-shard table to `_can_one_pass` (the shares are
+        one shape on one kind of device: the first is asked), so on the TPU
+        a leg's epochs are the one-read kernel's. Returns the launch, a call
+        without arguments that gives the packed result device vector."""
+        B = int(self.global_batch_size)
+        has_weights = weights is not None
+        columns = [X, y, weights] if has_weights else [X, y]
+
+        def first_row(shard):
+            return shard.index[0].start or 0
+
+        # a share: its piece of every column, in row order
+        shares = [
+            [shard.data for shard in share]
+            for share in zip(*(sorted(col.addressable_shards, key=first_row) for col in columns))
+        ]
+        share_rows = X.shape[0] // len(shares)
+        reached = max(1, -(-self.max_iter * B // share_rows))  # the shares the epochs reach
+        legs = shares if validate_labels else shares[:reached]
+        device = legs[0][0].device
+        one_pass = _can_one_pass(legs[0][0], loss_func, mesh_lib.create_mesh(devices=[device]))
+        interpret = one_pass and device.platform != "tpu"
+        # the table is the caller's and stays where it is: ledgered as the
+        # fit's training-data residency, like the flat route's
+        from ..obs import memledger
+
+        memledger.track(columns, "streamSegments")
+        from ..parallel import dispatch
+
+        metrics.inc_counter("layout.walk")
+        metrics.inc_counter("layout.walk.legs", len(legs))
+        metrics.inc_counter("dense_epoch.one_pass" if one_pass else "dense_epoch.reduce")
+        dtype = np.dtype(self.dtype)
+        no_weights = [] if has_weights else [np.zeros((0,), dtype)]
+        first = _first_leg_carry(init_coeff, dtype)
+        hyper = self._hyper()
+
+        def walk():
+            carry = first
+            for i, share in enumerate(legs):
+                leg_hyper = hyper.copy()
+                leg_hyper[0] = min(self.max_iter, (i + 1) * (share_rows // B))
+                # chip to chip: the stager places it and counts no upload. The
+                # first leg's goes up with its launch: placed first like the
+                # others it saves jax a second lowering (0.17 s of set-up) and
+                # costs every fit 0.3 ms before its first leg starts
+                if i:
+                    carry = h2d.stage_to_device(carry, share[0].device)
+                carry = _sgd_train_flat(
+                    *share,
+                    *no_weights,
+                    None,
+                    loss_func,
+                    B,
+                    has_weights,
+                    np.int32(share_rows),
+                    leg_hyper,
+                    validate_labels,
+                    one_pass,
+                    interpret,
+                    carry=carry,
+                )
+            return _finish_walk(carry, hyper, dtype, validate_labels)
+
+        return partial(dispatch.timed_dispatch, walk, start=0, end=self.max_iter)
 
     def _optimize_with_checkpoints(self, X_b, y_b, w_b, init_coeff, loss_func, mesh):
         """Checkpointed training as a pipeline of epoch CHUNKS: K epochs

@@ -69,6 +69,7 @@ def test_loops_sparse_and_one_device_phases_toy():
     assert loops["streamHostSyncs"] > 1 and loops["checkpointSnapshots"] >= 1
     one = chip_smoke.phase_one_device(table, whole, batch=500)
     assert one["oneDeviceVsDefaultMeshRel"] <= chip_smoke.ONE_DEVICE_RTOL
+    assert one["walkedVsOneDeviceRel"] == 0.0  # the CPU sums a share's batch as the one device does
     sparse = chip_smoke.phase_sparse(rows=2048, dim=5000, nnz=6, batch=256)
     assert sparse["mesh2dVs1dRel"] <= chip_smoke.CROSS_PATH_RTOL
 

@@ -352,7 +352,9 @@ def sparse_table(mesh, rows, width=WIDE_DIM):
 
 
 def coefficients_by_either_form(table, batch, max_iter, monkeypatch):
-    """LogisticRegression fitted with the exchange taken, then with it turned away."""
+    """LogisticRegression fitted with the exchange taken, then with it turned
+    away. `max_iter` is more than a pass: a dense fit of at most one walks
+    the table's shares and lays nothing out (tests/test_layout_walk.py)."""
 
     def fit():
         before = metrics.snapshot()
@@ -373,7 +375,8 @@ def coefficients_by_either_form(table, batch, max_iter, monkeypatch):
 def test_four_shard_fit_is_bit_identical_by_either_form(make_table, rows_minor, monkeypatch):
     mesh = data_mesh(4)
     with mesh_lib.use_mesh(mesh):
-        exchanged, general = coefficients_by_either_form(make_table(mesh, WIDE_ROWS), WIDE_BATCH, 12, monkeypatch)
+        more_than_a_pass = WIDE_ROWS // WIDE_BATCH + 4
+        exchanged, general = coefficients_by_either_form(make_table(mesh, WIDE_ROWS), WIDE_BATCH, more_than_a_pass, monkeypatch)
     np.testing.assert_array_equal(exchanged, general)
 
 
@@ -392,7 +395,7 @@ def test_ragged_strips_fit_bit_identical_by_either_form(shards, make_table, rows
     batch = shards * RAGGED_PIECE
     with mesh_lib.use_mesh(mesh):
         table = make_table(mesh, shards * batch * SLAB, RAGGED_DIM)
-        exchanged, general = coefficients_by_either_form(table, batch, 2 * SLAB + 3, monkeypatch)
+        exchanged, general = coefficients_by_either_form(table, batch, shards * SLAB + 3, monkeypatch)  # a pass and three
     np.testing.assert_array_equal(exchanged, general)
 
 
@@ -592,21 +595,23 @@ def test_compiled_for_four_v5e_the_sparse_epoch_reads_its_batch_where_it_lies(fo
 # --- one chip: the flat loop's dense epoch as one read (ops/dense_epoch.py) ------
 
 
-def compiled_flat(four_v5e, rows, loss_func, one_pass, has_weights=False):
-    """`_sgd_train_flat` for a 100-wide float32 table on ONE described v5e."""
+def compiled_flat(four_v5e, rows, loss_func, one_pass, has_weights=False, chip=0, carry=False):
+    """`_sgd_train_flat` for a 100-wide float32 table on ONE described v5e;
+    with `carry`, as a leg of a walked fit on chip `chip`."""
     from jax.sharding import SingleDeviceSharding
 
-    chip = SingleDeviceSharding(four_v5e.devices.flat[0])
+    chip = SingleDeviceSharding(four_v5e.devices.flat[chip])
 
     def on_chip(shape, dtype=np.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    train = lambda X, y, w, c, n, h: optimizer._sgd_train_flat(
-        X, y, w, c, loss_func, CELL_BATCH, has_weights, n, h, True, one_pass, False
+    train = lambda X, y, w, c, n, h, carry: optimizer._sgd_train_flat(
+        X, y, w, c, loss_func, CELL_BATCH, has_weights, n, h, True, one_pass, False, carry=carry
     )
     weights = on_chip((rows if has_weights else 0,))
     return jax.jit(train).lower(
-        on_chip((rows, 100)), on_chip((rows,)), weights, on_chip((100,)), on_chip((), np.int32), on_chip((5,))
+        on_chip((rows, 100)), on_chip((rows,)), weights, None if carry else on_chip((100,)), on_chip((), np.int32),
+        on_chip((5,)), on_chip((204,)) if carry else None,
     ).compile()
 
 
@@ -638,6 +643,37 @@ def test_compiled_for_a_v5e_the_one_read_epoch_copies_no_table(four_v5e, rows, l
     if rows == 20_000_000:
         reduce_form = compiled_flat(four_v5e, rows, getattr(losses, loss), False)
         assert not any(opcode == "custom-call" for _, opcode in instructions(reduce_form))
+
+
+@pytest.mark.parametrize("chip, has_weights", [(0, False), (3, False), (2, True)], ids=["first_chip", "last_chip", "weighted"])
+def test_compiled_for_a_v5e_a_leg_of_a_walked_fit_is_the_one_read_epoch_over_the_share_where_it_lies(
+    four_v5e, chip, has_weights
+):
+    """The four-chip cell's walked fit (`SGD._stage_walk`): a leg is the flat
+    program over one chip's share of 8M rows, whichever chip holds it; it
+    holds the one-read kernel, copies neither the share nor a column of it,
+    takes the state of the leg before as one vector of 2 * 100 + 4 numbers
+    and hands its own on as one; the finish that follows the last leg is a
+    program of a few hundred numbers."""
+    rows = 32_000_000 // 4
+    leg = compiled_flat(four_v5e, rows, losses.BINARY_LOGISTIC_LOSS, True, has_weights, chip, carry=True)
+    text = leg.as_text()
+    assert f"f32[{rows},100]{{0,1:T(8,128)}} parameter(0)" in text
+    calls = [(name, opcode) for name, opcode in instructions(leg) if opcode == "custom-call"]
+    assert len(calls) == 1 and calls[0][0].startswith("dense_epoch_one_pass")
+    assert not re.search(rf"= \w+\[[\d,]*{rows}[\d,]*\]\S* (copy|transpose)\(", text)
+    memory = leg.memory_analysis()
+    assert memory.temp_size_in_bytes < 2 << 20
+    assert memory.output_size_in_bytes <= 1024  # 204 floats handed on
+    parameters = re.findall(r"= (\w+\[[\d,]*\])\S* parameter\(\d\)", text.split("ENTRY", 1)[1])
+    assert "f32[204]" in parameters and "f32[100]" not in parameters
+    from jax.sharding import SingleDeviceSharding
+
+    on_chip = lambda shape: jax.ShapeDtypeStruct(shape, np.float32, sharding=SingleDeviceSharding(four_v5e.devices.flat[chip]))  # noqa: E731
+    finish = jax.jit(lambda carry, hyper: optimizer._finish_walk(carry, hyper, np.dtype(np.float32), True))
+    finish = finish.lower(on_chip((204,)), on_chip((5,))).compile()
+    assert finish.memory_analysis().output_size_in_bytes <= 512  # flag, 100 coefficients, criteria, epochs
+    assert not any(opcode == "custom-call" for _, opcode in instructions(finish))
 
 
 # --- one chip: the Lloyd loop's cross term by the pieces its points have (ops/distance.py) ------

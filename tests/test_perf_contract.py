@@ -43,10 +43,13 @@ FOLLOW = "keep the name, or change it in a `benchmark` PR together with the file
 # lanes, a width is whole sublanes, a shard holds one slab of batches
 BATCH, DIM, MAX_ITER, K = 4 * mesh_lib.LANES, mesh_lib.SUBLANES, 3, 4
 ROWS = 4 * BATCH * mesh_lib.SUBLANES
+# a fit that reads a batch twice: on several shards it lays its table out and
+# trains data-parallel, where one of at most a pass walks (every cell's does)
+SEVERAL_PASSES = ROWS // BATCH + 1
 
 
 @cache
-def toy_fit(config_name: str, shards: int) -> dict:
+def toy_fit(config_name: str, shards: int, max_iter: int = MAX_ITER) -> dict:
     """One cold fit of the configuration's estimator on a toy table over
     `shards` CPU devices: the XLA modules jax lowered for it, by the names it
     gave them, and the counters the fit moved."""
@@ -55,7 +58,7 @@ def toy_fit(config_name: str, shards: int) -> dict:
     stage = getattr(importlib.import_module(module), cls)()
     online = hasattr(stage, "set_initial_model_data")
     if not online:
-        stage.set_max_iter(MAX_ITER)
+        stage.set_max_iter(max_iter)
     lloyd = hasattr(stage, "set_k")
     if lloyd:
         stage.set_k(K)
@@ -200,12 +203,19 @@ def counters_read() -> list:
     "config,name", [(c["name"], p) for c in CONFIGS.values() for p in c["train_programs"]]
 )
 def test_train_program_is_launched_under_the_name_the_configuration_gives(config, name):
-    fits = [toy_fit(config, shards) for shards in (1, 4)]
+    fits = [toy_fit(config, 1), toy_fit(config, 4)]
+    if not hasattr(stage_class(config)(), "set_initial_model_data"):
+        fits.append(toy_fit(config, 4, SEVERAL_PASSES))
     assert any(name in fit["lowered"] for fit in fits), (
         f"perf/configs/{config}.json names {name} under train_programs, and on the chip "
         f"perf/metrics/epoch_roofline.py raises when a trace holds none of them, but toy fits over "
-        f"one and four shards ran {[fit['lowered'] for fit in fits]}: {FOLLOW}"
+        f"one and four shards, and of several passes over four, ran {[fit['lowered'] for fit in fits]}: {FOLLOW}"
     )
+
+
+def stage_class(config_name: str):
+    module, _, cls = CONFIGS[config_name]["stage"]["class"].rpartition(".")
+    return getattr(importlib.import_module(module), cls)
 
 
 def dense_assembly() -> dict:
@@ -233,7 +243,20 @@ def lloyd_fit_of_general_floats() -> dict:
     return metrics.snapshot_delta(before, metrics.snapshot())["counters"]
 
 
-OTHER_SIDE = {"assembler.dense_out": dense_assembly, "lloyd.product.full": lloyd_fit_of_general_floats}
+def dense_fit_of_several_passes_over_four_shards() -> dict:
+    """The counters of the dense fit that no cell runs since the walk: one
+    that reads a batch twice lays its table out (the exchange for the table,
+    the general form for the label) and trains by the reduce form."""
+    return toy_fit("lr-dense-100", 4, SEVERAL_PASSES)["counters"]
+
+
+OTHER_SIDE = {
+    "assembler.dense_out": dense_assembly,
+    "lloyd.product.full": lloyd_fit_of_general_floats,
+    "layout.exchange": dense_fit_of_several_passes_over_four_shards,
+    "layout.general": dense_fit_of_several_passes_over_four_shards,
+    "dense_epoch.reduce": dense_fit_of_several_passes_over_four_shards,
+}
 
 
 def collection_inside_a_fit() -> dict:
@@ -294,8 +317,9 @@ def test_prep_program_is_launched_under_the_name_the_configuration_gives(config,
 @pytest.mark.parametrize("phase", documented_phases())
 def test_documented_phase_is_emitted_once_a_fit(phase):
     """Once in every fit that goes through it (`fit.layout`: not on one
-    shard), and in one cell's fit at least."""
+    shard and not in a walked fit, so in no cell's), and in one fit at least."""
     counted = {cell: toy_fit_of_cell(cell)["counters"].get(phase + ".n", 0) for cell in CELLS}
+    counted["several passes over four shards"] = dense_fit_of_several_passes_over_four_shards().get(phase + ".n", 0)
     for cell in pipeline_cells():
         # `fit.total` is every estimator's, once a fitted stage: a pipeline's
         # fit counts one for itself and one for each estimator in front of
@@ -364,6 +388,39 @@ def test_a_toy_stream_syncs_with_the_host_for_no_batch():
         assert 0 < counters["sync.fence.wait.ns"] <= counters["online.fence.ns"]
         assert not any(name.startswith("sync.") and not name.startswith("sync.fence.") for name in counters)
         assert counters.get("online.fence.dry", 0) <= 1
+
+
+def test_the_four_chip_cells_toy_fit_walks_under_a_train_program_the_configuration_names():
+    """`lr-dense-100.pass-x4` is one pass over a row-sharded table of whole
+    batches: its fit walks, a leg is `jit__sgd_train_flat` (so
+    `epoch_roofline` finds its program in a trace and does not raise), and
+    the counters say so by the names the docs give them."""
+    cell = "lr-dense-100.pass-x4"
+    fit, config = toy_fit_of_cell(cell), CONFIGS[CELLS[cell]["config"]]
+    counters = fit["counters"]
+    assert counters["layout.walk"] == 1 and counters["layout.walk.legs"] == 4
+    assert not any(name in counters for name in ("layout.exchange", "layout.general", "fit.layout.n"))
+    assert not any(name.startswith("collective.") for name in counters)
+    assert counters["iteration.host_sync"] == 1 and counters["fit.launch.n"] == 1
+    assert "jit__sgd_train_flat" in fit["lowered"] and "jit__sgd_train_flat" in config["train_programs"]
+    assert not {"jit__sgd_train", "jit__exchange_batches_impl", "jit__layout_batches_impl"} & set(fit["lowered"])
+    fit_phases = (ROOT / "docs" / "observability.md").read_text().split("## Fit phases", 1)[1].split("\n## ", 1)[0]
+    assert "`layout.walk`" in fit_phases and "`layout.walk.legs`" in fit_phases, FOLLOW
+    # what the cell's readers make of it: a trace that holds the lowered
+    # programs and no other, one fit of MAX_ITER epochs in a window of a second
+    run = {
+        "counters": counters,
+        "config": config,
+        "window": {"units": [MAX_ITER * BATCH], "attempted": 1, "begin": 0.0, "end": 1.0},
+        "least_per_unit": {"seconds": 1e-9, "flops_seconds": 1e-12, "bound": "hbm"},
+        "trace": {"modules_s": {name: 1e-3 for name in fit["lowered"]}, "window_s": 1.0},
+    }
+    read = lambda metric: perf_module("metrics", metric).read(run)  # noqa: E731
+    assert read("dense_one_pass_share") == 100.0
+    assert read("layout_exchange_share") is None  # nothing was laid out
+    assert read("host_syncs_per_fit") == 1.0
+    assert read("epoch_roofline") == pytest.approx(MAX_ITER * BATCH * 1e-9 / 1e-3 * 100.0)
+    assert read("fit_mfu") == pytest.approx(MAX_ITER * BATCH * 1e-9 * 100.0)
 
 
 FIT_CELLS = METRICS["fit_host_self_ms"]["workloads"]
