@@ -15,8 +15,20 @@ are vmapped over a leading fleet axis (`ops.optimizer._sgd_fleet_*`,
   bit-identical to its solo fit (every contraction in the member bodies
   is vmap-batching bit-stable — see ops/losses.py module docstring);
 - the staged dataset is closed over UNBATCHED: input bytes are paid once
-  for N models;
+  for N models, and the members find their batch once an epoch, at the
+  fleet's furthest epoch (`ops.optimizer.FLEET_AXIS`; a batch indexed by
+  each member's own counter is a gather of N copies of it);
 - readback is ONE packed [N, result_pack] array.
+
+In place or laid out: on ONE device, over a dense device table of the
+engine's dtype in whole batches, the programs read the caller's table
+where it lies (`SGD._in_place`, an `optimizer.FlatBatches` view;
+`optimizer._can_train_in_place` decides from the arrays and the mesh, and
+the fit counts `fleet.in_place`), as `SGD._stage_flat` trains a solo fit:
+a laid-out copy of a table that fills half a chip has no room beside it.
+Several data shards, the fleet-sharded regime, a sparse, host or ragged
+table and a checkpointed fleet lay their table out as before
+(`SGD._batchify`); a `StreamTable` keeps its stacked segments.
 
 Sharding over the fleet axis: when N x per-member state crosses
 `config.fleet_shard_state_bytes` (and N divides the data shards), the
@@ -35,7 +47,17 @@ tests pin it. On the TPU v5e the vmapped program rounds its contractions
 differently from the solo program: a 32-member fleet differed from its
 solo fits by at most 2.2e-7 relative on a four-chip mesh and 1.4e-7 on
 one device (PR 21, `bench.bench_fleet_sweep`), which is why the bench
-asserts 1e-5 and reports `bitIdenticalToSolo` as measured.
+asserts 1e-5 and reports `bitIdenticalToSolo` as measured; the
+benchmark's cell `lr-regpath-100.path` holds every member of a 100-member
+path to the plain reference's solo fits at the tolerance measured there
+(PERF.md §4, PR 39).
+
+A fleet fit is ONE fit to the observability layer (`fit.total`,
+`fit.outer`, the `stage.fit` span), and a fleet of linear members over an
+in-memory table reports a solo fit's phases once a FLEET fit:
+`fit.extract`, `fit.stage`, `fit.launch`, `fit.readback`, its one
+blocking read through `tracing.sync` (docs/observability.md, "Fit
+phases").
 
 Fleet checkpointing rides the JobSnapshot coordinator (ckpt/snapshot.py)
 as one cut over the fleet-axis-sharded carry (section "fleet", tag
@@ -58,6 +80,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .obs import tracing
 from .parallel import mesh as mesh_lib
 from .parallel import prefetch as h2d
 
@@ -248,13 +271,81 @@ class FitFleet:
         from .models import _linear
         from .utils import metrics
         from .ops.losses import sparse_variant
-        from .ops.optimizer import SGD
-        from .parallel import dispatch, overlap
+        from .ops.optimizer import SGD, _can_train_in_place
         from .table import StreamTable
 
         ests = self.estimators
         loss_name, validate = _LINEAR_KINDS[self.kind]
         loss_func = _loss_by_name(loss_name)
+        if isinstance(table, StreamTable):
+            return self._fit_linear_stream(
+                table, mesh, loss_func, *self._shared_params(validate), validate
+            )
+        # the fleet's phases are a solo fit's (`_linear.run_sgd`,
+        # `SGD.optimize_async`), once a FLEET fit: one job, N models
+        with tracing.phase("fit.extract"):
+            hyper, gmax, features_col, label_col, weight_col, gbs = self._shared_params(validate)
+            X, y, w = _linear.extract_train_data(
+                table, features_col, label_col, weight_col, keep_sparse=True
+            )
+            validate_on_device = False
+            if validate:
+                if isinstance(y, jax.Array):
+                    validate_on_device = True  # fused into the fleet program
+                else:
+                    _linear.validate_binomial_labels(y)
+            if isinstance(X, tuple):  # sparse padded-CSR, never densified
+                indices, values, d = X
+                X = (indices, values)
+                loss_func = sparse_variant(loss_func.name)
+                n_rows = int(indices.shape[0])
+            else:
+                n_rows, d = map(int, X.shape)
+
+        with tracing.phase("fit.stage"):
+            # coeff + grad are the dim-proportional member state
+            sharded = self._decide_sharded(mesh, state_bytes=2 * len(ests) * d * 4)
+            metrics.set_gauge("fleet.sharded", 1.0 if sharded else 0.0)
+            template = SGD(global_batch_size=gbs)
+            in_place = (
+                not sharded
+                and config.iteration_checkpoint_dir is None
+                and _can_train_in_place(X, y, w, gbs, template.dtype, mesh)
+            )
+            if in_place:
+                metrics.inc_counter("fleet.in_place")
+                X_b, y_b, w_b = template._in_place(mesh, X, y, w)
+            else:
+                X_b, y_b, w_b = template._batchify(mesh, X, y, w, replicate_data=sharded)
+            carry, crit = self._stage_fleet_state(mesh, len(ests), d, sharded)
+
+        flags, coeffs, crits, epochs = self._run_fleet_sgd(
+            mesh, X_b, y_b, w_b, carry, crit, loss_func, hyper, gmax, d,
+            validate_on_device, sharded, gbs,
+        )
+        if flags is not None:
+            _linear._raise_if_invalid(float(np.min(flags)))
+        # counted from rows and epochs: an epoch trains batch k = epoch mod
+        # the table's batches, whose last may be short
+        num_batches = max(1, -(-n_rows // gbs))
+        metrics.inc_counter(
+            "fleet.examplesTrained",
+            sum(int(e // num_batches) * n_rows + min(int(e % num_batches) * gbs, n_rows) for e in epochs),
+        )
+        models = []
+        for i, est in enumerate(ests):
+            model = _linear_model_for(est)
+            model.coefficient = np.asarray(coeffs[i], np.float64)
+            models.append(model)
+        return models
+
+    def _shared_params(self, validate: bool):
+        """What every member has to share and the packed per-member rest:
+        (hyper [N, 5], the longest maxIter, featuresCol, labelCol, weightCol,
+        globalBatchSize)."""
+        from .parallel import dispatch, overlap
+
+        ests = self.estimators
         features_col = _require_same(ests, "get_features_col", "featuresCol")
         label_col = _require_same(ests, "get_label_col", "labelCol")
         weight_col = _require_same(ests, "get_weight_col", "weightCol")
@@ -267,58 +358,11 @@ class FitFleet:
                         "Supported options: [auto, binomial]."
                     )
         hyper = np.asarray([_member_hyper(e) for e in ests], np.float32)
-        gmax = int(hyper[:, 0].max())
         if self._overlap_requested() and not overlap.fleet_overlap_supported():
             # overlap-scheduled programs cannot host the fleet axis yet;
             # reason-counted so overlap-tuned deployments see the downgrade
             dispatch.account_whole_fit_fallback("fleet_overlap")
-
-        if isinstance(table, StreamTable):
-            return self._fit_linear_stream(
-                table, mesh, loss_func, hyper, gmax,
-                features_col, label_col, weight_col, gbs, validate,
-            )
-
-        X, y, w = _linear.extract_train_data(
-            table, features_col, label_col, weight_col, keep_sparse=True
-        )
-        validate_on_device = False
-        if validate:
-            if isinstance(y, jax.Array):
-                validate_on_device = True  # fused into the fleet program
-            else:
-                _linear.validate_binomial_labels(y)
-        if isinstance(X, tuple):  # sparse padded-CSR, never densified
-            indices, values, d = X
-            X = (indices, values)
-            loss_func = sparse_variant(loss_func.name)
-        else:
-            d = int(X.shape[1])
-
-        # coeff + grad are the dim-proportional member state
-        sharded = self._decide_sharded(mesh, state_bytes=2 * len(ests) * d * 4)
-        metrics.set_gauge("fleet.sharded", 1.0 if sharded else 0.0)
-        template = SGD(global_batch_size=gbs)
-        X_b, y_b, w_b = template._batchify(mesh, X, y, w, replicate_data=sharded)
-        carry, crit = self._stage_fleet_state(mesh, len(ests), d, sharded)
-
-        flags, coeffs, crits, epochs = self._run_fleet_sgd(
-            mesh, X_b, y_b, w_b, carry, crit, loss_func, hyper, gmax, d,
-            validate_on_device, sharded, gbs,
-        )
-        if flags is not None:
-            _linear._raise_if_invalid(float(np.min(flags)))
-        n_rows = int(y_b.shape[0]) * int(y_b.shape[1])
-        metrics.inc_counter(
-            "fleet.examplesTrained",
-            int(np.sum(epochs)) * (n_rows // max(1, int(y_b.shape[0]))),
-        )
-        models = []
-        for i, est in enumerate(ests):
-            model = _linear_model_for(est)
-            model.coefficient = np.asarray(coeffs[i], np.float64)
-            models.append(model)
-        return models
+        return hyper, int(hyper[:, 0].max()), features_col, label_col, weight_col, gbs
 
     def _overlap_requested(self) -> bool:
         from . import config
@@ -336,7 +380,6 @@ class FitFleet:
         from . import config
         from .ckpt import faults
         from .ckpt import snapshot as _snapshot
-        from .obs import tracing
         from .ops import optimizer as opt
         from .parallel import dispatch
         from .utils.packing import packed_device_get
@@ -386,9 +429,8 @@ class FitFleet:
                     check_labels, pack_sharding,
                     start=planned, end=gmax,
                 )
-                (host,) = packed_device_get(packed, sync_kind="fit")
                 flags, coeffs, crits, epochs = opt.unpack_fleet_train_result(
-                    np.asarray(host), d, check_labels
+                    opt._read_packed(packed), d, check_labels
                 )
                 if (
                     ckpt_dir is not None
@@ -438,9 +480,8 @@ class FitFleet:
             opt._sgd_fleet_final, carry, crit, hyper_dev, pack_sharding,
             start=planned, end=planned,
         )
-        (host,) = packed_device_get(packed, sync_kind="fit")
         flags, coeffs, crits, epochs = opt.unpack_fleet_train_result(
-            np.asarray(host), d, False
+            opt._read_packed(packed), d, False
         )
         if check_labels:
             flag = packed_device_get(
@@ -624,6 +665,11 @@ class FitFleet:
             update_existing_params(model, est)
             models.append(model)
         return models
+
+
+# a fleet fit is one fit to the observability layer: `fit.total`, the
+# outermost fit (`fit.outer`: one job, N models) and the `stage.fit` span
+tracing.instrument_stage_methods(FitFleet)
 
 
 # ---------------------------------------------------------------------------

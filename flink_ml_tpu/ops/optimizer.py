@@ -81,16 +81,39 @@ class BatchStrips:
         return jnp.swapaxes(strips, 1, 2)[:, :, : self.width].reshape(-1, self.width)
 
 
+@partial(jax.tree_util.register_dataclass, data_fields=("rows",), meta_fields=("size",))
+@dataclass(frozen=True)
+class FlatBatches:
+    """A one-shard table where it lies, read as its batches
+    (`SGD._in_place`): `rows` is the caller's own (n, width) array, n a whole
+    number of batches of `size` rows, and batch k is rows [k * size,
+    (k + 1) * size), sliced where an epoch reads it as `_sgd_train_flat`
+    slices its own. Nothing is laid out: the general form's (num_batches,
+    batch, width) array is a second table (and a third where the device pads
+    100 columns to a tile's 128 lanes), which a table that fills half a chip
+    has no room for."""
+
+    rows: jax.Array
+    size: int
+
+    @property
+    def dtype(self):
+        return self.rows.dtype
+
+    def batch(self, k):
+        return lax.dynamic_slice_in_dim(self.rows, k * self.size, self.size, 0)
+
+
 def _index_batch(X_b, k):
     """Select batch k from batched features; X may be a dense array or the
     sparse (indices, values) tuple — every driver treats features as a
     pytree so the sparse padded-CSR layout flows through unchanged. Each
     array says by its own form how its batch is found: the general layout's
-    (num_batches, batch, ...) by an index, the exchange's `BatchStrips` by
-    its view."""
+    (num_batches, batch, ...) by an index, the exchange's `BatchStrips` and
+    the in-place `FlatBatches` by their views."""
     if isinstance(X_b, tuple):
         return tuple(_index_batch(leaf, k) for leaf in X_b)
-    if isinstance(X_b, BatchStrips):
+    if isinstance(X_b, (BatchStrips, FlatBatches)):
         return X_b.batch(k)
     return lax.dynamic_index_in_dim(X_b, k, 0, False)
 
@@ -262,6 +285,33 @@ def _can_walk(X, y, weights, batch, max_iter, dtype, mesh) -> bool:
             for c in columns
         )
         and all(arr.dtype == dtype for arr in [X] + columns)
+    )
+
+
+def _can_train_in_place(X, y, weights, batch, dtype, mesh) -> bool:
+    """Whether a fleet's programs read a table's batches where the table
+    lies (`SGD._in_place`, a `FlatBatches` view) and lay nothing out. They do
+    where `_stage_flat` would train a solo fit without a copy: ONE device
+    (a mesh of it alone, and the table on it), a dense table of the
+    engine's dtype (a cast is a copy of the table) whose rows are a whole
+    number of batches (ragged rows keep the padded copy), with y, and a
+    weight column where there is one, device columns of that dtype. All read
+    off shapes, dtypes, the array's devices and the mesh, nothing a user
+    sets; and the one place that decides, counted as `fleet.in_place` a
+    fleet fit. What it turns away keeps the laid-out route as it was:
+    several data shards, the fleet-sharded regime (which needs several), a
+    sparse or a host table. `FitFleet` never asks for a stream or under a
+    checkpoint directory."""
+    columns = [y] if weights is None else [y, weights]
+    return (
+        mesh.devices.size == 1
+        and isinstance(X, jax.Array)
+        and X.ndim == 2
+        and X.dtype == dtype
+        and X.shape[0] > 0
+        and X.shape[0] % batch == 0
+        and X.devices() == set(mesh.devices.flat)
+        and all(isinstance(c, jax.Array) and c.ndim == 1 and c.dtype == dtype for c in columns)
     )
 
 
@@ -686,12 +736,22 @@ def _unpack_stream_batch(packed, d, mat_sharding, row_sharding):
     return X, y, w
 
 
-def _sgd_chunk_impl(X_b, y_b, w_b, carry, criteria, loss_func, hyper, chunk_end):
+def _sgd_chunk_impl(X_b, y_b, w_b, carry, criteria, loss_func, hyper, chunk_end, fleet_axis=None):
     """Up to `chunk_end - carry.epoch` host-driven epochs fused into ONE
     device program, for the checkpointed train loop: the tol check runs
     every epoch inside the while condition (same order as the per-epoch
     loop, so the stop epoch is identical for any chunk size), and the only
-    readback is the packed [epoch, criteria] pair."""
+    readback is the packed [epoch, criteria] pair.
+
+    `fleet_axis` is the name of the `vmap` axis a fleet program maps this
+    loop over: the epoch that finds the batch is then the fleet's furthest
+    (a maximum over the members, one number; over a fleet axis that is
+    sharded, four bytes on the wire an epoch), which is every running
+    member's own (the members start together and step together) and a
+    stopped member's step is thrown away by the loop's select. Indexed by
+    each member's own counter the batch is a gather of N copies of itself,
+    4.8 GB an epoch for 100 members of the reference's batch, and the table
+    is copied to feed it."""
     num_batches = y_b.shape[0]
     dtype = _feature_dtype(X_b)
     _, tol, lr, reg, elastic_net = _unpack_hyper(hyper, dtype)
@@ -702,7 +762,8 @@ def _sgd_chunk_impl(X_b, y_b, w_b, carry, criteria, loss_func, hyper, chunk_end)
 
     def step(state):
         c, _ = state
-        k = jnp.mod(c[3], num_batches)
+        epoch = c[3] if fleet_axis is None else collectives.all_reduce_max(c[3], fleet_axis)
+        k = jnp.mod(epoch, num_batches)
         Xk = _index_batch(X_b, k)
         yk = lax.dynamic_index_in_dim(y_b, k, axis=0, keepdims=False)
         wk = lax.dynamic_index_in_dim(w_b, k, axis=0, keepdims=False)
@@ -809,6 +870,11 @@ _sgd_stream_whole_fit = lazy_jit(
 # preserving the update-not-fused-into-the-loop-epilogue guarantee that
 # makes whole-fit results match the chunked path's host-side
 # `_final_update` bitwise.
+#
+# The member axis has a name, `FLEET_AXIS`, by which the vmapped loop finds
+# its batch ONCE for all members (`_sgd_chunk_impl`, `fleet_axis`).
+
+FLEET_AXIS = "fleet"
 
 
 def _fleet_member_finish(carry, criteria, hyper, dtype, flag):
@@ -837,11 +903,11 @@ def _sgd_fleet_whole_fit_impl(
     def member_loop(c, crit, h):
         member_max_iter = _unpack_hyper(h, dtype)[0]
         c, crit, _ = _sgd_chunk_impl(
-            X_b, y_b, w_b, c, crit, loss_func, h, member_max_iter
+            X_b, y_b, w_b, c, crit, loss_func, h, member_max_iter, FLEET_AXIS
         )
         return c, crit
 
-    carry, criteria = jax.vmap(member_loop)(carry, criteria, hyper)
+    carry, criteria = jax.vmap(member_loop, axis_name=FLEET_AXIS)(carry, criteria, hyper)
     carry = lax.optimization_barrier(carry)
     flag = _binomial_labels_ok(y_b) if check_labels else None
 
@@ -872,9 +938,9 @@ def _sgd_fleet_chunk_impl(X_b, y_b, w_b, carry, criteria, loss_func, hyper, chun
         member_end = jnp.minimum(
             jnp.asarray(chunk_end, jnp.int32), _unpack_hyper(h, dtype)[0]
         )
-        return _sgd_chunk_impl(X_b, y_b, w_b, c, crit, loss_func, h, member_end)
+        return _sgd_chunk_impl(X_b, y_b, w_b, c, crit, loss_func, h, member_end, FLEET_AXIS)
 
-    return jax.vmap(member)(carry, criteria, hyper)
+    return jax.vmap(member, axis_name=FLEET_AXIS)(carry, criteria, hyper)
 
 
 _sgd_fleet_chunk = lazy_jit(_sgd_fleet_chunk_impl, static_argnames=("loss_func",))
@@ -2034,6 +2100,32 @@ class SGD:
         )
         (coeff_h,) = packed_device_get(coeff, sync_kind="fit")
         return np.asarray(coeff_h), final_crit, final_epoch
+
+    def _in_place(self, mesh: Mesh, X, y, weights):
+        """`_batchify`'s three for a table `_can_train_in_place` admits, the
+        table not touched: X is a `FlatBatches` view of the caller's array,
+        and only the columns take the general form's (num_batches, batch)
+        shape, 80 MB each for the 20M rows whose table is 8.3 GB (y a
+        reshape; absent weights the ones `_default_weights` makes, as on the
+        laid-out route). No `fit.layout` and no `layout.*` tick: no table is
+        laid out. The batches are read by the reduce form, as laid-out ones
+        are."""
+        n, B = int(X.shape[0]), int(self.global_batch_size)
+        num_batches = n // B
+        row_sharding = NamedSharding(mesh, P(None, mesh_lib.DATA_AXIS))
+        batches = (n, num_batches, B, B)  # no row and no batch is padded
+        y_b = _layout_batches(y, *batches, None, row_sharding)
+        if weights is None:
+            w_b = _default_weights(*batches, self.dtype, row_sharding)
+        else:
+            w_b = _layout_batches(weights, *batches, None, row_sharding)
+        metrics.inc_counter("dense_epoch.reduce")
+        # the table is the caller's and stays where it is: ledgered as the
+        # fit's training-data residency, like the flat route's
+        from ..obs import memledger
+
+        memledger.track((X, y_b, w_b), "streamSegments")
+        return FlatBatches(X, B), y_b, w_b
 
     def _batchify(self, mesh: Mesh, X, y, weights, d_pad=None, replicate_data=False):
         """`_lay_out` as the `fit.layout` phase: the host's time to cast,

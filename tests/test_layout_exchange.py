@@ -739,3 +739,47 @@ def test_compiled_for_a_v5e_the_look_is_one_pass_with_no_temporary(four_v5e):
     look = jax.jit(km._exact_in_bfloat16_impl).lower(table).compile()
     assert " reduce-precision(" in look.as_text() and " convert(" not in look.as_text()
     assert look.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def compiled_fleet(mesh, members, in_place):
+    """`_sgd_fleet_whole_fit_impl` for one v5e chip over the path cell's
+    table, 20M x 100 in batches of 100,000: the caller's table viewed where it
+    lies (`FlatBatches`), or laid out first."""
+    from jax.sharding import SingleDeviceSharding
+
+    chip = SingleDeviceSharding(mesh.devices.flat[0])
+    rows, width, batches = 20_000_000, 100, 200
+
+    def on_chip(shape, dtype=np.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def fit(X, y_b, w_b, carry, criteria, hyper):
+        X_b = optimizer.FlatBatches(X, CELL_BATCH) if in_place else X
+        return optimizer._sgd_fleet_whole_fit_impl(
+            X_b, y_b, w_b, carry, criteria, losses.BINARY_LOGISTIC_LOSS, hyper, True, None
+        )
+
+    carry = (on_chip((members, width)), on_chip((members, width)), on_chip((members,)), on_chip((members,), np.int32))
+    table = on_chip((rows, width) if in_place else (batches, CELL_BATCH, width))
+    return jax.jit(fit).lower(
+        table, on_chip((batches, CELL_BATCH)), on_chip((batches, CELL_BATCH)), carry, on_chip((members,)), on_chip((members, 5))
+    ).compile()
+
+
+@pytest.mark.parametrize("in_place", [True, False], ids=["in_place", "laid_out"])
+def test_compiled_for_a_v5e_a_fleet_of_a_hundred_reads_one_batch_an_epoch_and_copies_no_table(four_v5e, in_place):
+    """The path cell's program (`lr-regpath-100.path`): the table is the
+    parameter as the device keeps it (rows-minor for the flat view), no copy
+    and no gather of it, and the temporaries are a batch and the members'
+    products of it. Indexed by each member's own epoch the same program asked
+    for 22 GB of a 15.75 GB chip: the table copied rows-major for a gather
+    that made a hundred copies of the batch."""
+    fleet = compiled_fleet(four_v5e, 100, in_place)
+    text = fleet.as_text()
+    if in_place:
+        assert "f32[20000000,100]{0,1:T(8,128)} parameter(0)" in text
+    assert " gather(" not in text
+    assert not re.search(r"= f32\[(20000000,100|200,100000,100)\]\S* (copy|transpose)\(", text)
+    memory = fleet.memory_analysis()
+    assert memory.argument_size_in_bytes >= 20_000_000 * 100 * 4
+    assert memory.temp_size_in_bytes < 256 << 20  # a batch is 42 MB as the device keeps it, the products 41

@@ -92,6 +92,17 @@ def toy_fit(config_name: str, shards: int, max_iter: int = MAX_ITER) -> dict:
         features = by_rows(values)
     label = by_rows((values.sum(axis=1) > DIM / 2).astype(np.float32))
     table = Table({"features": features, "label": label})
+    fleet = config["stage"].get("fleet")
+    if fleet:
+        # a fleet configuration: the job is a `FitFleet` of the estimator at
+        # the first few values of the grid, every other hyperparameter shared
+        from flink_ml_tpu.fleet import FitFleet
+
+        setter = "set_" + fleet["param"]
+        grid = config["stage"]["params"][fleet["param"]][:K]
+        stage = FitFleet(
+            [getattr(stage_class(config_name)().set_max_iter(max_iter).set_global_batch_size(BATCH), setter)(v) for v in grid]
+        )
     if "pipeline" in config:
         # a pipeline configuration: its feature stages in front of the
         # estimator, over raw columns of their names (a numeric matrix, index
@@ -341,6 +352,29 @@ def estimators_of(config: dict) -> int:
 
     classes = [spec["class"].rpartition(".") for spec in config["pipeline"]]
     return sum(issubclass(getattr(importlib.import_module(m), c), Estimator) for m, _, c in classes)
+
+
+def test_the_fleet_cells_toy_fit_is_one_fleet_trained_in_place():
+    """`lr-regpath-100.path` is a `FitFleet` over a one-device table of whole
+    batches: it copies no table, it is one fit with one readback whatever its
+    members, its program is the one the configuration names, and the two
+    readers the cell brought say so."""
+    cell = "lr-regpath-100.path"
+    fit, config = toy_fit_of_cell(cell), CONFIGS[CELLS[cell]["config"]]
+    counters = fit["counters"]
+    assert config["stage"]["fleet"]["members"] == len(config["stage"]["params"]["reg"]) == 100
+    assert counters["fleet.fits"] == counters["fleet.in_place"] == 1 and counters["fleet.modelsTrained"] == K
+    assert counters["fleet.examplesTrained"] == K * MAX_ITER * BATCH
+    assert not any(name in counters for name in ("layout.exchange", "layout.general", "fit.layout.n"))
+    assert counters["iteration.host_sync"] == counters["fit.outer.n"] == counters["fit.total.n"] == 1
+    assert "jit__sgd_fleet_whole_fit_impl" in fit["lowered"] and not {"jit__sgd_train_flat", "jit__sgd_train"} & set(fit["lowered"])
+    fit_phases = (ROOT / "docs" / "observability.md").read_text().split("## Fit phases", 1)[1].split("\n## ", 1)[0]
+    assert "`fleet.in_place`" in fit_phases, FOLLOW
+    run = {"counters": counters, "window": {"attempted": 1}, "trace": None}
+    assert perf_module("metrics", "fleet_members_per_fit").read(run) == K
+    assert perf_module("metrics", "fleet_in_place_share").read(run) == 100.0
+    # on several shards the same job lays its table out, as it did
+    assert not toy_fit(CELLS[cell]["config"], 4)["counters"].get("fleet.in_place")
 
 
 @pytest.mark.parametrize("phase", documented_phases("Pipeline phases", "pipeline"))
