@@ -12,8 +12,9 @@ are vmapped over a leading fleet axis (`ops.optimizer._sgd_fleet_*`,
 - the per-member convergence mask is the vmapped `while_loop` itself —
   it runs until EVERY member's condition is false and select-freezes
   finished members, so each member's stop epoch and coefficients are
-  bit-identical to its solo fit (every contraction in the member bodies
-  is vmap-batching bit-stable — see ops/losses.py module docstring);
+  bit-identical to its solo fit on the CPU (every contraction in the
+  member bodies is vmap-batching bit-stable there — see ops/losses.py
+  module docstring; on a TPU see below);
 - the staged dataset is closed over UNBATCHED: input bytes are paid once
   for N models, and the members find their batch once an epoch, at the
   fleet's furthest epoch (`ops.optimizer.FLEET_AXIS`; a batch indexed by
@@ -43,14 +44,20 @@ ONE data shard (and allclose to any shard count — the across-mesh
 reduction-order doctrine of docs/fault_tolerance.md).
 
 "Bit-identical" above is a statement about the CPU backend, where the
-tests pin it. On the TPU v5e the vmapped program rounds its contractions
-differently from the solo program: a 32-member fleet differed from its
-solo fits by at most 2.2e-7 relative on a four-chip mesh and 1.4e-7 on
-one device (PR 21, `bench.bench_fleet_sweep`), which is why the bench
-asserts 1e-5 and reports `bitIdenticalToSolo` as measured; the
-benchmark's cell `lr-regpath-100.path` holds every member of a 100-member
-path to the plain reference's solo fits at the tolerance measured there
-(PERF.md §4, PR 39).
+tests pin it. On a TPU the fleet's epochs do not take the reduce form at
+all: over a dense float32 table the members' row-dots and gradients are
+two float32 matrix products at `Precision.HIGHEST`, one for all members
+each (`ops.losses.product_variant`, decided by
+`ops.optimizer._fleet_multiplies` and counted as `fleet.product.matrix`
+or `fleet.product.reduce` a fleet fit), on the matrix unit where the two
+reductions ran on the vector unit (PERF.md §6, PR 40). They sum in
+another order than a solo fit, so a member agrees with its solo fit to
+rounding; even the reduce form's vmapped program rounded differently
+there (a 32-member fleet within 2.2e-7 of its solo fits, PR 21,
+`bench.bench_fleet_sweep`, which asserts 1e-5 and reports
+`bitIdenticalToSolo` as measured). The benchmark's cell
+`lr-regpath-100.path` holds every member of a 100-member path to the
+plain reference's solo fits (PERF.md §4).
 
 A fleet fit is ONE fit to the observability layer (`fit.total`,
 `fit.outer`, the `stage.fit` span), and a fleet of linear members over an
@@ -103,6 +110,22 @@ def _loss_by_name(name: str):
         "hinge": losses.HINGE_LOSS,
         "least_square": losses.LEAST_SQUARE_LOSS,
     }[name]
+
+
+def _product_form(X_b, loss_func):
+    """The loss the fleet's programs take for the table as they are handed
+    it: the matrix-product form where `optimizer._fleet_multiplies` says so
+    (a dense float32 table on a TPU), else `loss_func` as it is; one tick a
+    fleet fit, `fleet.product.matrix` or `fleet.product.reduce`."""
+    from .ops.losses import product_variant
+    from .ops.optimizer import _fleet_multiplies
+    from .utils import metrics
+
+    if _fleet_multiplies(X_b, loss_func):
+        metrics.inc_counter("fleet.product.matrix")
+        return product_variant(loss_func)
+    metrics.inc_counter("fleet.product.reduce")
+    return loss_func
 
 
 def _linear_model_for(est):
@@ -317,6 +340,7 @@ class FitFleet:
                 X_b, y_b, w_b = template._in_place(mesh, X, y, w)
             else:
                 X_b, y_b, w_b = template._batchify(mesh, X, y, w, replicate_data=sharded)
+            loss_func = _product_form(X_b, loss_func)
             carry, crit = self._stage_fleet_state(mesh, len(ests), d, sharded)
 
         flags, coeffs, crits, epochs = self._run_fleet_sgd(
@@ -559,6 +583,7 @@ class FitFleet:
         packed_all = h2d.stage_to_device(
             packed_np, seg_sharding, category="streamSegments"
         )
+        loss_func = _product_form(packed_all, loss_func)
         carry, crit = self._stage_fleet_state(mesh, len(ests), d, sharded)
         if dispatch.whole_fit_enabled():
             dispatch.account_whole_fit("fleet")

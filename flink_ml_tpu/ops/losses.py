@@ -28,14 +28,24 @@ loop takes its epoch's sums from `ops/dense_epoch.one_pass` instead, a
 Pallas kernel that reads the batch once and forms the row-dot, this
 module's `pointwise` and the gradient from the tile in fast memory, for a
 narrow float32 table the device keeps rows-minor
-(`optimizer._can_one_pass` decides, from the array alone). Everything else
-keeps the reduce form: laid-out batches on several shards (GSPMD; that
-program reads its batch once already), the fleet's `vmap`, sparse rows, a
-wide or 16-bit table, and every fit on the CPU, where the bit-parity
-contracts above live and a second read of X costs nothing like it does on
-the chip. The kernel sums the same float32 products in another order (rows
-by lane, then the lanes), so its fits agree with the reduce form's to
-rounding, not to the bit.
+(`optimizer._can_one_pass` decides, from the array alone). The kernel sums
+the same float32 products in another order (rows by lane, then the lanes),
+so its fits agree with the reduce form's to rounding, not to the bit.
+
+On the TPU a fleet's epoch is handed `product_variant(loss)` instead, whose
+contractions are `jnp.dot` at `Precision.HIGHEST` (`_dense_product`): under
+the fleet's member `vmap` the batched coefficient becomes a free dimension,
+so the N members' row-dots are ONE [B, d] x [d, N] float32 product and
+their gradients ONE [N, B] x [B, d], on the matrix unit, where the reduce
+form's two vector-unit reductions took 94% of a 100-member fleet's epoch
+(PERF.md §5, PR 39; `optimizer._fleet_multiplies` decides, from the table
+and the loss, and a fleet fit counts `fleet.product.matrix` or
+`fleet.product.reduce`). Its members agree with their solo fits to
+rounding, as the kernel's do. So the reduce form is now the CPU's, where
+the fleet's bit-parity contract lives and a second read of X costs nothing
+like it does on the chip, and the solo general form's: laid-out batches on
+several shards (GSPMD; that program reads its batch once already), sparse
+rows, a wide or 16-bit table, the overlap schedule (parallel/overlap.py).
 """
 
 from __future__ import annotations
@@ -43,6 +53,7 @@ from __future__ import annotations
 from typing import Callable, NamedTuple, Tuple
 
 import jax.numpy as jnp
+from jax import lax
 
 LossOut = Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]  # (loss_sum, grad_sum, weight_sum)
 
@@ -93,9 +104,13 @@ def _least_square_pointwise(dot, y, w):
 def dense_dot(X, coeff):
     """Per-row dot products X[B,d] · coeff[d] -> [B], in the
     vmap-batching-stable reduce form (see module docstring). Every dense
-    training-path dot MUST go through this helper (or `dense_grad`) —
-    mixing it with a `X @ coeff` matvec in a parity-coupled path
-    reintroduces the gemv/gemm accumulation split."""
+    training-path dot that a bit-parity contract couples MUST go through
+    this helper (or `dense_grad`): a solo fit and its fleet on the CPU,
+    whole-fit and chunked programs. Mixing it with a `X @ coeff` matvec in
+    such a path reintroduces the gemv/gemm accumulation split. The fleet's
+    matrix form (`product_variant`) is taken on the TPU alone, where no
+    such contract holds: a solo fit there takes the one-read kernel or this
+    form, and neither sums in the product's order."""
     return jnp.sum(X * coeff, axis=-1)
 
 
@@ -112,6 +127,22 @@ def _dense(pointwise):
     def fn(X, y, w, coeff) -> LossOut:
         loss, multiplier = pointwise(dense_dot(X, coeff), y, w)
         return jnp.sum(loss), dense_grad(X, multiplier), jnp.sum(w)
+
+    return fn
+
+
+def _dense_product(pointwise):
+    """Dense batched loss whose contractions are float32 products at
+    `Precision.HIGHEST`: a matvec each for one coefficient, and under the
+    fleet's member `vmap`, whose coefficient carries the member axis, ONE
+    [B, d] x [d, N] product for the N members' row-dots and ONE [N, B] x
+    [B, d] for their gradients (`product_variant`)."""
+
+    def fn(X, y, w, coeff) -> LossOut:
+        dot = jnp.dot(X, coeff, precision=lax.Precision.HIGHEST)
+        loss, multiplier = pointwise(dot, y, w)
+        grad = jnp.dot(multiplier, X, precision=lax.Precision.HIGHEST)
+        return jnp.sum(loss), grad, jnp.sum(w)
 
     return fn
 
@@ -154,6 +185,13 @@ HINGE_LOSS = LossFunc("hinge", _dense(_hinge_pointwise), _hinge_pointwise)
 LEAST_SQUARE_LOSS = LossFunc(
     "least_square", _dense(_least_square_pointwise), _least_square_pointwise
 )
+
+#: dense loss name -> its matrix-product form, a DISTINCT LossFunc object
+#: (the loss is a jit static argument), handed to the fleet programs alone.
+PRODUCT_VARIANTS = {
+    loss.name: LossFunc(loss.name + "_product", _dense_product(loss.pointwise), loss.pointwise)
+    for loss in (BINARY_LOGISTIC_LOSS, HINGE_LOSS, LEAST_SQUARE_LOSS)
+}
 
 SPARSE_BINARY_LOGISTIC_LOSS = LossFunc(
     "sparse_binary_logistic", _sparse(_logistic_pointwise), _logistic_pointwise, True
@@ -262,6 +300,11 @@ def feature_sharded_variant(loss_func: LossFunc) -> LossFunc:
     LossFunc object per base loss (the loss is a jit static argument), so
     the 2D programs never collide with the 1D executables."""
     return FEATURE_SHARDED_VARIANTS[loss_func.name]
+
+
+def product_variant(loss_func: LossFunc) -> LossFunc:
+    """The matrix-product LossFunc for the dense loss `loss_func`."""
+    return PRODUCT_VARIANTS[loss_func.name]
 
 
 def sparse_variant(name: str) -> LossFunc:

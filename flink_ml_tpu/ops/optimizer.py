@@ -46,7 +46,7 @@ from ..parallel import prefetch as h2d
 from ..utils import metrics
 from ..utils.lazyjit import lazy_jit
 from . import dense_epoch, sparse_epoch
-from .losses import LossFunc
+from .losses import PRODUCT_VARIANTS, LossFunc
 
 
 @partial(jax.tree_util.register_dataclass, data_fields=("strips",), meta_fields=("width",))
@@ -312,6 +312,29 @@ def _can_train_in_place(X, y, weights, batch, dtype, mesh) -> bool:
         and X.shape[0] % batch == 0
         and X.devices() == set(mesh.devices.flat)
         and all(isinstance(c, jax.Array) and c.ndim == 1 and c.dtype == dtype for c in columns)
+    )
+
+
+def _fleet_multiplies(X_b, loss_func) -> bool:
+    """Whether a fleet's epochs form their members' row-dots and gradients as
+    two float32 matrix products at `Precision.HIGHEST`
+    (`losses.product_variant`: under the member `vmap` ONE [B, d] x [d, N]
+    and ONE [N, B] x [B, d] on the TPU's matrix unit) in place of the reduce
+    form's two vector-unit reductions, which took 94% of a 100-member
+    fleet's epoch (PERF.md §5, PR 39). They do where the table, as the
+    fleet's programs are handed it (in place, laid out, in strips or a
+    stream's stacked segments), is a dense float32 array on a TPU, for a
+    dense loss. All read off the array and the loss, nothing a user sets;
+    the one place that decides, counted as `fleet.product.matrix` or
+    `fleet.product.reduce` a fleet fit. What it turns away keeps `dense_dot`
+    and `dense_grad`: so every fleet on the CPU, whose members tier-1 holds
+    to their solo fits bit for bit. The solo programs never ask."""
+    X = X_b.rows if isinstance(X_b, FlatBatches) else X_b.strips if isinstance(X_b, BatchStrips) else X_b
+    return (
+        loss_func.name in PRODUCT_VARIANTS
+        and isinstance(X, jax.Array)
+        and X.dtype == jnp.float32
+        and mesh_lib.on_tpu(X)
     )
 
 
@@ -862,7 +885,11 @@ _sgd_stream_whole_fit = lazy_jit(
 # batching rule runs the loop until every member's condition is false and
 # select-freezes finished members' carries — exactly the per-member
 # convergence-mask contract, and (pinned by tests/test_fleet.py) each
-# member's result is bit-identical to its solo fit on the same mesh.
+# member's result is bit-identical to its solo fit on the same mesh, on the
+# CPU. On a TPU the fleet hands these programs the loss's matrix-product
+# form (`_fleet_multiplies`): the members' row-dots and gradients are then
+# one float32 product each an epoch, and a member agrees with its solo fit
+# to rounding.
 #
 # `lax.optimization_barrier` has NO batching rule, so the final-update
 # barrier of `_sgd_whole_fit_impl` must be applied OUTSIDE the vmap, on
@@ -2108,8 +2135,9 @@ class SGD:
         shape, 80 MB each for the 20M rows whose table is 8.3 GB (y a
         reshape; absent weights the ones `_default_weights` makes, as on the
         laid-out route). No `fit.layout` and no `layout.*` tick: no table is
-        laid out. The batches are read by the reduce form, as laid-out ones
-        are."""
+        laid out. The `dense_epoch.reduce` tick says, as for laid-out
+        batches, that the one-read kernel is not taken; which form the
+        fleet's epochs take is `fleet.product.*` (`_fleet_multiplies`)."""
         n, B = int(X.shape[0]), int(self.global_batch_size)
         num_batches = n // B
         row_sharding = NamedSharding(mesh, P(None, mesh_lib.DATA_AXIS))

@@ -741,7 +741,7 @@ def test_compiled_for_a_v5e_the_look_is_one_pass_with_no_temporary(four_v5e):
     assert look.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-def compiled_fleet(mesh, members, in_place):
+def compiled_fleet(mesh, members, in_place, loss=losses.BINARY_LOGISTIC_LOSS):
     """`_sgd_fleet_whole_fit_impl` for one v5e chip over the path cell's
     table, 20M x 100 in batches of 100,000: the caller's table viewed where it
     lies (`FlatBatches`), or laid out first."""
@@ -756,7 +756,7 @@ def compiled_fleet(mesh, members, in_place):
     def fit(X, y_b, w_b, carry, criteria, hyper):
         X_b = optimizer.FlatBatches(X, CELL_BATCH) if in_place else X
         return optimizer._sgd_fleet_whole_fit_impl(
-            X_b, y_b, w_b, carry, criteria, losses.BINARY_LOGISTIC_LOSS, hyper, True, None
+            X_b, y_b, w_b, carry, criteria, loss, hyper, True, None
         )
 
     carry = (on_chip((members, width)), on_chip((members, width)), on_chip((members,)), on_chip((members,), np.int32))
@@ -783,3 +783,21 @@ def test_compiled_for_a_v5e_a_fleet_of_a_hundred_reads_one_batch_an_epoch_and_co
     memory = fleet.memory_analysis()
     assert memory.argument_size_in_bytes >= 20_000_000 * 100 * 4
     assert memory.temp_size_in_bytes < 256 << 20  # a batch is 42 MB as the device keeps it, the products 41
+
+
+@pytest.mark.parametrize("in_place", [True, False], ids=["in_place", "laid_out"])
+def test_compiled_for_a_v5e_the_fleets_matrix_form_is_two_products_on_the_batch_where_it_lies(four_v5e, in_place):
+    """What a fleet on the chip is handed (`losses.product_variant`,
+    PR 40): the members' row-dots and gradients are two `convolution`s at
+    HIGHEST, the batch's slice fused into their operands, with no copy or
+    transpose of the table or of a batch for a transposed operand, and no
+    product of the members with the batch ([100, 100000, 100]) formed."""
+    fleet = compiled_fleet(four_v5e, 100, in_place, losses.product_variant(losses.BINARY_LOGISTIC_LOSS))
+    text = fleet.as_text()
+    products = re.findall(r"= f32\[(\d+,\d+)\]\S* convolution\(.*operand_precision=\{highest,highest\}", text)
+    assert sorted(products) == ["100,100", "100,100000"]
+    assert " gather(" not in text and "f32[100,100000,100]" not in text
+    assert not re.search(r"= f32\[(20000000,100|200,100000,100|100000,100)\]\S* (copy|transpose)\(", text)
+    memory = fleet.memory_analysis()
+    assert memory.argument_size_in_bytes >= 20_000_000 * 100 * 4
+    assert memory.temp_size_in_bytes < 256 << 20
