@@ -21,15 +21,16 @@ are vmapped over a leading fleet axis (`ops.optimizer._sgd_fleet_*`,
   each member's own counter is a gather of N copies of it);
 - readback is ONE packed [N, result_pack] array.
 
-In place or laid out: on ONE device, over a dense device table of the
-engine's dtype in whole batches, the programs read the caller's table
-where it lies (`SGD._in_place`, an `optimizer.FlatBatches` view;
-`optimizer._can_train_in_place` decides from the arrays and the mesh, and
-the fit counts `fleet.in_place`), as `SGD._stage_flat` trains a solo fit:
-a laid-out copy of a table that fills half a chip has no room beside it.
-Several data shards, the fleet-sharded regime, a sparse, host or ragged
-table and a checkpointed fleet lay their table out as before
-(`SGD._batchify`); a `StreamTable` keeps its stacked segments.
+In place or laid out: on ONE device, over a device table in whole
+batches, dense of the engine's dtype or a padded-CSR pair, the programs read
+the caller's table where it lies (`SGD._in_place`, an
+`optimizer.FlatBatches` view of each array; `optimizer._can_train_in_place`
+decides from the arrays and the mesh, and the fit counts `fleet.in_place`),
+as `SGD._stage_flat` trains a solo fit: a laid-out copy of a table that
+fills half a chip has no room beside it. Several data shards, the
+fleet-sharded regime, a host or ragged table and a checkpointed fleet lay
+their table out as before (`SGD._batchify`); a `StreamTable` keeps its
+stacked segments.
 
 Sharding over the fleet axis: when N x per-member state crosses
 `config.fleet_shard_state_bytes` (and N divides the data shards), the
@@ -57,7 +58,13 @@ there (a 32-member fleet within 2.2e-7 of its solo fits, PR 21,
 `bench.bench_fleet_sweep`, which asserts 1e-5 and reports
 `bitIdenticalToSolo` as measured). The benchmark's cell
 `lr-regpath-100.path` holds every member of a 100-member path to the
-plain reference's solo fits (PERF.md §4).
+plain reference's solo fits (PERF.md §4). Over a padded-CSR float32 table
+on a TPU the whole-fit route of one fleet takes the member-row form instead
+(`ops.optimizer._fleet_rows`, `fleet.product.rows`): a program of
+its own, `_sgd_fleet_rows_whole_fit`, holds the members' coefficients [d, N]
+and gathers and segment-sums an entry's N of them as ONE row, over the
+column plan made once a fleet fit (`ops.sparse_epoch.plan_fit`), as the
+cell `lr-regpath-criteo-1m.resident-path` runs it.
 
 A fleet fit is ONE fit to the observability layer (`fit.total`,
 `fit.outer`, the `stage.fit` span), and a fleet of linear members over an
@@ -112,18 +119,24 @@ def _loss_by_name(name: str):
     }[name]
 
 
-def _product_form(X_b, loss_func):
+def _product_form(X_b, loss_func, rows: bool = False):
     """The loss the fleet's programs take for the table as they are handed
     it: the matrix-product form where `optimizer._fleet_multiplies` says so
-    (a dense float32 table on a TPU), else `loss_func` as it is; one tick a
-    fleet fit, `fleet.product.matrix` or `fleet.product.reduce`."""
-    from .ops.losses import product_variant
-    from .ops.optimizer import _fleet_multiplies
+    (a dense float32 table on a TPU), the member-row form where `rows` (the
+    whole-fit route of one fleet, which has a program for it) and
+    `optimizer._fleet_rows` say so (a padded-CSR float32 table on a TPU),
+    else `loss_func` as it is; one tick a fleet fit, `fleet.product.matrix`,
+    `fleet.product.rows` or `fleet.product.reduce`."""
+    from .ops.losses import product_variant, rows_variant
+    from .ops.optimizer import _fleet_multiplies, _fleet_rows
     from .utils import metrics
 
     if _fleet_multiplies(X_b, loss_func):
         metrics.inc_counter("fleet.product.matrix")
         return product_variant(loss_func)
+    if rows and _fleet_rows(X_b, loss_func):
+        metrics.inc_counter("fleet.product.rows")
+        return rows_variant(loss_func)
     metrics.inc_counter("fleet.product.reduce")
     return loss_func
 
@@ -268,7 +281,10 @@ class FitFleet:
 
     def fit(self, table) -> List:
         """Train every member on `table`; returns the N fitted models (same
-        order as the estimators)."""
+        order as the estimators). A linear member's coefficient is a row of
+        the fit's one float32 readback, as a solo fit's is a view of its own:
+        a model kept alone keeps that readback, which `np.array` of its
+        coefficient lets go."""
         from .obs import memledger
         from .utils import metrics
 
@@ -293,7 +309,8 @@ class FitFleet:
         from . import config
         from .models import _linear
         from .utils import metrics
-        from .ops.losses import sparse_variant
+        from .ops import sparse_epoch
+        from .ops.losses import ROW_VARIANTS, sparse_variant
         from .ops.optimizer import SGD, _can_train_in_place
         from .table import StreamTable
 
@@ -340,12 +357,20 @@ class FitFleet:
                 X_b, y_b, w_b = template._in_place(mesh, X, y, w)
             else:
                 X_b, y_b, w_b = template._batchify(mesh, X, y, w, replicate_data=sharded)
-            loss_func = _product_form(X_b, loss_func)
-            carry, crit = self._stage_fleet_state(mesh, len(ests), d, sharded)
+            whole = not sharded and config.iteration_checkpoint_dir is None
+            loss_func = _product_form(X_b, loss_func, rows=whole)
+            if loss_func in ROW_VARIANTS.values():
+                # the row program makes its member-minor state itself; the
+                # plan is the fleet's, once a fit, as a solo fit's is
+                carry = crit = None
+                plan = sparse_epoch.plan_fit(X, loss_func, mesh, gbs)
+            else:
+                carry, crit = self._stage_fleet_state(mesh, len(ests), d, sharded)
+                plan = (None, None)
 
         flags, coeffs, crits, epochs = self._run_fleet_sgd(
             mesh, X_b, y_b, w_b, carry, crit, loss_func, hyper, gmax, d,
-            validate_on_device, sharded, gbs,
+            validate_on_device, sharded, gbs, plan,
         )
         if flags is not None:
             _linear._raise_if_invalid(float(np.min(flags)))
@@ -359,7 +384,11 @@ class FitFleet:
         models = []
         for i, est in enumerate(ests):
             model = _linear_model_for(est)
-            model.coefficient = np.asarray(coeffs[i], np.float64)
+            # a row of the fit's one readback, as a solo fit's coefficient is
+            # a view of its own: a copy a member is 400 MB of fresh host
+            # memory a fit of 100 members of a million coefficients (0.4 s on
+            # a v5e's host; 0.9 s as float64, PERF.md section 6)
+            model.coefficient = coeffs[i]
             models.append(model)
         return models
 
@@ -395,12 +424,14 @@ class FitFleet:
 
     def _run_fleet_sgd(
         self, mesh, X_b, y_b, w_b, carry, crit, loss_func, hyper, gmax, d,
-        check_labels, sharded, gbs,
+        check_labels, sharded, gbs, plan=(None, None),
     ):
         """The fleet SGD loop: ONE whole-fit dispatch + ONE packed readback
         when no checkpoint boundary lands mid-fit, else the chunked path
-        with fleet-axis-sharded snapshot cuts. Returns host
-        (flags|None, coeffs [N, d], criteria [N], epochs [N])."""
+        with fleet-axis-sharded snapshot cuts. Without a `carry` the member-row
+        program (`_fleet_rows`) runs, over the fleet's column `plan` (widths,
+        dictionaries). Returns host (flags|None, coeffs [N, d], criteria [N],
+        epochs [N])."""
         from . import config
         from .ckpt import faults
         from .ckpt import snapshot as _snapshot
@@ -411,6 +442,20 @@ class FitFleet:
         n = len(self.estimators)
         pack_sharding = self._pack_sharding(mesh)
         hyper_dev = jnp.asarray(hyper)
+        if carry is None:
+            if dispatch.whole_fit_enabled():
+                dispatch.account_whole_fit("fleet")
+            with tracing.span("iteration.run", mode="fleet_rows", epochs=gmax, fleet=n):
+                packed = dispatch.timed_dispatch(
+                    opt._sgd_fleet_rows_whole_fit,
+                    X_b, y_b, w_b, loss_func, hyper_dev, d, check_labels, pack_sharding, *plan,
+                    start=0, end=gmax,
+                )
+                flags, coeffs, crits, epochs = opt.unpack_fleet_train_result(
+                    opt._read_packed(packed), d, check_labels
+                )
+                faults.tick("chunk")  # the whole fleet fit is one chunk
+            return flags, coeffs, crits, epochs
         ckpt_dir = config.iteration_checkpoint_dir
         planned = 0
 
@@ -703,13 +748,15 @@ tracing.instrument_stage_methods(FitFleet)
 
 def fleet_model_arrays(model) -> Tuple:
     """The swap-protocol array tuple for a fleet-trained model — the same
-    leaves the model's `model_arrays()` would publish."""
+    leaves the model's `model_arrays()` would publish, copied: a linear
+    member's coefficient is a row of its fleet's readback, which a published
+    version should not keep."""
     if hasattr(model, "centroids"):
         return (
-            np.asarray(model.centroids, np.float32),
-            np.asarray(model.weights, np.float32),
+            np.array(model.centroids, np.float32),
+            np.array(model.weights, np.float32),
         )
-    return (np.asarray(model.coefficient, np.float32),)
+    return (np.array(model.coefficient, np.float32),)
 
 
 def promote_fleet_winner(lifecycle, models: Sequence, scores: Sequence[float], mode: str = "max"):

@@ -46,6 +46,16 @@ the fleet's bit-parity contract lives and a second read of X costs nothing
 like it does on the chip, and the solo general form's: laid-out batches on
 several shards (GSPMD; that program reads its batch once already), sparse
 rows, a wide or 16-bit table, the overlap schedule (parallel/overlap.py).
+
+A fleet over sparse rows on a TPU is handed `rows_variant(loss)` instead
+(`optimizer._fleet_rows`, counted `fleet.product.rows`): the members'
+coefficients held member-minor, [d, N], so that an entry's N coefficients
+are ONE gathered row and its N gradients ONE row segment-summed
+(`sparse_rows_dot`, `_sparse_rows`), where `_sparse` under the member `vmap`
+gathers and scatters N single values an entry; on the TPU both cost by the
+entry. Its program is written out for the member axis
+(`optimizer._sgd_fleet_rows_whole_fit_impl`), and its members agree with
+their solo fits to rounding.
 """
 
 from __future__ import annotations
@@ -210,6 +220,44 @@ SPARSE_VARIANTS = {
 }
 
 
+def sparse_rows_dot(indices, values, coeff):
+    """`sparse_dot` for a member-minor coefficient [d, N]: every entry's N
+    members' coefficients are ONE gathered row, and the row-dots come out
+    [B, N]. Returns (dot, safe, vals) as `sparse_dot` does."""
+    valid = indices >= 0
+    safe = jnp.where(valid, indices, 0)
+    vals = jnp.where(valid, values, 0.0).astype(coeff.dtype)
+    return jnp.sum(vals[:, :, None] * coeff[safe], axis=1), safe, vals
+
+
+def _sparse_rows(pointwise):
+    """Padded-CSR batched loss of N members at once, their coefficients
+    held member-minor, [d, N] (the fleet's row form, `rows_variant`): an
+    entry's row-dots are ONE gather of an N-wide row and its gradients ONE
+    N-wide row segment-summed into [d, N], where `_sparse` under the member
+    `vmap` gathers and scatters N single values an entry. Returns
+    (loss_sum [N], grad_sum [d, N], weight_sum)."""
+
+    def fn(X, y, w, coeff) -> LossOut:
+        indices, values = X
+        dot, safe, vals = sparse_rows_dot(indices, values, coeff)
+        loss, multiplier = pointwise(dot, y[:, None], w[:, None])
+        grad = jnp.zeros_like(coeff).at[safe].add(
+            vals[:, :, None] * multiplier[:, None, :], mode="drop"
+        )
+        return jnp.sum(loss, axis=0), grad, jnp.sum(w)
+
+    return fn
+
+
+#: sparse loss name -> its member-row form, a DISTINCT LossFunc object (the
+#: loss is a jit static argument), handed to the fleet's row program alone.
+ROW_VARIANTS = {
+    loss.name: LossFunc(loss.name + "_rows", _sparse_rows(loss.pointwise), loss.pointwise, True)
+    for loss in SPARSE_VARIANTS.values()
+}
+
+
 def _feature_sharded(pointwise):
     """Padded-CSR batched loss for the explicit 2D `(data, model)` mesh
     (parallel/overlap.py `sgd2d_*`): runs INSIDE a shard_map body where
@@ -305,6 +353,11 @@ def feature_sharded_variant(loss_func: LossFunc) -> LossFunc:
 def product_variant(loss_func: LossFunc) -> LossFunc:
     """The matrix-product LossFunc for the dense loss `loss_func`."""
     return PRODUCT_VARIANTS[loss_func.name]
+
+
+def rows_variant(loss_func: LossFunc) -> LossFunc:
+    """The member-row LossFunc for the sparse loss `loss_func`."""
+    return ROW_VARIANTS[loss_func.name]
 
 
 def sparse_variant(name: str) -> LossFunc:

@@ -46,7 +46,7 @@ from ..parallel import prefetch as h2d
 from ..utils import metrics
 from ..utils.lazyjit import lazy_jit
 from . import dense_epoch, sparse_epoch
-from .losses import PRODUCT_VARIANTS, LossFunc
+from .losses import PRODUCT_VARIANTS, ROW_VARIANTS, LossFunc
 
 
 @partial(jax.tree_util.register_dataclass, data_fields=("strips",), meta_fields=("width",))
@@ -293,26 +293,38 @@ def _can_train_in_place(X, y, weights, batch, dtype, mesh) -> bool:
     lies (`SGD._in_place`, a `FlatBatches` view) and lay nothing out. They do
     where `_stage_flat` would train a solo fit without a copy: ONE device
     (a mesh of it alone, and the table on it), a dense table of the
-    engine's dtype (a cast is a copy of the table) whose rows are a whole
-    number of batches (ragged rows keep the padded copy), with y, and a
-    weight column where there is one, device columns of that dtype. All read
-    off shapes, dtypes, the array's devices and the mesh, nothing a user
-    sets; and the one place that decides, counted as `fleet.in_place` a
+    engine's dtype (a cast is a copy of the table), or a padded-CSR pair of
+    int32 ids and values of that dtype (`FlatBatches` of each), whose rows
+    are a whole number of batches (ragged rows keep the padded copy), with
+    y, and a weight column where there is one, device columns of that dtype.
+    All read off shapes, dtypes, the arrays' devices and the mesh, nothing a
+    user sets; and the one place that decides, counted as `fleet.in_place` a
     fleet fit. What it turns away keeps the laid-out route as it was:
     several data shards, the fleet-sharded regime (which needs several), a
-    sparse or a host table. `FitFleet` never asks for a stream or under a
-    checkpoint directory."""
+    host table. `FitFleet` never asks for a stream or under a checkpoint
+    directory."""
     columns = [y] if weights is None else [y, weights]
+    leaves, dtypes = (X, (jnp.int32, dtype)) if isinstance(X, tuple) else ((X,), (dtype,))
+    if not (
+        len(leaves) == len(dtypes)
+        and all(isinstance(a, jax.Array) and a.ndim == 2 and a.dtype == t for a, t in zip(leaves, dtypes))
+        and len({a.shape for a in leaves}) == 1
+    ):
+        return False
+    n = leaves[0].shape[0]
     return (
         mesh.devices.size == 1
-        and isinstance(X, jax.Array)
-        and X.ndim == 2
-        and X.dtype == dtype
-        and X.shape[0] > 0
-        and X.shape[0] % batch == 0
-        and X.devices() == set(mesh.devices.flat)
+        and n > 0
+        and n % batch == 0
+        and all(a.devices() == set(mesh.devices.flat) for a in leaves)
         and all(isinstance(c, jax.Array) and c.ndim == 1 and c.dtype == dtype for c in columns)
     )
+
+
+def _viewed(X_b):
+    """The array behind a table as the fleet's programs are handed it: a
+    `FlatBatches` view's rows, `BatchStrips`' strips, or the array itself."""
+    return X_b.rows if isinstance(X_b, FlatBatches) else X_b.strips if isinstance(X_b, BatchStrips) else X_b
 
 
 def _fleet_multiplies(X_b, loss_func) -> bool:
@@ -329,12 +341,39 @@ def _fleet_multiplies(X_b, loss_func) -> bool:
     `fleet.product.reduce` a fleet fit. What it turns away keeps `dense_dot`
     and `dense_grad`: so every fleet on the CPU, whose members tier-1 holds
     to their solo fits bit for bit. The solo programs never ask."""
-    X = X_b.rows if isinstance(X_b, FlatBatches) else X_b.strips if isinstance(X_b, BatchStrips) else X_b
+    X = _viewed(X_b)
     return (
         loss_func.name in PRODUCT_VARIANTS
         and isinstance(X, jax.Array)
         and X.dtype == jnp.float32
         and mesh_lib.on_tpu(X)
+    )
+
+
+def _fleet_rows(X_b, loss_func) -> bool:
+    """Whether a fleet's epochs take the member-row form
+    (`_sgd_fleet_rows_whole_fit`, `losses.rows_variant`,
+    `sparse_epoch.planned_rows_loss`): the coefficients held [d, N], an
+    entry's N coefficients ONE gathered row and its N gradients ONE row
+    segment-summed, where `_sparse` under the member `vmap` gathers and
+    scatters N single values an entry out of and into [N, d]. They do where
+    the table, as the fleet's programs are handed it (in place, laid out or
+    in strips), is a padded-CSR pair whose values are float32 on a TPU, for a sparse
+    loss: `_fleet_multiplies`' place, for the table it turns away. All read
+    off the arrays and the loss, nothing a user sets; `FitFleet` asks for its
+    whole-fit route of one fleet alone (not under a checkpoint directory, not
+    fleet-sharded) and counts the answer as `fleet.product.rows` or
+    `fleet.product.reduce`. What it turns away keeps `_sparse`: so every
+    fleet on the CPU, whose members tier-1 holds to their solo fits bit for
+    bit."""
+    if not (isinstance(X_b, tuple) and len(X_b) == 2):
+        return False
+    values = _viewed(X_b[1])
+    return (
+        loss_func.name in ROW_VARIANTS
+        and isinstance(values, jax.Array)
+        and values.dtype == jnp.float32
+        and mesh_lib.on_tpu(values)
     )
 
 
@@ -1057,6 +1096,81 @@ def _sgd_fleet_stream_whole_fit_impl(
 _sgd_fleet_stream_whole_fit = lazy_jit(
     _sgd_fleet_stream_whole_fit_impl,
     static_argnames=("loss_func", "d", "pack_sharding"),
+)
+
+
+def _update_rows(coeff, grad, wsum, lr, reg, elastic_net):
+    """`_update_model` of N members at once, their coefficients member-minor
+    [d, N] and their numbers [N] along its last axis: the step, then
+    `regularize`, for the members whose last batch had weight."""
+    stepped, _ = regularize(coeff - (lr / jnp.maximum(wsum, 1e-30)) * grad, reg, elastic_net, lr)
+    return jnp.where(wsum > 0, stepped, coeff)
+
+
+def _sgd_fleet_rows_whole_fit_impl(
+    X_b, y_b, w_b, loss_func, hyper, d, check_labels, pack_sharding, plan=None, dictionaries=None
+):
+    """N ENTIRE fits of a padded-CSR table as ONE resident program in the
+    member-row form (`_fleet_rows`): the members' coefficients and
+    gradients are held member-minor, [d, N], from zeros made here, so that
+    an entry's N coefficients are ONE row (`losses.rows_variant`; with
+    `plan`, `sparse_epoch.planned_rows_loss` over the fleet's
+    `dictionaries`). The schedule is `_sgd_fleet_whole_fit_impl`'s written
+    out for the member axis: every member's own maxIter and tol, the batch
+    found once at the fleet's furthest epoch, a stopped member's state kept
+    by a select, the barrier-pinned final update, and the same packed [N,
+    flag? + d + 2] result. Returns the pack."""
+    dtype = _feature_dtype(X_b)
+    n = hyper.shape[0]
+    max_iter, tol = hyper[:, 0].astype(jnp.int32), hyper[:, 1]
+    lr, reg, elastic_net = (hyper[:, i].astype(dtype) for i in (2, 3, 4))
+    num_batches = y_b.shape[0]
+    loss = loss_func
+    if plan is not None:
+        loss = partial(sparse_epoch.planned_rows_loss(loss_func, plan), dictionaries=dictionaries)
+
+    def running(state):
+        (_, _, _, epochs), criteria = state
+        return jnp.logical_and(epochs < max_iter, criteria > tol)
+
+    def body(state):
+        (coeff, grad, wsum, epochs), criteria = state
+        on = running(state)
+        k = jnp.mod(jnp.max(epochs), num_batches)
+        Xk = _index_batch(X_b, k)
+        yk = lax.dynamic_index_in_dim(y_b, k, axis=0, keepdims=False)
+        wk = lax.dynamic_index_in_dim(w_b, k, axis=0, keepdims=False)
+        stepped = _update_rows(coeff, grad, wsum, lr, reg, elastic_net)
+        lsum, new_grad, new_wsum = loss(Xk, yk, wk, stepped)
+        new_criteria = jnp.asarray(lsum / jnp.maximum(new_wsum, 1e-30), jnp.float32)
+        carry = (
+            jnp.where(on, stepped, coeff),
+            jnp.where(on, new_grad, grad),
+            jnp.where(on, new_wsum, wsum),
+            jnp.where(on, epochs + 1, epochs),
+        )
+        return carry, jnp.where(on, new_criteria, criteria)
+
+    start = (
+        (jnp.zeros((d, n), dtype), jnp.zeros((d, n), dtype), jnp.zeros((n,), dtype), jnp.zeros((n,), jnp.int32)),
+        jnp.full((n,), jnp.inf, jnp.float32),
+    )
+    carry, criteria = lax.while_loop(lambda state: jnp.any(running(state)), body, start)
+    coeff, grad, wsum, epochs = lax.optimization_barrier(carry)
+    final = _update_rows(coeff, grad, wsum, lr, reg, elastic_net)
+    dt = jnp.promote_types(dtype, jnp.float32)
+    parts = [final.T.astype(dt), criteria[:, None].astype(dt), epochs[:, None].astype(dt)]
+    if check_labels:
+        parts.insert(0, jnp.broadcast_to(_binomial_labels_ok(y_b).astype(dt), (n, 1)))
+    packed = jnp.concatenate(parts, axis=1)
+    if pack_sharding is not None:
+        packed = lax.with_sharding_constraint(packed, pack_sharding)
+    return packed
+
+
+_sgd_fleet_rows_whole_fit = lazy_jit(
+    _sgd_fleet_rows_whole_fit_impl,
+    static_argnames=("loss_func", "d", "check_labels", "pack_sharding", "plan"),
 )
 
 
@@ -2130,15 +2244,17 @@ class SGD:
 
     def _in_place(self, mesh: Mesh, X, y, weights):
         """`_batchify`'s three for a table `_can_train_in_place` admits, the
-        table not touched: X is a `FlatBatches` view of the caller's array,
-        and only the columns take the general form's (num_batches, batch)
-        shape, 80 MB each for the 20M rows whose table is 8.3 GB (y a
-        reshape; absent weights the ones `_default_weights` makes, as on the
-        laid-out route). No `fit.layout` and no `layout.*` tick: no table is
-        laid out. The `dense_epoch.reduce` tick says, as for laid-out
-        batches, that the one-read kernel is not taken; which form the
-        fleet's epochs take is `fleet.product.*` (`_fleet_multiplies`)."""
-        n, B = int(X.shape[0]), int(self.global_batch_size)
+        table not touched: X is a `FlatBatches` view of the caller's array
+        (of each of a padded-CSR pair's), and only the columns take the
+        general form's (num_batches, batch) shape, 80 MB each for the 20M
+        rows whose table is 8.3 GB (y a reshape; absent weights the ones
+        `_default_weights` makes, as on the laid-out route). No `fit.layout`
+        and no `layout.*` tick: no table is laid out. For a dense table the
+        `dense_epoch.reduce` tick says, as for laid-out batches, that the
+        one-read kernel is not taken; which form the fleet's epochs take is
+        `fleet.product.*` (`_fleet_multiplies`, `_fleet_rows`)."""
+        sparse = isinstance(X, tuple)
+        n, B = int((X[0] if sparse else X).shape[0]), int(self.global_batch_size)
         num_batches = n // B
         row_sharding = NamedSharding(mesh, P(None, mesh_lib.DATA_AXIS))
         batches = (n, num_batches, B, B)  # no row and no batch is padded
@@ -2147,12 +2263,15 @@ class SGD:
             w_b = _default_weights(*batches, self.dtype, row_sharding)
         else:
             w_b = _layout_batches(weights, *batches, None, row_sharding)
-        metrics.inc_counter("dense_epoch.reduce")
+        if not sparse:
+            metrics.inc_counter("dense_epoch.reduce")
         # the table is the caller's and stays where it is: ledgered as the
         # fit's training-data residency, like the flat route's
         from ..obs import memledger
 
         memledger.track((X, y_b, w_b), "streamSegments")
+        if sparse:
+            return tuple(FlatBatches(leaf, B) for leaf in X), y_b, w_b
         return FlatBatches(X, B), y_b, w_b
 
     def _batchify(self, mesh: Mesh, X, y, weights, d_pad=None, replicate_data=False):

@@ -57,7 +57,7 @@ from ..obs import tracing
 from ..parallel import mesh as mesh_lib
 from ..utils import metrics
 from ..utils.lazyjit import lazy_jit
-from .losses import LossFunc, sparse_dot
+from .losses import LossFunc, sparse_dot, sparse_rows_dot
 
 # ids of a dictionary the confirming pass compares a column with at a time,
 # and the narrowest dictionary: a tile's lanes
@@ -190,6 +190,25 @@ def _columns(arr, columns):
     return runs[0] if len(runs) == 1 else jnp.concatenate(runs, axis=1)
 
 
+def _blocks(widths: Tuple[int, ...], apart=()):
+    """(the gathered columns, (width, the columns of that width) for every
+    other width but those kept `apart`, narrowest first)."""
+    gathered = tuple(j for j, width in enumerate(widths) if width == GATHER)
+    blocks = tuple(
+        (width, tuple(j for j, other in enumerate(widths) if other == width))
+        for width in sorted(set(widths) - {GATHER, *apart})
+    )
+    return gathered, blocks
+
+
+def _slots(dictionaries, blocks):
+    """Every dictionary's ids, block by block [columns, width], and all of
+    them in one row, where their coefficients are gathered and their sums
+    scatter-added."""
+    ids_known = [jnp.stack([dictionaries[j, :width] for j in columns]) for width, columns in blocks]
+    return ids_known, jnp.concatenate([block.reshape(-1) for block in ids_known])
+
+
 def planned_loss(loss_func: LossFunc, widths: Tuple[int, ...]):
     """`loss_func` over a table planned as `widths`:
     fn(X, y, w, coeff, dictionaries) -> (loss_sum, grad_sum, weight_sum).
@@ -197,18 +216,12 @@ def planned_loss(loss_func: LossFunc, widths: Tuple[int, ...]):
     against one block of dictionaries: an op over 100,000 rows costs the chip
     ~0.1 ms whatever it does, and a column of its own would pay that twice."""
     pointwise = loss_func.pointwise
-    gathered = tuple(j for j, width in enumerate(widths) if width == GATHER)
-    # (width, the columns of that width), narrowest first
-    blocks = tuple(
-        (width, tuple(j for j, other in enumerate(widths) if other == width))
-        for width in sorted(set(widths) - {GATHER})
-    )
+    gathered, blocks = _blocks(widths)
 
     def fn(X, y, w, coeff, dictionaries):
         indices, values = X
-        # every dictionary's ids, block by block, and their coefficients in one small gather
-        ids_known = [jnp.stack([dictionaries[j, :width] for j in columns]) for width, columns in blocks]
-        slots = jnp.concatenate([block.reshape(-1) for block in ids_known])
+        # every dictionary's ids and their coefficients in one small gather
+        ids_known, slots = _slots(dictionaries, blocks)
         known = coeff[jnp.minimum(slots, coeff.shape[0] - 1)]
         dot = jnp.zeros(indices.shape[:1], coeff.dtype)
         taken, offset = [], 0
@@ -242,5 +255,75 @@ def planned_loss(loss_func: LossFunc, widths: Tuple[int, ...]):
         ]
         grad = grad.at[slots].add(jnp.concatenate(sums), mode="drop")
         return jnp.sum(loss), grad, jnp.sum(w)
+
+    return fn
+
+
+def planned_rows_loss(loss_func: LossFunc, widths: Tuple[int, ...]):
+    """`planned_loss` for N members at once, their coefficients held
+    member-minor [d, N] (`losses.rows_variant`'s form, the fleet's row
+    program): fn(X, y, w, coeff, dictionaries) -> (loss_sum [N], grad_sum
+    [d, N], weight_sum). The plan is the fleet's, made once a fit, and every
+    entry is read once for all members:
+
+    - a constant column's part of the row-dots is its values times its id's
+      ONE row of N coefficients, and of the gradient its values against the
+      multipliers, summed over the rows (no gather an entry);
+    - a dictionary column's entry is compared with its dictionary once, as
+      `planned_loss` compares it, for its place in the dictionaries' own
+      rows ([slots, N], gathered once an epoch): the entry's N coefficients
+      are ONE row of that small table, and its gradients ONE row
+      segment-summed into it, scatter-added at the dictionaries' ids;
+    - a gathered column takes `losses.sparse_rows_dot` and the segment-sum
+      of rows into [d, N]."""
+    pointwise = loss_func.pointwise
+    gathered, blocks = _blocks(widths, apart=(1,))
+    constant = tuple(j for j, width in enumerate(widths) if width == 1)
+
+    def fn(X, y, w, coeff, dictionaries):
+        indices, values = X
+        last = coeff.shape[0] - 1
+        dot = jnp.zeros((indices.shape[0], coeff.shape[1]), coeff.dtype)
+        if constant:
+            constant_ids = dictionaries[jnp.asarray(constant), 0]
+            constant_vals = jnp.where(
+                _columns(indices, constant) >= 0, _columns(values, constant), 0.0
+            ).astype(coeff.dtype)
+            dot = dot + jnp.sum(constant_vals[:, :, None] * coeff[jnp.minimum(constant_ids, last)][None], axis=1)
+        if blocks:
+            ids_known, slots = _slots(dictionaries, blocks)
+            known = coeff[jnp.minimum(slots, last)]  # [slots, N]
+            places, block_vals, offset = [], [], 0
+            for (width, columns), block in zip(blocks, ids_known):
+                ids = _columns(indices, columns)
+                block_vals.append(jnp.where(ids >= 0, _columns(values, columns), 0.0).astype(coeff.dtype))
+                # an entry's place among the slots: the one id of its column's dictionary
+                # it is (the plan holds every row's), 0 for a padding entry, whose value is 0
+                first = offset + jnp.arange(len(columns), dtype=jnp.int32)[:, None] * width
+                same = ids[:, :, None] == block[None]
+                places.append(jnp.sum(jnp.where(same, first + jnp.arange(width, dtype=jnp.int32), 0), axis=2))
+                offset += block.size
+            places, block_vals = jnp.concatenate(places, axis=1), jnp.concatenate(block_vals, axis=1)
+            dot = dot + jnp.sum(block_vals[:, :, None] * known[places], axis=1)
+        if gathered:
+            # as `planned_loss` orders it: the gather's ids wait for the selections
+            dot, wide = lax.optimization_barrier((dot, _columns(indices, gathered)))
+            gather_dot, safe, gather_vals = sparse_rows_dot(wide, _columns(values, gathered), coeff)
+            dot = dot + gather_dot
+        loss, multiplier = pointwise(dot, y[:, None], w[:, None])
+        grad = jnp.zeros_like(coeff)
+        if gathered:
+            grad = grad.at[safe].add(gather_vals[:, :, None] * multiplier[:, None, :], mode="drop")
+        taken = []  # (ids, the gradient's rows at them)
+        if constant:
+            taken.append((constant_ids, jnp.sum(constant_vals[:, :, None] * multiplier[:, None, :], axis=0)))
+        if blocks:
+            sums = jnp.zeros_like(known).at[places].add(block_vals[:, :, None] * multiplier[:, None, :])
+            taken.append((slots, sums))
+        if taken:
+            grad = grad.at[jnp.concatenate([at for at, _ in taken])].add(
+                jnp.concatenate([rows for _, rows in taken]), mode="drop"
+            )
+        return jnp.sum(loss, axis=0), grad, jnp.sum(w)
 
     return fn

@@ -8,7 +8,7 @@ table and lays nothing out.
 2. it agrees with the benchmark's plain reference of a path
    (`perf/reference/lr-regpath-100.py`) on seeded data;
 3. what the view turns away keeps the laid-out route (`fleet.in_place` does
-   not tick): ragged rows, several shards, a host table, a sparse table,
+   not tick): ragged rows (dense or sparse), several shards, a host table,
    another dtype, a `StreamTable`, a checkpointed fleet;
 4. a fleet fit is one fit to the observability layer: the four phases once,
    one `tracing.sync`, one outermost fit;
@@ -169,8 +169,10 @@ def test_the_fleet_agrees_with_the_plain_reference_of_a_path(one_device, seed):
 
 
 def sparse_table():
-    X, y = columns()
-    indices = jnp.tile(jnp.arange(WIDTH, dtype=jnp.int32), (ROWS, 1))
+    """A padded-CSR device table of ragged rows: a sparse table of whole
+    batches trains in place (tests/test_fleet_sparse_rows.py)."""
+    X, y = columns(rows=ROWS - 50)
+    indices = jnp.tile(jnp.arange(WIDTH, dtype=jnp.int32), (ROWS - 50, 1))
     return Table({"features": SparseBatch(WIDTH, indices, X), "label": y})
 
 

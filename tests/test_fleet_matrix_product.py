@@ -15,7 +15,8 @@ wants the form tells `mesh_lib.on_tpu` to say yes.
    `fleet.product.reduce`, and the programs are handed that form's loss: on
    the CPU, `on_tpu` untouched, the reduce form on every route of 2. (the
    bit-parity tests of tests/test_fleet.py and tests/test_fleet_in_place.py
-   stand as they were), and a sparse table keeps it on the chip too;
+   stand as they were), and a sparse table takes no product form on the chip
+   either (its own form is tests/test_fleet_sparse_rows.py's);
 4. the solo programs and the CPU's fleet program lower to the parent's text;
    the TPU form's fleet program holds two `dot_general` at HIGHEST and no
    product of the members with the batch.
@@ -246,11 +247,16 @@ def test_each_fleet_fit_ticks_the_matrix_form_once(one_device, fits, on_the_chip
         assert len(handed) == (9 if checkpoints else 1)
 
 
-def test_a_sparse_table_keeps_the_reduce_form_on_the_chip_too(one_device, fits, on_the_chip):
+def test_a_sparse_table_keeps_the_reduce_form_on_the_chip_too(one_device, fits, on_the_chip, monkeypatch, tmp_path):
+    """No product form for a sparse table. On the whole-fit route of one
+    fleet it takes the member-row form (`fleet.product.rows`,
+    tests/test_fleet_sparse_rows.py); the checkpointed chunks keep the
+    reduce form, as they did."""
+    checkpointed(monkeypatch, tmp_path)
     X, y = columns()
     indices = jnp.tile(jnp.arange(WIDTH, dtype=jnp.int32), (ROWS, 1))
     _, _, ticks, handed = fits(members(), Table({"features": SparseBatch(WIDTH, indices, X), "label": y}))
-    assert ticks == {"matrix": 0, "reduce": 1} and handed == [losses.SPARSE_BINARY_LOGISTIC_LOSS]
+    assert ticks == {"matrix": 0, "reduce": 1} and set(handed) == {losses.SPARSE_BINARY_LOGISTIC_LOSS}
 
 
 def test_the_decision_reads_the_table_and_the_loss(on_the_chip):

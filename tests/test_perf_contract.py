@@ -377,6 +377,45 @@ def test_the_fleet_cells_toy_fit_is_one_fleet_trained_in_place():
     assert not toy_fit(CELLS[cell]["config"], 4)["counters"].get("fleet.in_place")
 
 
+def test_the_sparse_path_cells_toy_fit_is_one_fleet_in_place_in_the_row_form():
+    """`lr-regpath-criteo-1m.resident-path` is a `FitFleet` over a one-device
+    padded-CSR table of whole batches: it copies no table, plans its columns
+    once, takes the member-row form (on the chip's word, as every toy fit
+    here is told), syncs twice (the plan, the result), runs the program the
+    configuration names, and the readers the cell lists say so."""
+    cell = "lr-regpath-criteo-1m.resident-path"
+    fit, config = toy_fit_of_cell(cell), CONFIGS[CELLS[cell]["config"]]
+    counters = fit["counters"]
+    assert config["stage"]["fleet"]["members"] == len(config["stage"]["params"]["reg"]) == 100
+    assert counters["fleet.fits"] == counters["fleet.in_place"] == counters["fleet.product.rows"] == 1
+    assert not counters.get("fleet.product.reduce") and not counters.get("fleet.product.matrix")
+    assert counters["sparse_epoch.planned"] == 1 and counters["sync.plan.n"] == 1
+    assert counters["sparse_epoch.entries"] == BATCH * DIM and 0 < counters["sparse_epoch.entries_gathered"] < BATCH * DIM
+    assert not any(name in counters for name in ("layout.exchange", "layout.general", "fit.layout.n", "dense_epoch.reduce"))
+    assert counters["iteration.host_sync"] == 2 and counters["fit.outer.n"] == counters["fit.total.n"] == 1
+    assert config["train_programs"] == ["jit__sgd_fleet_rows_whole_fit_impl"]
+    assert "jit__sgd_fleet_rows_whole_fit_impl" in fit["lowered"]
+    assert not {"jit__sgd_fleet_whole_fit_impl", "jit__sgd_train_flat", "jit__sgd_train"} & set(fit["lowered"])
+    fit_phases = (ROOT / "docs" / "observability.md").read_text().split("## Fit phases", 1)[1].split("\n## ", 1)[0]
+    assert "`fleet.product.rows`" in fit_phases and "`fleet.in_place`" in fit_phases, FOLLOW
+    run = {"counters": counters, "window": {"attempted": 1}, "trace": None}
+    read = lambda metric: perf_module("metrics", metric).read(run)  # noqa: E731
+    assert read("fleet_row_form_share") == read("fleet_in_place_share") == 100.0
+    assert read("fleet_members_per_fit") == K and read("host_syncs_per_fit") == 2.0
+    assert read("sparse_gather_share") == 100.0 * counters["sparse_epoch.entries_gathered"] / (BATCH * DIM)
+    assert METRICS["fleet_row_form_share"]["workloads"] == [cell]
+    # on several shards the same job lays its table out, in the row form, unplanned
+    laid_out = toy_fit(CELLS[cell]["config"], 4)["counters"]
+    assert not laid_out.get("fleet.in_place") and laid_out["fleet.product.rows"] == laid_out["sparse_epoch.general"] == 1
+
+
+def test_the_sparse_path_counter_counts_the_batch_once_and_every_members_coefficient():
+    counter = perf_module("counters", "fleet_sparse_lr_epoch")
+    config = CONFIGS["lr-regpath-criteo-1m"]
+    epoch = counter.fleet_sparse_lr_epoch(config["data"], config["stage"]["params"])
+    assert epoch == {"bytes": 100_000 * 39 * 8 + 4 * 100_000 + 2 * 1_000_000 * 100 * 4, "flops": 4 * 100_000 * 39 * 100}
+
+
 @pytest.mark.parametrize("phase", documented_phases("Pipeline phases", "pipeline"))
 def test_documented_pipeline_phase_is_emitted_once_a_pipeline_fit(phase):
     assert pipeline_cells()
