@@ -63,8 +63,9 @@ on a TPU the whole-fit route of one fleet takes the member-row form instead
 (`ops.optimizer._fleet_rows`, `fleet.product.rows`): a program of
 its own, `_sgd_fleet_rows_whole_fit`, holds the members' coefficients [d, N]
 and gathers and segment-sums an entry's N of them as ONE row, over the
-column plan made once a fleet fit (`ops.sparse_epoch.plan_fit`), as the
-cell `lr-regpath-criteo-1m.resident-path` runs it.
+column plan made once a fleet fit (`ops.sparse_epoch.plan_fit`) over the
+rows the longest member's epochs reach, as the cell
+`lr-regpath-criteo-1m.resident-path` runs it (2M of its 28M rows).
 
 A fleet fit is ONE fit to the observability layer (`fit.total`,
 `fit.outer`, the `stage.fit` span), and a fleet of linear members over an
@@ -361,9 +362,10 @@ class FitFleet:
             loss_func = _product_form(X_b, loss_func, rows=whole)
             if loss_func in ROW_VARIANTS.values():
                 # the row program makes its member-minor state itself; the
-                # plan is the fleet's, once a fit, as a solo fit's is
+                # plan is the fleet's, once a fit, as a solo fit's is, over
+                # the rows the fleet's furthest epoch reaches
                 carry = crit = None
-                plan = sparse_epoch.plan_fit(X, loss_func, mesh, gbs)
+                plan = sparse_epoch.plan_fit(X, loss_func, mesh, gbs, gmax)
             else:
                 carry, crit = self._stage_fleet_state(mesh, len(ests), d, sharded)
                 plan = (None, None)

@@ -1887,7 +1887,8 @@ class SGD:
         are placed on the mesh's device (a 1-device mesh may deliberately
         pin a fit to a non-default chip); already-device-resident inputs
         stay where they are. A sparse table is planned here, once a fit,
-        where `sparse_epoch.plan_fit` admits it. Returns the launch, a call
+        where `sparse_epoch.plan_fit` admits it, over the rows the fit's
+        `max_iter` epochs reach. Returns the launch, a call
         without arguments that gives the packed result device vector."""
         n = int(np.shape(X[0] if isinstance(X, tuple) else X)[0])
         B = int(self.global_batch_size)
@@ -1939,7 +1940,7 @@ class SGD:
         one_pass = _can_one_pass(X_f, loss_func, mesh)
         plan = dictionaries = None
         if isinstance(X_f, tuple):
-            plan, dictionaries = sparse_epoch.plan_fit(X_f, loss_func, mesh, B)
+            plan, dictionaries = sparse_epoch.plan_fit(X_f, loss_func, mesh, B, self.max_iter)
         else:
             metrics.inc_counter("dense_epoch.one_pass" if one_pass else "dense_epoch.reduce")
         return partial(

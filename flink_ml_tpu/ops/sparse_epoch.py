@@ -21,19 +21,25 @@ field j in column j) does not need them for most of its columns:
 
 The plan is made once a fit, on the device, by one small program
 (`_column_dictionaries`) that counts the distinct ids of every column over
-ALL the table's rows, as cheaply as the column allows: two sorts of the
-first `SAMPLE_ROWS` rows give each column's candidates; one pass over all
-the rows confirms them (every valid entry is one of the candidates); and a
-column that held an id the sample missed (a rare category of a skewed field)
-is sorted in full. The host reads back one count a column to fix the
-program's static shape: each column's class and its dictionary's width,
-rounded up to a power of two so that tables whose counts differ (partitions
-of one log, another seed, another day) share a few compiled programs and not
-one each. The dictionaries are runtime arrays.
+the rows the fit's epochs reach, as cheaply as the column allows: two sorts
+of the first `SAMPLE_ROWS` of those rows give each column's candidates; one
+pass over all of them confirms them (every valid entry is one of the
+candidates); and a column that held an id the sample missed (a rare category
+of a skewed field) is sorted in full. An epoch trains batch `epoch mod
+batches`, so a fit of E epochs from the first reads the table's first
+`min(E x batch, rows)` rows, all of them once it wraps (`plan_fit`); those
+rows are read where they lie, with no copy. The host reads back one count a
+column to fix the program's static shape: each column's class and its
+dictionary's width, rounded up to a power of two so that tables whose counts
+differ (partitions of one log, another seed, another day) share a few
+compiled programs and not one each. The dictionaries are runtime arrays.
 
 Exact for every input, and no batch can miss: a dictionary holds every id its
-column holds in the fit's own table, whatever the sample saw, so the class of
-a column follows from its count alone and not from the sample's luck. No
+column holds in the rows the fit trains, whatever the sample saw, so the
+class of a column follows from its count alone and not from the sample's
+luck. Ids in rows no epoch reads change no row-dot and no gradient, so they
+are not counted: over the rows a fit reads a column may hold fewer ids than
+over the whole table, or one. No
 entry is dropped, two fields of a row that hash to one id both count (the
 dictionaries' sums are scatter-added at their ids beside the gather
 columns'), values are applied in float32 after the selection, and nothing is
@@ -46,6 +52,7 @@ contracts between solo, fleet, chunked, stream and whole-fit programs stand.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional, Tuple
 
 import jax
@@ -110,16 +117,23 @@ def _distinct(ids):
     return jnp.sum(first, axis=-1, dtype=jnp.int32), distinct
 
 
-@lazy_jit
-def _column_dictionaries(indices):
-    """(distinct ids a column holds over all the table's rows i32[nnz], the
-    first `DICTIONARY_MAX` of them in ascending order i32[nnz,
-    DICTIONARY_MAX]). A count above `DICTIONARY_MAX` is the sample's, and a
-    lower bound."""
-    candidates = _distinct(indices[:SAMPLE_ROWS].T)
+@partial(lazy_jit, static_argnames=("rows",))
+def _column_dictionaries(indices, rows=None):
+    """(distinct ids a column holds over the table's first `rows` rows, all
+    of them by default, i32[nnz], the first `DICTIONARY_MAX` of them in
+    ascending order i32[nnz, DICTIONARY_MAX]). A count above
+    `DICTIONARY_MAX` is the sample's, and a lower bound. A column's rows are
+    cut inside the loop over the columns, which the compiler folds into the
+    column's slice of the table (on a TPU, which keeps a narrow table's rows
+    on the lanes, the columns are a view of the table): no rows are copied,
+    however few the fit reaches, and for all of them the program is the one
+    that always read all."""
+    rows = indices.shape[0] if rows is None else rows
+    candidates = _distinct(indices[: min(rows, SAMPLE_ROWS)].T)
 
-    def of_all_rows(column):
+    def of_reached_rows(column):
         ids, (count, known) = column
+        ids = ids[:rows]
         # a padding entry has nothing to match, and a constant column no more than its id
         matched = (ids < 0) | (ids == known[0])
         # as many buckets of the candidates as hold ids; none for a column that is no
@@ -135,7 +149,7 @@ def _column_dictionaries(indices):
         missed = (count <= DICTIONARY_MAX) & jnp.logical_not(jnp.all(matched))
         return lax.cond(missed, _distinct, lambda _: (count, known), ids)
 
-    return lax.map(of_all_rows, (indices.T, candidates))
+    return lax.map(of_reached_rows, (indices.T, candidates))
 
 
 def width_of(count: int) -> int:
@@ -150,12 +164,13 @@ def width_of(count: int) -> int:
     return max(BUCKET, 1 << (count - 1).bit_length())
 
 
-def column_plan(indices) -> Tuple[Optional[Tuple[int, ...]], Optional[jax.Array]]:
-    """(each column's width, the dictionaries) of a staged table's ids, or
-    (None, None) where every column keeps the gather. One small program and
-    one readback of a count a column: a host sync of the fit (kind `plan`),
-    whose wait is the program's pass over the table."""
-    counts, dictionaries = _column_dictionaries(indices)
+def column_plan(indices, rows=None) -> Tuple[Optional[Tuple[int, ...]], Optional[jax.Array]]:
+    """(each column's width, the dictionaries) of a staged table's ids, over
+    its first `rows` rows (all of them by default), or (None, None) where
+    every column keeps the gather. One small program and one readback of a
+    count a column: a host sync of the fit (kind `plan`), whose wait is the
+    program's pass over those rows."""
+    counts, dictionaries = _column_dictionaries(indices, rows)
     counts = tracing.sync("plan", counts)
     widths = tuple(width_of(int(count)) for count in counts)
     if not any(widths):
@@ -163,14 +178,25 @@ def column_plan(indices) -> Tuple[Optional[Tuple[int, ...]], Optional[jax.Array]
     return widths, dictionaries
 
 
-def plan_fit(X, loss_func: LossFunc, mesh, batch: int):
+def plan_fit(X, loss_func: LossFunc, mesh, batch: int, epochs: int):
     """`column_plan` of a sparse flat fit's staged table where `can_plan`
     admits it, (None, None) elsewhere, and the fit's counters: one tick of
     `sparse_epoch.planned` or `.general`, and the entries of one epoch's
-    batch (columns x rows), all of them and those the epoch gathers."""
+    batch (columns x rows), all of them and those the epoch gathers.
+
+    `epochs` is the most epochs the fit runs from its first: epoch e trains
+    batch k = e mod batches, rows [k x batch, (k + 1) x batch) of the table
+    as handed over, so the plan reads the first `min(epochs x batch, rows)`
+    rows, every row once the epochs wrap. Where it plans,
+    `sparse_epoch.plan_rows` and `sparse_epoch.table_rows` count the rows it
+    read and the table's."""
     widths = dictionaries = None
     if can_plan(X, loss_func, mesh):
-        widths, dictionaries = column_plan(X[0])
+        table_rows = int(X[0].shape[0])
+        rows = min(int(epochs) * batch, table_rows)
+        widths, dictionaries = column_plan(X[0], rows)
+        metrics.inc_counter("sparse_epoch.plan_rows", rows)
+        metrics.inc_counter("sparse_epoch.table_rows", table_rows)
     columns = int(X[0].shape[1])
     gathered = columns if widths is None else widths.count(GATHER)
     metrics.inc_counter("sparse_epoch.general" if widths is None else "sparse_epoch.planned")

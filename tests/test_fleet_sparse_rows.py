@@ -14,7 +14,10 @@ that wants the form tells `mesh_lib.on_tpu` to say yes.
    rounding, at the same stop epochs, in place and laid out, with a weight
    column, for `reg` on a path, mixed elasticNet, unequal maxIter and a tol
    stop; on the CPU a sparse fleet in place is its solo fits bit for bit;
-3. the plan is made once a fleet fit; `fleet.in_place`, `fleet.product.rows`
+3. the plan is made once a fleet fit, over the rows the longest member's
+   epochs reach: ids in rows no member reads (one more id than the widest
+   dictionary, a second id in a column constant where it is read) change
+   neither the plan nor the members; `fleet.in_place`, `fleet.product.rows`
    and `sparse_epoch.*` tick as docs/observability.md says;
 4. the programs: the row program holds its state [d, N] and gathers rows;
    the solo sparse programs and the CPU's sparse fleet program lower to the
@@ -115,6 +118,7 @@ def on_the_chip(monkeypatch):
 WATCHED = (
     "fleet.in_place", "fleet.product.rows", "fleet.product.reduce", "fleet.product.matrix", "fleet.fits",
     "sparse_epoch.planned", "sparse_epoch.general", "sparse_epoch.entries", "sparse_epoch.entries_gathered",
+    "sparse_epoch.plan_rows", "sparse_epoch.table_rows",
     "sync.plan.n", "sync.fit.n", "iteration.host_sync", "fit.layout.n", "layout.general", "dense_epoch.reduce",
 )
 
@@ -284,15 +288,72 @@ def test_the_plan_is_made_once_a_fleet_fit(one_device, fits, on_the_chip, monkey
     made = []
     plan = sparse_epoch.column_plan
 
-    def counted_plan(indices):
-        made.append(indices.shape)
-        return plan(indices)
+    def counted_plan(indices, rows):
+        made.append((indices.shape, rows))
+        return plan(indices, rows)
 
     monkeypatch.setattr(sparse_epoch, "column_plan", counted_plan)
     for _ in range(2):
         _, _, ticks, _ = fits(members(), device_table())
         assert ticks["sync.plan.n"] == 1 and ticks["sync.fit.n"] == 1 and ticks["iteration.host_sync"] == 2
-    assert made == [(ROWS, NNZ)] * 2
+    assert made == [((ROWS, NNZ), ROWS)] * 2  # the longest member's 14 epochs wrap past the 10 batches
+
+
+T = sparse_epoch.DICTIONARY_MAX
+LONG_ROWS = 12 * BATCH
+READ_EPOCHS = 9  # the longest member's: 4,500 of the 6,000 rows
+WIDENED = 13 + 2  # the 300-category field, made to hold T ids in the read rows
+MAX_ITERS = {"longest_first": (9, 3, 6), "longest_last": (2, 5, 9), "longest_in_the_middle": (4, 9, 1)}
+
+
+def partly_read_log():
+    """12 batches whose first 9 hold `T` ids in one field and one id in each
+    integer field; the 3 after them repeat those rows but for one id more in
+    the widened field and a second id in the first integer field."""
+    ids, values, labels = click_log(LONG_ROWS, seed=2)
+    read = READ_EPOCHS * BATCH
+    ids[:, WIDENED] = 13 + (np.arange(LONG_ROWS) % T) * 3
+    ids[read:, WIDENED] = ids[: LONG_ROWS - read, WIDENED]
+    ids[read + 100, WIDENED] = 13 + T * 3
+    ids[read + 400, 0] = 7
+    return ids, values, labels
+
+
+@pytest.mark.parametrize("max_iters", list(MAX_ITERS))
+def test_the_fleets_plan_reads_the_rows_its_longest_member_reaches(one_device, fits, monkeypatch, max_iters):
+    """Whichever member it is, the longest decides: the plan is `column_plan`
+    of the 4,500 rows its 9 epochs read, where the whole table's would gather
+    the widened field and hold the first integer field as a dictionary; the
+    members are the reduce form's and their solo fits' to rounding."""
+    ids, values, labels = partly_read_log()
+    table = Table({"features": SparseBatch(DIM, jnp.asarray(ids), jnp.asarray(values)), "label": jnp.asarray(labels)})
+    fleet = lambda: [  # noqa: E731
+        LogisticRegression().set_reg(reg).set_max_iter(max_iter).set_global_batch_size(BATCH).set_tol(0.0)
+        for reg, max_iter in zip(PATH, MAX_ITERS[max_iters])
+    ]
+    want, want_epochs, _, _ = fits(fleet(), table)
+    solo = np.stack([np.asarray(m.fit(table).coefficient) for m in fleet()])
+    made, plan = [], sparse_epoch.column_plan
+
+    def kept_plan(indices, rows):
+        made.append((rows, plan(indices, rows)))
+        return made[-1][1]
+
+    monkeypatch.setattr(sparse_epoch, "column_plan", kept_plan)
+    monkeypatch.setattr(mesh_lib, "on_tpu", lambda arr: True)
+    got, got_epochs, ticks, _ = fits(fleet(), table)
+    read = READ_EPOCHS * BATCH
+    ((rows, (widths, dictionaries)),) = made
+    want_widths, want_dictionaries = plan(jnp.asarray(ids[:read]))
+    assert rows == read and widths == want_widths
+    np.testing.assert_array_equal(np.asarray(dictionaries), np.asarray(want_dictionaries))
+    whole = plan(jnp.asarray(ids))[0]
+    assert (widths[0], widths[WIDENED]) == (1, T) and (whole[0], whole[WIDENED]) == (sparse_epoch.BUCKET, sparse_epoch.GATHER)
+    assert ticks["sparse_epoch.planned"] == 1 and ticks["fleet.product.rows"] == 1
+    assert ticks["sparse_epoch.plan_rows"] == read and ticks["sparse_epoch.table_rows"] == LONG_ROWS
+    assert got_epochs.tolist() == want_epochs.tolist() == list(MAX_ITERS[max_iters])
+    assert member_gaps(got, want).max() < 1e-5
+    assert member_gaps(got, solo).max() < 1e-5
 
 
 def test_the_counters_say_the_route_and_the_form(one_device, fits, on_the_chip):
@@ -302,7 +363,7 @@ def test_the_counters_say_the_route_and_the_form(one_device, fits, on_the_chip):
         "fleet.fits": 1, "sparse_epoch.planned": 1, "sparse_epoch.general": 0,
         # an epoch's batch: its entries, and those of the columns the plan leaves to the gather
         "sparse_epoch.entries": BATCH * NNZ, "sparse_epoch.entries_gathered": BATCH * GATHERED,
-        "sync.plan.n": 1, "sync.fit.n": 1, "iteration.host_sync": 2, "fit.layout.n": 0, "layout.general": 0,
+        "sparse_epoch.plan_rows": ROWS, "sparse_epoch.table_rows": ROWS, "sync.plan.n": 1, "sync.fit.n": 1, "iteration.host_sync": 2, "fit.layout.n": 0, "layout.general": 0,
         "dense_epoch.reduce": 0,
     }
 

@@ -801,3 +801,24 @@ def test_compiled_for_a_v5e_the_fleets_matrix_form_is_two_products_on_the_batch_
     memory = fleet.memory_analysis()
     assert memory.argument_size_in_bytes >= 20_000_000 * 100 * 4
     assert memory.temp_size_in_bytes < 256 << 20
+
+
+def test_compiled_for_a_v5e_the_plan_reads_the_rows_a_fit_reaches_where_they_lie(four_v5e):
+    """The sparse path cell's plan (`sparse_epoch._column_dictionaries`): a
+    fleet of 20 epochs of 100,000 rows reads 2M of the 28M resident rows of
+    39 fields. The table is the parameter as the device keeps it, rows-minor,
+    its columns a view; neither it nor the read rows are copied or
+    transposed, and the temporaries are a column's and the sample's (the
+    plan over all 28M rows held 225 MB)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from flink_ml_tpu.ops import sparse_epoch
+
+    table = jax.ShapeDtypeStruct((28_000_000, 39), np.int32, sharding=SingleDeviceSharding(four_v5e.devices.flat[0]))
+    plan = jax.jit(sparse_epoch._column_dictionaries, static_argnames=("rows",)).lower(table, rows=2_000_000).compile()
+    text = plan.as_text()
+    assert "s32[28000000,39]{0,1:T(8,128)} parameter(0)" in text
+    assert not re.search(r"= s32\[(28000000,39|39,28000000|2000000,39|39,2000000)\]\S* (copy|transpose|fusion)\(", text)
+    memory = plan.memory_analysis()
+    assert memory.argument_size_in_bytes >= 28_000_000 * 39 * 4
+    assert memory.temp_size_in_bytes < 64 << 20

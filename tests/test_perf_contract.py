@@ -41,7 +41,10 @@ FOLLOW = "keep the name, or change it in a `benchmark` PR together with the file
 # the smallest table the batch exchange admits over four shards
 # (tests/test_layout_exchange.py): a shard's piece of a batch is one tile's
 # lanes, a width is whole sublanes, a shard holds one slab of batches
-BATCH, DIM, MAX_ITER, K = 4 * mesh_lib.LANES, mesh_lib.SUBLANES, 3, 4
+# epochs of a toy fit: under a pass of the table's 32 batches, and enough rows
+# (9 x 512) for a sparse fit's plan, which reads the rows its epochs reach, to
+# find more ids in a spread column than a dictionary holds (4,096)
+BATCH, DIM, MAX_ITER, K = 4 * mesh_lib.LANES, mesh_lib.SUBLANES, 9, 4
 ROWS = 4 * BATCH * mesh_lib.SUBLANES
 # a fit that reads a batch twice: on several shards it lays its table out and
 # trains data-parallel, where one of at most a pass walks (every cell's does)

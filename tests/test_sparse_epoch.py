@@ -16,6 +16,14 @@ Pinned:
 3. a whole fit through `LogisticRegression` / `LinearSVC` /
    `LinearRegression` on either program: the same coefficient to 1e-5, a
    ragged last batch, the counters;
+3a. the plan reads the rows the fit's epochs reach: a fit that reads part of
+   its table plans what `column_plan` of those rows plans, and ids in rows
+   no epoch reads (one more id than the widest dictionary, a second id in a
+   column that is constant where it is read) change neither its plan nor its
+   coefficient; a fit whose epochs wrap plans every row; the counters
+   `sparse_epoch.plan_rows` / `.table_rows`; the rows are sliced column by
+   column inside the program (`tests/test_layout_exchange.py` compiles it
+   for a described v5e: no copy of them);
 4. `sparse_epoch.can_plan` turns away, one by one, everything the plan is
    not made for, and an unpatched fit on the CPU keeps `_sparse`
    (`sparse_epoch.general`) to the bit;
@@ -28,6 +36,9 @@ need the plan taken tell `mesh_lib.on_tpu` to say what an array on the chip
 says (as `tests/test_dense_one_pass.py` does for its kernel). The planned
 loss is plain jax.numpy and runs anywhere.
 """
+
+import hashlib
+import re
 
 import numpy as np
 import pytest
@@ -248,13 +259,14 @@ def table_of(indices, seed=6):
     return Table({"features": features, "label": jax.device_put(label)})
 
 
-def estimator_fit(loss, indices, max_iter=8):
+RAGGED = ROWS - 100  # 5,196 rows: the last batch is 76 rows and 180 of padding
+ONE_PASS = -(-RAGGED // BATCH)  # 21 epochs: every batch once, the ragged last one too, so the plan reads every row
+
+
+def estimator_fit(loss, indices, max_iter=ONE_PASS):
     stage = ESTIMATORS[loss]().set_max_iter(max_iter).set_global_batch_size(BATCH).set_learning_rate(0.5).set_tol(0.0)
     with mesh_lib.use_mesh(one_shard()):
         return np.asarray(stage.fit(table_of(indices)).coefficient)
-
-
-RAGGED = ROWS - 100  # 3,900 rows: the last batch is 60 rows and 196 of padding
 
 
 @pytest.mark.parametrize("case", CASES)
@@ -345,6 +357,153 @@ def test_the_flat_program_keeps_its_name_with_a_plan(monkeypatch):
     assert "jit(_sgd_train_flat)" in lowered and "jit(_column_dictionaries)" in lowered
 
 
+# --- the rows the fit reaches ----------------------------------------------------
+
+READ_EPOCHS = 16
+READ = READ_EPOCHS * BATCH  # 4,096 rows the fit's epochs read of a table of 24 batches
+BOUNDARY = (3, 4711)  # an id of the 300-id column in the LAST read row alone
+UNREAD = {
+    # column 4 holds T ids in the read rows, and one more in an unread row
+    "an_id_past_the_widest_dictionary": (4, DIM - 1, (1, 1, 128, 512, T, T), (1, 1, 128, 512, 0, T)),
+    # column 0 holds id 0 in the read rows, and id 7 in an unread row
+    "a_second_id_in_a_constant_column": (0, 7, (1, 1, 128, 512, T, T), (128, 1, 128, 512, T, T)),
+}
+
+
+def partly_read(unread):
+    """24 batches whose first 16 hold, but for one id in the last of their
+    rows, what the 8 after them hold, and one id more in an unread row."""
+    indices = fields(24 * BATCH)
+    indices[:, 5] = 305 + np.random.default_rng(5).permutation(DIM - 305)[: 24 * BATCH] % T
+    indices[READ:, 4:] = indices[: 24 * BATCH - READ, 4:]
+    indices[READ - 1, BOUNDARY[0]] = BOUNDARY[1]
+    indices[READ:, BOUNDARY[0]] = indices[: 24 * BATCH - READ, BOUNDARY[0]]
+    column, stranger, *_ = UNREAD[unread]
+    indices[READ + 300, column] = stranger
+    return indices
+
+
+def planned_fit(loss, indices, max_iter):
+    """(the coefficient, the plan the flat program was handed, the counters)
+    of a planned fit through the estimator."""
+    handed = []
+    original = optimizer._sgd_train_flat
+
+    def spy(*args, **kwargs):
+        handed.append(args[12:14])
+        return original(*args, **kwargs)
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(mesh_lib, "on_tpu", lambda arr: True)
+        patch.setattr(optimizer, "_sgd_train_flat", spy)
+        coefficient, ticked = counted(lambda: estimator_fit(loss, indices, max_iter=max_iter))
+    (plan,) = handed
+    return coefficient, plan, ticked
+
+
+def assert_same_plan(got, want):
+    assert got[0] == want[0]
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+
+
+@pytest.mark.parametrize("unread", UNREAD)
+@pytest.mark.parametrize("loss", LOSSES)
+def test_a_fit_that_reads_part_of_its_table_plans_the_rows_it_reads(loss, unread):
+    """A fit of 16 epochs over 24 batches reads the first 16: its plan is
+    `column_plan` of those 4,096 rows (the id in the last of them included),
+    the ids after them change nothing, and the coefficient is the general
+    program's."""
+    indices = partly_read(unread)
+    _, _, read_widths, table_widths = UNREAD[unread]
+    general = estimator_fit(loss, indices, max_iter=READ_EPOCHS)
+    planned, plan, ticked = planned_fit(loss, indices, READ_EPOCHS)
+    want = sparse_epoch.column_plan(jnp.asarray(indices[:READ]))
+    assert_same_plan(plan, want)
+    assert plan[0] == read_widths and BOUNDARY[1] in np.asarray(plan[1][BOUNDARY[0]]).tolist()
+    assert sparse_epoch.column_plan(jnp.asarray(indices))[0] == table_widths  # what the whole table would plan
+    assert ticked["sparse_epoch.planned"] == 1
+    assert ticked["sparse_epoch.plan_rows"] == READ and ticked["sparse_epoch.table_rows"] == 24 * BATCH
+    assert np.max(np.abs(planned - general)) <= 1e-5 * np.max(np.abs(general))
+
+
+@pytest.mark.parametrize("loss", LOSSES)
+def test_a_fit_whose_epochs_wrap_plans_every_row(loss):
+    """30 epochs over 24 batches read every batch, the 8 last once: the plan
+    is the whole table's, its widest column a gather again."""
+    indices = partly_read("an_id_past_the_widest_dictionary")
+    general = estimator_fit(loss, indices, max_iter=30)
+    planned, plan, ticked = planned_fit(loss, indices, 30)
+    assert_same_plan(plan, sparse_epoch.column_plan(jnp.asarray(indices)))
+    assert plan[0] == UNREAD["an_id_past_the_widest_dictionary"][3]
+    assert ticked["sparse_epoch.plan_rows"] == ticked["sparse_epoch.table_rows"] == 24 * BATCH
+    assert np.max(np.abs(planned - general)) <= 1e-5 * np.max(np.abs(general))
+
+
+@pytest.mark.parametrize(
+    "max_iter, plan_rows",
+    [(1, BATCH), (4, 4 * BATCH), (READ_EPOCHS, READ), (24, 24 * BATCH), (25, 24 * BATCH), (60, 24 * BATCH)],
+)
+def test_the_plan_counts_the_rows_it_read_and_the_tables(max_iter, plan_rows, monkeypatch):
+    """One tick of each a planned fit: the rows the plan read, the staged
+    table's; a ragged table's are its padded rows, which its last batch
+    reaches."""
+    monkeypatch.setattr(mesh_lib, "on_tpu", lambda arr: True)
+    _, ticked = counted(lambda: estimator_fit("sparse_binary_logistic", fields(24 * BATCH), max_iter=max_iter))
+    assert ticked["sparse_epoch.plan_rows"] == plan_rows and ticked["sparse_epoch.table_rows"] == 24 * BATCH
+    _, ticked = counted(lambda: estimator_fit("sparse_binary_logistic", fields(RAGGED), max_iter=max_iter))
+    assert ticked["sparse_epoch.plan_rows"] == min(max_iter, ONE_PASS) * BATCH
+    assert ticked["sparse_epoch.table_rows"] == ONE_PASS * BATCH
+
+
+def test_a_plan_that_finds_nothing_counts_the_rows_it_read(monkeypatch):
+    """20 batches of rows with no structure hold more than `T` ids in every
+    column: the plan read them and left every column to the gather."""
+    monkeypatch.setattr(mesh_lib, "on_tpu", lambda arr: True)
+    _, ticked = counted(lambda: estimator_fit("sparse_binary_logistic", bag(RAGGED), max_iter=20))
+    assert ticked["sparse_epoch.general"] == 1 and "sparse_epoch.planned" not in ticked
+    assert ticked["sparse_epoch.plan_rows"] == 20 * BATCH and ticked["sparse_epoch.table_rows"] == ONE_PASS * BATCH
+
+
+def test_an_unplanned_fit_counts_no_rows():
+    _, ticked = counted(lambda: estimator_fit("sparse_binary_logistic", fields(RAGGED), max_iter=4))
+    assert ticked["sparse_epoch.general"] == 1
+    assert "sparse_epoch.plan_rows" not in ticked and "sparse_epoch.table_rows" not in ticked
+
+
+def lowered_plan(table, nnz, **rows):
+    return jax.jit(sparse_epoch._column_dictionaries, static_argnames=("rows",)).lower(
+        jax.ShapeDtypeStruct((table, nnz), jnp.int32), **rows
+    ).as_text()
+
+
+def test_the_plan_cuts_each_columns_read_rows_inside_its_loop():
+    """The rows a fit reads (more than the sample) are cut from each column
+    inside the loop over the columns, where the compiler folds the cut into
+    the column's slice (`tests/test_layout_exchange.py` compiles it for a
+    described v5e: no copy); the table's rows of several columns are its
+    transpose alone (on the chip a view of the rows-minor table), and no op
+    takes the read rows of all columns as one array."""
+    table, nnz, rows = 3 * sparse_epoch.SAMPLE_ROWS, 6, 2 * sparse_epoch.SAMPLE_ROWS
+    text = lowered_plan(table, nnz, rows=rows)
+    assert f"tensor<{rows}x{nnz}xi32>" not in text and f"tensor<{nnz}x{rows}xi32>" not in text
+    made = re.findall(rf"= stablehlo\.(\w+) .*-> tensor<(\d+)x{table}xi32>", text)
+    assert sorted(made) == [("dynamic_slice", "1"), ("transpose", str(nnz))]
+    assert f"stablehlo.slice %arg0 [0:{rows}] : (tensor<{table}xi32>) -> tensor<{rows}xi32>" in text
+
+
+# sha256 of the StableHLO text of the plan over a 196,608 x 6 table before the
+# plan read only the rows a fit reaches (read with `lowered_plan` over that package)
+WHOLE_TABLE_PLAN = "5e7602a4ac4549b7c247b532b26f2f5bbc4ae37e277fadea3319800660ee7363"
+
+
+@pytest.mark.parametrize("rows", [{}, {"rows": 3 * sparse_epoch.SAMPLE_ROWS}], ids=["by_default", "every_row_reached"])
+def test_a_plan_over_every_row_lowers_to_the_text_it_always_had(rows):
+    """A fit whose epochs reach every row (the two solo sparse cells' fits
+    make one whole pass) runs the plan program it ran before."""
+    text = lowered_plan(3 * sparse_epoch.SAMPLE_ROWS, 6, **rows)
+    assert hashlib.sha256(text.encode()).hexdigest() == WHOLE_TABLE_PLAN
+
+
 # --- who takes it ----------------------------------------------------------------
 
 
@@ -410,12 +569,12 @@ def test_an_unpatched_fit_on_the_cpu_is_the_general_program_without_a_plan():
     assert handed == [(None, None)]
     assert ticked["sparse_epoch.general"] == 1 and ticked["iteration.host_sync"] == 1
     assert "iteration.host_sync.plan" not in ticked
-    sgd = SGD(max_iter=8, learning_rate=0.5, global_batch_size=BATCH, tol=0.0)
+    sgd = SGD(max_iter=ONE_PASS, learning_rate=0.5, global_batch_size=BATCH, tol=0.0)
     table = table_of(indices)
     features = table.column("features")
     coeff, _, epochs = sgd.optimize(
         np.zeros(DIM), (features.indices, features.values), table.column("label"), None,
         losses.SPARSE_BINARY_LOGISTIC_LOSS, one_shard(),
     )
-    assert epochs == 8
+    assert epochs == ONE_PASS
     np.testing.assert_array_equal(coeff, estimator_fit("sparse_binary_logistic", indices))
