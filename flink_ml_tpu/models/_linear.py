@@ -125,7 +125,8 @@ def run_sgd(
             on_device = isinstance(indices, jax.Array) and not optimizer.shard_features
             init_coeff = (jnp if on_device else np).zeros(dim, dtype=optimizer.dtype)
         else:
-            init_coeff = np.zeros(X.shape[1], dtype=np.float64)
+            # a dense model is narrow: its zeros go up with the launch
+            init_coeff = np.zeros(X.shape[1], dtype=optimizer.dtype)
     result = optimizer.optimize_async(
         init_coeff, X, y, w, loss_func, validate_labels=validate_on_device
     )

@@ -1888,7 +1888,12 @@ class SGD:
         pin a fit to a non-default chip); already-device-resident inputs
         stay where they are. A sparse table is planned here, once a fit,
         where `sparse_epoch.plan_fit` admits it, over the rows the fit's
-        `max_iter` epochs reach. Returns the launch, a call
+        `max_iter` epochs reach. The small inputs run no device program
+        here: the row count, an absent weight column's placeholder and a
+        host start coefficient go up as host values with the launch, as the
+        walk's first leg does (placed first, each is an eager program and
+        about 0.5 ms of an idle chip on a v5e host); a start that lies on
+        the device stays there. Returns the launch, a call
         without arguments that gives the packed result device vector."""
         n = int(np.shape(X[0] if isinstance(X, tuple) else X)[0])
         B = int(self.global_batch_size)
@@ -1929,7 +1934,12 @@ class SGD:
                 w_f = jnp.pad(w_f, (0, n_pad - n))
         has_weights = w_f is not None
         if not has_weights:
-            w_f = jnp.zeros((0,), self.dtype)
+            w_f = np.zeros((0,), self.dtype)
+        if isinstance(init_coeff, jax.Array):
+            init = self._device_init(init_coeff)
+        else:
+            init = np.asarray(init_coeff, self.dtype)
+            metrics.inc_counter("fit.stage.launch_inputs")
         # the flat staged (or padded) arrays are this fit's training-data
         # residency — ledger them like the batched layouts in _batchify
         from ..obs import memledger
@@ -1949,11 +1959,11 @@ class SGD:
             X_f,
             y_f,
             w_f,
-            self._device_init(init_coeff),
+            init,
             loss_func,
             B,
             has_weights,
-            jnp.asarray(n, jnp.int32),
+            np.int32(n),
             self._hyper(),
             validate_labels,
             one_pass,

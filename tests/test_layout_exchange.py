@@ -676,6 +676,46 @@ def test_compiled_for_a_v5e_a_leg_of_a_walked_fit_is_the_one_read_epoch_over_the
     assert not any(opcode == "custom-call" for _, opcode in instructions(finish))
 
 
+def test_compiled_for_a_v5e_the_one_shard_fit_is_the_same_program_with_its_small_inputs_from_the_host(four_v5e):
+    """The one-chip cell's fit hands `_sgd_train_flat` its row count, weight
+    placeholder and start coefficient as host values (`SGD._stage_flat`),
+    which jit places with the launch: the program compiled so holds the
+    instructions of the one handed them on the chip, line for line, but for
+    the annotations that say where a parameter lies."""
+    from jax.sharding import SingleDeviceSharding
+
+    chip = SingleDeviceSharding(four_v5e.devices.flat[0])
+    rows = 20_000_000
+
+    def compiled(placed):
+        small = dict(sharding=chip) if placed else {}
+        train = lambda X, y, w, c, n, h: optimizer._sgd_train_flat(  # noqa: E731
+            X, y, w, c, losses.BINARY_LOGISTIC_LOSS, CELL_BATCH, False, n, h, True, True, False
+        )
+        return jax.jit(train).lower(
+            jax.ShapeDtypeStruct((rows, 100), np.float32, sharding=chip),
+            jax.ShapeDtypeStruct((rows,), np.float32, sharding=chip),
+            jax.ShapeDtypeStruct((0,), np.float32, **small),
+            jax.ShapeDtypeStruct((100,), np.float32, **small),
+            jax.ShapeDtypeStruct((), np.int32, **small),
+            jax.ShapeDtypeStruct((5,), np.float32, sharding=chip),
+        ).compile().as_text()
+
+    def lines(text):
+        """The instructions, where each parameter lies and the source lines left out."""
+        placement = r", sharding=\{[^}]*\}, frontend_attributes=\{xla\.sdy\.sharding=\"[^\"]*\"\}"
+        return [
+            re.sub(r", metadata=\{[^}]*\}", "", re.sub(placement, "", line))
+            for line in text.splitlines()
+            if " = " in line and not line.startswith("HloModule")
+        ]
+
+    from_host, placed = compiled(False), compiled(True)
+    assert from_host != placed  # the parameters' annotations differ ...
+    assert lines(from_host) == lines(placed)  # ... and nothing else
+    assert len([line for line in lines(from_host) if "tpu_custom_call" in line]) == 1
+
+
 # --- one chip: the Lloyd loop's cross term by the pieces its points have (ops/distance.py) ------
 
 

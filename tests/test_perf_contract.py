@@ -345,6 +345,20 @@ def test_documented_phase_is_emitted_once_a_fit(phase):
     )
 
 
+@pytest.mark.parametrize(
+    "cell, ticks",
+    [("lr-dense-100.pass", 1), ("lr-sparse-1m.partitions", 0), ("criteo-onehot-pipeline.day-partitions", 0)],
+)
+def test_the_one_shard_cells_start_goes_up_with_the_launch_where_it_is_on_the_host(cell, ticks):
+    """`fit.stage.launch_inputs` (docs/observability.md "Fit phases"): a dense
+    fit's start goes up with its launch; a sparse fit's zeros are made on the
+    device and stay there."""
+    counters = toy_fit_of_cell(cell)["counters"]
+    assert counters["fit.launch.n"] == 1 and counters.get("fit.stage.launch_inputs", 0) == ticks
+    fit_phases = (ROOT / "docs" / "observability.md").read_text().split("## Fit phases", 1)[1].split("\n## ", 1)[0]
+    assert "`fit.stage.launch_inputs`" in fit_phases
+
+
 def pipeline_cells() -> list:
     return sorted(name for name, cell in CELLS.items() if "pipeline" in CONFIGS[cell["config"]])
 
