@@ -7,23 +7,22 @@ math says:
    (or an 8-bit type) immediately upstream of a ``psum``-family
    collective makes every addend lose mantissa *before* the sum — at
    dim=1e6 the accumulated error is not noise, it is a different
-   model. An f32 accumulator over f32 operands is fine (and
-   ``astype(jnp.float32)`` on the two tol-check scalars in overlap.py
-   is exactly that); it is the *narrowing* direction that corrupts.
+   model. An f32 accumulator over f32 operands is fine (an
+   ``astype(jnp.float32)`` on a loss scalar before its psum is exactly
+   that); it is the *narrowing* direction that corrupts.
    The fix is to reduce in the operand's dtype (or wider) and narrow
    the *result* if the wire format demands it.
 
-2. **Reduction-order-sensitive folds outside the sanctioned ring.**
-   The bit-exactness contract of the comm layer (`docs/performance.md`
-   §7) holds because the two hand-rolled folds — the ring ppermute
-   fold in ``parallel/collectives.py`` and its overlap-scheduled
-   caller — fold arrivals in **replica order**, the same association
-   the backend's own all-reduce uses. A manual loop elsewhere that
+2. **Reduction-order-sensitive folds outside the comm layer.**
+   The parity contract of the comm layer (`docs/performance.md` §7)
+   holds because its reductions are the backend's own collectives
+   (``psum``, reduce_scatter + all_gather) or fold in **replica
+   order** in ``parallel/collectives.py``. A manual loop elsewhere that
    accumulates permuted shards reassociates the sum: bit-identity
    silently becomes "close enough", which breaks every parity test the
-   repo pins (chunked==monolithic, overlap==eager, kill→resume
-   bit-identical). New ring schedules belong next to the existing one,
-   where the replica-order discipline and its parity suite live.
+   repo pins (chunked==monolithic, kill→resume bit-identical). A new
+   reduction belongs in ``parallel/collectives.py``, where its parity
+   suite lives.
 
 Both checks ride the shared SPMD layer (``analysis/spmd.py``): the
 interpreter tracks narrowing provenance through assignments into
@@ -51,8 +50,8 @@ class PrecisionDeterminismRule(Rule):
         "result instead. And a manual loop accumulating permuted shards "
         "outside parallel/collectives.py reassociates the reduction, "
         "breaking the replica-order bit-exactness contract every parity "
-        "suite in the repo pins (chunked==monolithic, overlap==eager, "
-        "resume bit-identical)."
+        "suite in the repo pins (chunked==monolithic, resume "
+        "bit-identical)."
     )
     example = "total = all_reduce_sum(grad.astype(jnp.bfloat16), DATA_AXIS)"
     scope = ("flink_ml_tpu",)
@@ -85,13 +84,12 @@ class PrecisionDeterminismRule(Rule):
                 rule=self.id,
                 message=(
                     f"loop at line {loop_line} accumulates permuted shards "
-                    "— a hand-rolled ring fold outside the sanctioned "
-                    "replica-order implementation in parallel/"
+                    "— a hand-rolled ring fold outside parallel/"
                     "collectives.py; its association differs from psum, so "
                     "results are no longer bit-identical to the monolithic "
                     "collective (the contract docs/performance.md §7 "
-                    "pins). Build on _reduce_bucket_ring or add the new "
-                    "schedule beside it with the same replica-order fold"
+                    "pins). Reduce with psum (collectives.all_reduce_sum) "
+                    "instead"
                 ),
                 data=("order-fold", event.detail),
             )

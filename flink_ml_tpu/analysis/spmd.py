@@ -60,10 +60,10 @@ MESH_PATH = "flink_ml_tpu/parallel/mesh.py"
 COLLECTIVES_PATH = "flink_ml_tpu/parallel/collectives.py"
 
 #: modules whose hand-rolled reduction folds are the sanctioned,
-#: replica-order bit-exact implementations (the ring fold and the sparse
-#: scatter-partial fold live here; anywhere else a manual fold over
-#: collective results reassociates the sum)
-SANCTIONED_FOLD_PATHS = (COLLECTIVES_PATH, "flink_ml_tpu/parallel/overlap.py")
+#: replica-order implementations (the sparse scatter-partial fold lives
+#: here; anywhere else a manual fold over collective results reassociates
+#: the sum)
+SANCTIONED_FOLD_PATHS = (COLLECTIVES_PATH,)
 
 #: raw lax primitive -> (kind, axis positional index)
 LAX_COLLECTIVES = {
@@ -129,7 +129,7 @@ class SpmdEvent:
       ``spec-arity``          — in_specs arity does not match the body params
       ``downcast-before-reduce`` — narrowed dtype feeds a reduction
       ``order-fold``          — manual accumulation of permuted shards outside
-                                the sanctioned ring fold
+                                parallel/collectives.py
     """
 
     path: str
@@ -1315,11 +1315,10 @@ def _scan_axis_literals(ctx: ModuleContext, events: List[SpmdEvent]) -> None:
 
 
 def _scan_order_folds(ctx: ModuleContext, events: List[SpmdEvent]) -> None:
-    """Manual accumulation of permuted shards outside the sanctioned
-    ring fold: a python loop whose body both calls a permute collective
-    and accumulates into a loop-carried name reassociates the reduction
-    — replica-order bit-exactness lives only in collectives.py/
-    overlap.py."""
+    """Manual accumulation of permuted shards outside collectives.py: a
+    python loop whose body both calls a permute collective and accumulates
+    into a loop-carried name reassociates the reduction — replica-order
+    folds live only in collectives.py."""
     module = ctx.module
     if module.tree is None or module.path in SANCTIONED_FOLD_PATHS:
         return

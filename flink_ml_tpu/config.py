@@ -96,53 +96,11 @@ def fleet_shard_threshold(nbytes: Optional[int]):
         fleet_shard_state_bytes = prev
 
 
-# --- collectives: chunking, sparse reduction, comm/compute overlap ------------
-# (parallel/collectives.py + parallel/overlap.py)
-# Bucket size for all_reduce_sum_chunked: a large gradient pytree is
-# decomposed into size-targeted buckets and each bucket reduced on its own.
-# The reference hand-rolls the same decomposition at 32KB per chunk over
-# netty shuffles (AllReduceImpl.java:56-103, tuned for TCP framing); ICI
-# moves MB-class buckets at line rate, so the default is 4MB — small enough
-# that a multi-bucket reduce can pipeline, large enough to amortize
-# per-collective launch cost. None/0 = one bucket (no chunking).
-collective_chunk_bytes: Optional[int] = 4 << 20
-# Density threshold for the SparCML-style index-value gradient reduction:
-# the sparse path is used when its wire bytes (per-shard (index, value)
-# pairs) are at most this fraction of the dense-equivalent psum payload
-# (dim * itemsize); above it, the gradient densifies and rides the chunked
-# dense reduce. Decided at trace time from static shapes.
-collective_sparse_threshold: float = 0.5
-# Route each bucket through the ring-pipelined ppermute reduction instead
-# of reduce_scatter+all_gather. The ring rotates shard contributions and
-# folds them in replica order (bit-identical to psum), letting bucket i+1's
-# hops overlap bucket i's fold — the latency-bound small-bucket regime; the
-# default reduce_scatter+all_gather path is the bandwidth-optimal one.
-collective_ring: bool = False
-# Comm/compute overlap in the SGD/Lloyd training loops: the loop carries
-# the UNREDUCED per-shard gradient and defers its all-reduce to the top of
-# the next epoch, so the reduction of batch b's gradient overlaps the
-# forward of batch b+1 (carry-delayed apply; bit-identical by construction
-# — see docs/performance.md §7 and tests/test_collective_chunks.py).
-collective_overlap: bool = False
-
-
-@contextmanager
-def collective_overlap_mode(enabled: bool = True):
-    """Scoped override of `collective_overlap`."""
-    global collective_overlap
-    prev = collective_overlap
-    collective_overlap = bool(enabled)
-    try:
-        yield
-    finally:
-        collective_overlap = prev
-
-
 # True 2D (data × model) sparse training (docs/performance.md "2D mesh"):
 # "auto" routes a feature-sharded sparse fit on a mesh with a real model
-# axis through the explicit-SPMD 2D programs (parallel/overlap.py
-# sgd2d_*: coeff + optimizer carries live as model-axis slices, gradients
-# reduce over the data axis only). "off" keeps the GSPMD 1D program —
+# axis through the explicit-SPMD 2D programs (parallel/sgd2d.py: coeff +
+# optimizer carries live as model-axis slices, gradients reduce over the
+# data axis only). "off" keeps the GSPMD 1D program —
 # the replicated-residency reference the 2D parity tests compare against.
 sparse_2d: str = "auto"
 
@@ -161,21 +119,8 @@ def sparse_2d_mode(mode: str):
         sparse_2d = prev
 
 
-def resolve_chunk_bytes(chunk_bytes: Optional[int] = None) -> Optional[int]:
-    """Effective collective bucket size: explicit argument > process-wide
-    `collective_chunk_bytes`. None/<=0 means unchunked (one bucket)."""
-    v = chunk_bytes if chunk_bytes is not None else collective_chunk_bytes
-    if v is None or v <= 0:
-        return None
-    return int(v)
-
-
-if os.environ.get("FLINK_ML_TPU_COLLECTIVE_OVERLAP") in ("1", "true", "on"):
-    collective_overlap = True
 if os.environ.get("FLINK_ML_TPU_SPARSE_2D") in ("auto", "off"):
     sparse_2d = os.environ["FLINK_ML_TPU_SPARSE_2D"]
-if os.environ.get("FLINK_ML_TPU_COLLECTIVE_CHUNK_BYTES"):
-    collective_chunk_bytes = int(os.environ["FLINK_ML_TPU_COLLECTIVE_CHUNK_BYTES"])
 
 
 # --- pipeline transform fusion (pipeline.py) ----------------------------------

@@ -398,8 +398,6 @@ class FitFleet:
         """What every member has to share and the packed per-member rest:
         (hyper [N, 5], the longest maxIter, featuresCol, labelCol, weightCol,
         globalBatchSize)."""
-        from .parallel import dispatch, overlap
-
         ests = self.estimators
         features_col = _require_same(ests, "get_features_col", "featuresCol")
         label_col = _require_same(ests, "get_label_col", "labelCol")
@@ -413,16 +411,7 @@ class FitFleet:
                         "Supported options: [auto, binomial]."
                     )
         hyper = np.asarray([_member_hyper(e) for e in ests], np.float32)
-        if self._overlap_requested() and not overlap.fleet_overlap_supported():
-            # overlap-scheduled programs cannot host the fleet axis yet;
-            # reason-counted so overlap-tuned deployments see the downgrade
-            dispatch.account_whole_fit_fallback("fleet_overlap")
         return hyper, int(hyper[:, 0].max()), features_col, label_col, weight_col, gbs
-
-    def _overlap_requested(self) -> bool:
-        from . import config
-
-        return bool(config.collective_overlap)
 
     def _run_fleet_sgd(
         self, mesh, X_b, y_b, w_b, carry, crit, loss_func, hyper, gmax, d,

@@ -147,9 +147,6 @@ def _optimizer_for(params) -> SGD:
         tol=params.get_tol(),
         reg=params.get_reg(),
         elastic_net=params.get_elastic_net(),
-        # pin the comm schedule at fit start (a mid-fit config flip must
-        # not switch a running estimator between programs)
-        collective_overlap=config.collective_overlap,
         checkpoint_dir=config.iteration_checkpoint_dir,
         checkpoint_interval=config.iteration_checkpoint_interval,
         # namespace the shared checkpoint dir per estimator identity so two

@@ -134,8 +134,8 @@ def _accumulate_batch_impl(X, w, centroids, measure_name):
     """One pass of the rows held here, X (n, d), over the centroids: the
     (sums, counts) partials of a Lloyd iteration, block by block
     (`_block_rows`, `_one_block`), so that nothing of shape (n, k) exists.
-    Each batch of both stream fits and the overlap schedule take it; the
-    in-memory fit and the fleet walk the same blocks in `_lloyd_loop`."""
+    Each batch of both stream fits takes it; the in-memory fit and the
+    fleet walk the same blocks in `_lloyd_loop`."""
     measure = DistanceMeasure.get_instance(measure_name)
     n, d = X.shape
     k = centroids.shape[0]
@@ -525,7 +525,6 @@ class KMeans(Estimator, KMeansParams):
 
         if isinstance(table, StreamTable):
             return self._fit_stream(table)
-        from ... import config
         from ...obs import tracing
         from ...ops.optimizer import _read_packed
         from ...parallel import dispatch
@@ -547,21 +546,8 @@ class KMeans(Estimator, KMeansParams):
             shards = mesh_lib.num_data_shards(mesh)
             row_mesh = mesh if shards > 1 else None
             max_iter_dev = jnp.asarray(max_iter, jnp.int32)
-            overlapped = config.collective_overlap and row_mesh is not None
-            # the overlap schedule keeps the six passes: nothing is looked at
-            point_pieces = ALL_PIECES if overlapped else _point_pieces(X_dev)
+            point_pieces = _point_pieces(X_dev)
 
-        if overlapped:
-            # overlap-scheduled Lloyd: epoch e's centroid-partial reduce
-            # rides the chunked collective under epoch e+1's distance
-            # matmul (parallel/overlap.py; the same block step)
-            from ...parallel import overlap
-
-            def train(X, w, init, max_iter, measure, mesh, point_pieces):
-                return overlap.overlapped_lloyd_train(mesh, X, w, init, max_iter, measure)
-
-        else:
-            train = _lloyd_fit
         # the in-memory Lloyd loop has always been a whole-fit resident
         # program (one dispatch, one packed readback); counted when the
         # mode is on, like the fused SGD paths
@@ -577,7 +563,7 @@ class KMeans(Estimator, KMeansParams):
         # `iteration.run` span carries the per-run summary
         with tracing.span("iteration.run", mode="device", epochs=max_iter):
             packed = dispatch.timed_dispatch(
-                train, X_dev, w_dev, init_centroids, max_iter_dev, measure, row_mesh, point_pieces,
+                _lloyd_fit, X_dev, w_dev, init_centroids, max_iter_dev, measure, row_mesh, point_pieces,
                 start=0, end=max_iter,
             )
             host = _read_packed(packed)  # the fit's one readback

@@ -45,7 +45,7 @@ rounding, as the kernel's do. So the reduce form is now the CPU's, where
 the fleet's bit-parity contract lives and a second read of X costs nothing
 like it does on the chip, and the solo general form's: laid-out batches on
 several shards (GSPMD; that program reads its batch once already), sparse
-rows, a wide or 16-bit table, the overlap schedule (parallel/overlap.py).
+rows, a wide or 16-bit table.
 
 A fleet over sparse rows on a TPU is handed `rows_variant(loss)` instead
 (`optimizer._fleet_rows`, counted `fleet.product.rows`): the members'
@@ -72,10 +72,8 @@ class LossFunc(NamedTuple):
     """A batched loss: name + callable(X, y, w, coeff) -> (loss_sum, grad_sum, weight_sum).
 
     `pointwise(dot, y, w) -> (per-row loss, per-row multiplier)` is the
-    shared per-row form both layouts are built from; the overlap-scheduled
-    training path (parallel/overlap.py) uses it to compute per-shard local
-    loss pieces and defer the gradient reduction into the next epoch.
-    `sparse` marks the padded-CSR (indices, values) input layout."""
+    shared per-row form every layout is built from. `sparse` marks the
+    padded-CSR (indices, values) input layout."""
 
     name: str
     fn: Callable[..., LossOut]
@@ -260,7 +258,7 @@ ROW_VARIANTS = {
 
 def _feature_sharded(pointwise):
     """Padded-CSR batched loss for the explicit 2D `(data, model)` mesh
-    (parallel/overlap.py `sgd2d_*`): runs INSIDE a shard_map body where
+    (parallel/sgd2d.py): runs INSIDE a shard_map body where
     `coeff` is this MODEL shard's contiguous feature slice (d_local,) at
     offset `axis_index(model) * d_local`, and (indices, values, y, w) are
     this DATA shard's batch rows with GLOBAL feature indices.

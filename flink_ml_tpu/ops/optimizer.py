@@ -259,9 +259,9 @@ def _can_walk(X, y, weights, batch, max_iter, dtype, mesh) -> bool:
     turns away keeps the batched route as it was: a fit of several passes, a
     straddling batch, a sparse or a host table pay the layout once and train
     data-parallel (`_can_exchange` then says which form lays the table out).
-    The routes decided before it in `_stage_async` (the overlap schedule, one
-    shard, feature sharding, a checkpoint directory) and the fleet's
-    `replicate_data` never ask."""
+    The routes decided before it in `_stage_async` (one shard, feature
+    sharding, a checkpoint directory) and the fleet's `replicate_data` never
+    ask."""
     shards = mesh_lib.num_data_shards(mesh)
     columns = [y] if weights is None else [y, weights]
     if not (
@@ -1206,7 +1206,7 @@ def read_train_result(async_result):
         # tpulint: disable=host-sync-leak -- host-driven branch: coeff is already host numpy here, the copy is free
         return flag, np.asarray(coeff)[:d], criteria, epochs
     if async_result[0] == "packed2d":  # 2D (data × model) whole-fit path
-        from ..parallel.overlap import sgd2d_unpack_host
+        from ..parallel.sgd2d import sgd2d_unpack_host
 
         _, packed, d, has_flag, nm, d_local = async_result
         # ONE device_get of the model-sharded pack (per-shard block =
@@ -1261,29 +1261,10 @@ class SGD:
     (SURVEY.md §2.3: feature-sharded linear training as the TP analogue).
     The X@coeff contraction then all-reduces over `model` while the
     gradient contraction all-reduces over `data`; both ride ICI."""
-    collective_overlap: Optional[bool] = None
-    """Overlap-scheduled gradient reduction (parallel/overlap.py): the
-    epoch loop carries the unreduced per-shard gradient and defers its
-    bucketed all-reduce to the top of the next epoch, so batch b's
-    reduction overlaps batch b+1's staging — bit-identical coefficients by
-    construction. Sparse gradients additionally ride the SparCML
-    index-value reduction when below `config.collective_sparse_threshold`.
-    None follows the process-wide `config.collective_overlap`; applies to
-    the fused in-memory path (data-parallel, no checkpointing)."""
-
-    def _overlap_enabled(self) -> bool:
-        from .. import config
-
-        on = (
-            self.collective_overlap
-            if self.collective_overlap is not None
-            else config.collective_overlap
-        )
-        return bool(on) and not self.shard_features and self.checkpoint_dir is None
 
     def _use_2d(self, mesh: Mesh, loss_func: LossFunc) -> bool:
         """Route this fit through the explicit 2D (data × model) programs
-        (parallel/overlap.py sgd2d_*)? Requires a feature-sharded SPARSE
+        (parallel/sgd2d.py)? Requires a feature-sharded SPARSE
         fit on a mesh that actually has a model axis; `config.sparse_2d`
         = "off" keeps the GSPMD 1D program — the replicated-residency
         reference the 2D parity tests compare against. A 1-shard model
@@ -1390,31 +1371,12 @@ class SGD:
         d = int(np.shape(init_coeff)[0])
         from ..parallel import dispatch
 
-        # the in-memory fused paths below have been whole-fit programs
-        # since the dispatch pipeline landed (one dispatch, one packed
-        # readback, independent of the knob) — they count toward
-        # `dispatch.whole_fit` only when the mode is on, so chunked-vs-
-        # whole-fit BENCH comparisons see clean counters on the off side
+        # the in-memory routes below are whole-fit programs whatever the
+        # mode says (one dispatch, one packed readback); they count toward
+        # `dispatch.whole_fit` only when it is on, so that a process that
+        # turned the mode off counts no whole fit
         if dispatch.whole_fit_enabled() and self.checkpoint_dir is None:
             dispatch.account_whole_fit("sgd")
-        if self._overlap_enabled():
-            from ..parallel import overlap
-
-            X_b, y_b, w_b = self._batchify(mesh, X, y, weights)
-            launch = partial(
-                dispatch.timed_dispatch,
-                overlap.overlapped_sgd_train,
-                mesh,
-                X_b,
-                y_b,
-                w_b,
-                self._device_init(init_coeff),
-                loss_func,
-                self._hyper(),
-                validate_labels,
-                start=0, end=self.max_iter,
-            )
-            return lambda: ("packed", launch(), d, validate_labels)
         if (
             not self.shard_features
             and self.checkpoint_dir is None
@@ -1462,7 +1424,7 @@ class SGD:
 
             return host_driven
         if self._use_2d(mesh, loss_func) and isinstance(X_b, tuple):
-            from ..parallel import overlap
+            from ..parallel import sgd2d
 
             carry = (
                 jnp.asarray(init, self.dtype),
@@ -1472,7 +1434,7 @@ class SGD:
             )
             launch = partial(
                 dispatch.timed_dispatch,
-                overlap.sgd2d_whole_fit,
+                sgd2d.sgd2d_whole_fit,
                 mesh, X_b, y_b, w_b, carry,
                 jnp.asarray(np.inf, jnp.float32),
                 loss_func, self._hyper(), validate_labels,
@@ -2086,7 +2048,7 @@ class SGD:
         hyper = self._hyper()
         use_2d = self._use_2d(mesh, loss_func) and isinstance(X_b, tuple)
         if use_2d:
-            from ..parallel import overlap
+            from ..parallel import sgd2d
         carry = (
             jnp.asarray(init_coeff, self.dtype),
             self._stage_2d_grad(mesh, d)
@@ -2139,13 +2101,13 @@ class SGD:
             ):
                 if use_2d:
                     carry, crit_dev, packed = dispatch.timed_dispatch(
-                        overlap.sgd2d_whole_fit,
+                        sgd2d.sgd2d_whole_fit,
                         mesh, X_b, y_b, w_b, carry, crit_dev, loss_func, hyper,
                         start=epoch, end=self.max_iter,
                     )
                     (host,) = packed_device_get(packed, sync_kind="fit")
                     nm = mesh_lib.num_model_shards(mesh)
-                    coeff_h, final_crit, final_epoch, _ = overlap.sgd2d_unpack_host(
+                    coeff_h, final_crit, final_epoch, _ = sgd2d.sgd2d_unpack_host(
                         np.asarray(host), nm, d // nm, False
                     )
                 else:
@@ -2215,7 +2177,7 @@ class SGD:
                     # readable for a pending snapshot write, and the
                     # shard_map program re-enters its cached executable
                     def step(Xb, yb, wb, c, crit, lf, hy, ce):
-                        return overlap.sgd2d_chunk(
+                        return sgd2d.sgd2d_chunk(
                             mesh, Xb, yb, wb, c, crit, lf, hy, ce
                         )
                 else:

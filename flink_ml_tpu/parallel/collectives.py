@@ -7,26 +7,28 @@ partitionCustom shuffles), broadcast variables (BroadcastUtils.java:64),
 and the statefun in-JVM feedback channel (operator/TailOperator.java:76-79).
 On TPU these are hardware collectives over ICI; `psum` IS the all-reduce
 and replication IS the broadcast — but the reference's chunk decomposition
-is worth keeping: a large gradient reduced as one monolithic collective
-cannot overlap anything, while size-targeted buckets can pipeline against
-each other and against compute. This module therefore carries two tiers:
+is kept for the one route that reduces by hand. This module carries two
+tiers:
 
 - thin accounted wrappers over the hardware collectives (`all_reduce_sum`
   … `ppermute_ring`) — every collective a model dispatches rides one of
   these, so `collective.*` counters answer "what traffic does this program
   move";
-- the comm layer proper: `all_reduce_sum_chunked` (bucketed
-  reduce_scatter+all_gather with a ring-pipelined ppermute variant) and
-  `sparse_all_reduce_sum` (SparCML-style index-value reduction, wire bytes
-  ∝ nnz instead of dim — arXiv:1802.08021). The chunked reduce is
+- the gradient reductions of the feature-sharded loss
+  (`ops.losses.feature_sharded_variant`, run by the 2-D route in
+  parallel/sgd2d.py): `all_reduce_sum_chunked` (bucketed
+  reduce_scatter+all_gather) and `sparse_all_reduce_sum` (SparCML-style
+  index-value reduction, wire bytes ∝ nnz instead of dim —
+  arXiv:1802.08021), chosen by `sparse_reduce_wins`. The chunked reduce is
   bit-identical to a single `lax.psum` of the same operand (pinned across
   chunk sizes and shard counts by tests/test_collective_chunks.py, and
-  seen on four v5e chips); its ring variant is on the CPU backend only,
-  and the sparse reduce sums the same addends but agrees with the psum
-  of the densified operand to float rounding, not bitwise, on either
-  (see their docstrings).
-  The overlap-scheduled training loops in parallel/overlap.py are built
-  on them.
+  seen on four v5e chips); the sparse reduce sums the same addends but
+  agrees with the psum of the densified operand to float rounding, not
+  bitwise (see its docstring).
+
+Everywhere else a data-parallel fit reduces with one psum: XLA places it
+in a GSPMD program, or the program calls `all_reduce_sum` under
+`shard_map` (Lloyd's fit on row shards).
 
 These wrappers are used inside `shard_map`-ped functions; outside
 `shard_map`, prefer sharding annotations and let XLA insert collectives.
@@ -238,8 +240,16 @@ def ppermute_ring(x, axis_name: str = DATA_AXIS, shift: int = 1):
 
 
 # ---------------------------------------------------------------------------
-# bucketed / ring-pipelined all-reduce (the chunked-AllReduceImpl analogue)
+# bucketed all-reduce (the chunked-AllReduceImpl analogue)
 # ---------------------------------------------------------------------------
+
+#: Bucket size of `all_reduce_sum_chunked`. The reference hand-rolls its
+#: decomposition at 32KB per chunk over netty shuffles (AllReduceImpl.java:
+#: 56-103, tuned for TCP framing); ICI moves MB-class buckets at line rate.
+CHUNK_BYTES = 4 << 20
+#: `sparse_reduce_wins` takes the index-value reduction when its pair bytes
+#: are at most this share of the dense payload.
+SPARSE_THRESHOLD = 0.5
 
 
 def _reduce_bucket_rs_ag(vec, axis_name: str, n: int):
@@ -256,35 +266,6 @@ def _reduce_bucket_rs_ag(vec, axis_name: str, n: int):
     shard = lax.psum_scatter(vec, axis_name, scatter_dimension=0, tiled=True)
     out = lax.all_gather(shard, axis_name, axis=0, tiled=True)
     return out[:m] if pad else out
-
-
-def _reduce_bucket_ring(vec, axis_name: str, n: int):
-    """One bucket via the ring pipeline: n-1 `ppermute` hops rotate every
-    shard's contribution around the ring, and each shard folds the arrivals
-    IN REPLICA ORDER (0..n-1 left-associated — the order the CPU
-    backend's own all-reduce uses, so there the fold is bit-identical to
-    `psum`, pinned by tests/test_collective_chunks.py; a classic
-    rotation-order ring reassociates the sum and is not). The TPU's
-    all-reduce associates differently: on four v5e chips the ring and
-    `psum` differed in 6908 of 16384 elements, by up to 9.4e-5 relative
-    on an operand built to expose reassociation (PR 21), so on chips the
-    ring agrees with `psum` to float rounding only. With several
-    buckets in flight, bucket i+1's hops are dataflow-independent of bucket
-    i's fold — the double-buffered schedule where chunk i+1's transfer
-    overlaps chunk i's compute (the async-collective pass materializes the
-    overlap on hardware)."""
-    idx = lax.axis_index(axis_name)
-    received = [vec]  # received[s] = contribution of replica (idx - s) mod n
-    cur = vec
-    for _ in range(n - 1):
-        cur = lax.ppermute(cur, axis_name, [(i, (i + 1) % n) for i in range(n)])
-        received.append(cur)
-    stacked = jnp.stack(received)  # (n, m)
-    # contribution of replica r sits at arrival slot (idx - r) mod n
-    acc = stacked[jnp.mod(idx - 0, n)]
-    for r in range(1, n):
-        acc = acc + stacked[jnp.mod(idx - r, n)]
-    return acc
 
 
 def _bucket_sizes(total: int, itemsize: int, chunk_bytes) -> list:
@@ -304,26 +285,20 @@ def all_reduce_sum_chunked(
     x,
     axis_name: str = DATA_AXIS,
     chunk_bytes: int = None,
-    ring: bool = None,
 ):
     """Bucketed all-reduce-sum of a pytree: bit-identical to `lax.psum(x)`.
 
     The decomposition the reference hand-rolls at 32KB per chunk
     (AllReduceImpl.java:56-103), rebuilt for ICI: leaves are grouped by
     dtype, flattened, and split into `chunk_bytes`-targeted buckets
-    (config.collective_chunk_bytes when None, default 4MB); each bucket is
-    reduced independently — reduce_scatter+all_gather by default, or the
-    ring-pipelined ppermute fold with `ring=True`
-    (config.collective_ring when None). Because the per-element reduction
-    is unchanged, chunking changes *when bytes move*, never the result;
-    the parity suite pins bit-identity for chunk_bytes ∈ {1KB, 32KB, ∞}
-    on 1/2/8-shard meshes.
+    (`CHUNK_BYTES` when None; 0 or less is one bucket); each bucket is
+    reduced independently by reduce_scatter+all_gather. Because the
+    per-element reduction is unchanged, chunking changes *when bytes move*,
+    never the result; the parity suite pins bit-identity for chunk_bytes ∈
+    {1KB, 32KB, ∞} on 1/2/8-shard meshes.
     """
-    from .. import config
-
-    chunk_bytes = config.resolve_chunk_bytes(chunk_bytes)
-    if ring is None:
-        ring = config.collective_ring
+    if chunk_bytes is None:
+        chunk_bytes = CHUNK_BYTES
     n = axis_size(axis_name)
 
     leaves, treedef = jax.tree_util.tree_flatten(x)
@@ -338,7 +313,6 @@ def all_reduce_sum_chunked(
     for i, leaf in enumerate(leaves):
         by_dtype.setdefault(jnp.asarray(leaf).dtype, []).append(i)
 
-    reduce_bucket = _reduce_bucket_ring if ring else _reduce_bucket_rs_ag
     out_leaves = list(leaves)
     num_buckets = 0
     for dtype, idxs in by_dtype.items():
@@ -347,7 +321,7 @@ def all_reduce_sum_chunked(
         num_buckets += len(sizes)
         reduced, off = [], 0
         for size in sizes:
-            reduced.append(reduce_bucket(flat[off : off + size], axis_name, n))
+            reduced.append(_reduce_bucket_rs_ag(flat[off : off + size], axis_name, n))
             off += size
         flat_red = reduced[0] if len(reduced) == 1 else jnp.concatenate(reduced)
         off = 0
@@ -421,12 +395,10 @@ def sparse_reduce_wins(
 ) -> bool:
     """Trace-time decision: use the index-value reduction when its
     per-shard pair bytes are at most `threshold` × the dense psum payload
-    (config.collective_sparse_threshold when None). Static shapes only —
-    the choice is baked into the compiled program."""
-    from .. import config
-
+    (`SPARSE_THRESHOLD` when None). Static shapes only — the choice is
+    baked into the compiled program."""
     if threshold is None:
-        threshold = config.collective_sparse_threshold
+        threshold = SPARSE_THRESHOLD
     pair_bytes = int(nnz_local) * (4 + int(itemsize))
     return pair_bytes <= threshold * int(dim) * int(itemsize)
 

@@ -1,9 +1,10 @@
-"""Parity + accounting suite for the comm layer (parallel/collectives.py,
-parallel/overlap.py): the chunked/ring/sparse reductions and the
-overlap-scheduled training loops must be BIT-identical to the eager dense
-path — chunking and scheduling change when bytes move, never the result
-(the contract docs/performance.md §7 documents, the analogue of the
-reference's 32KB AllReduceImpl chunks reassembling to the exact sum)."""
+"""Parity + accounting suite for the comm layer (parallel/collectives.py):
+the chunked reduction must be BIT-identical to the monolithic psum —
+chunking changes when bytes move, never the result (the contract
+docs/performance.md §7 documents, the analogue of the reference's 32KB
+AllReduceImpl chunks reassembling to the exact sum) — and the sparse one
+equal to float rounding. A fit on several data shards, whose gradient XLA
+reduces, must agree with the fit on one."""
 
 import jax
 import jax.numpy as jnp
@@ -12,11 +13,10 @@ import pytest
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from flink_ml_tpu import config
 from flink_ml_tpu.obs import tracing
+from flink_ml_tpu.ops import optimizer
 from flink_ml_tpu.parallel import collectives as coll
 from flink_ml_tpu.parallel import mesh as mesh_lib
-from flink_ml_tpu.parallel import overlap
 from flink_ml_tpu.utils import metrics
 
 
@@ -38,13 +38,12 @@ def _flat(tree, rows):
 
 
 class TestChunkedParity:
-    """all_reduce_sum_chunked == lax.psum, bitwise, for every chunk size,
-    ring mode, and shard count."""
+    """all_reduce_sum_chunked == lax.psum, bitwise, for every chunk size
+    and shard count."""
 
     @pytest.mark.parametrize("ndev", [1, 2, 8])
     @pytest.mark.parametrize("chunk_bytes", [1024, 32 * 1024, None])
-    @pytest.mark.parametrize("ring", [False, True])
-    def test_bit_identical_to_psum(self, ndev, chunk_bytes, ring):
+    def test_bit_identical_to_psum(self, ndev, chunk_bytes):
         mesh = _mesh(ndev)
         rng = np.random.default_rng(0)
         # wide dynamic range so any reassociation of the sum would show
@@ -61,9 +60,7 @@ class TestChunkedParity:
 
         whole = np.asarray(run(lambda v: lax.psum(v, "data")))
         chunked = run(
-            lambda v: coll.all_reduce_sum_chunked(
-                _tree(v), chunk_bytes=chunk_bytes, ring=ring
-            )
+            lambda v: coll.all_reduce_sum_chunked(_tree(v), chunk_bytes=chunk_bytes)
         )
         np.testing.assert_array_equal(_flat(chunked, ndev), _flat(_tree(whole), ndev))
 
@@ -152,65 +149,73 @@ class TestSparseParity:
         assert not coll.sparse_reduce_wins(900, 1000, itemsize=4)
 
 
-class TestOverlapSgdParity:
-    """Overlap-scheduled SGD (carry-delayed apply) bit-identical to the
-    eager program — coefficients, final loss, stop epoch — wherever the
-    reduction is the chunked one; to float rounding where the sparse
-    index-value reduction engages (test_sparse)."""
+class TestShardedParity:
+    """A fit on several data shards agrees with the fit on one. With more
+    epochs than the table has batches the walk turns it away, so it takes
+    GSPMD `_sgd_train`, whose gradient XLA reduces with a psum; the fit on
+    one shard is `_sgd_train_flat`. The psum sums the shards' partial
+    gradients in another order than one shard's contraction, so the two
+    agree to float rounding."""
 
-    def _fit(self, mesh, X, y, loss, d, overlap_on, **kw):
-        from flink_ml_tpu.ops.optimizer import SGD
+    def _fit(self, ndev, X, y, loss, d, monkeypatch, **kw):
+        calls = []
+        train = optimizer._sgd_train
 
-        sgd = SGD(collective_overlap=overlap_on, **kw)
-        return sgd.optimize(np.zeros(d, np.float32), X, y, None, loss, mesh=mesh)
+        def spy(*args, **kwargs):
+            calls.append(1)
+            return train(*args, **kwargs)
 
-    @pytest.mark.parametrize("ndev", [1, 2, 8])
-    @pytest.mark.parametrize("loss_name", ["binary_logistic", "least_square"])
-    def test_dense(self, ndev, loss_name):
+        monkeypatch.setattr(optimizer, "_sgd_train", spy)
+        mesh = _mesh(ndev)
+        with mesh_lib.use_mesh(mesh):
+            fitted = optimizer.SGD(**kw).optimize(
+                np.zeros(d, np.float32), X, y, None, loss, mesh=mesh
+            )
+        assert len(calls) == int(ndev > 1)
+        return fitted
+
+    def _agree(self, ndev, X, y, loss, d, monkeypatch, **kw):
+        c1, l1, e1 = self._fit(1, X, y, loss, d, monkeypatch, **kw)
+        cn, ln, en = self._fit(ndev, X, y, loss, d, monkeypatch, **kw)
+        np.testing.assert_allclose(cn, c1, rtol=1e-5)
+        assert en == e1
+        assert ln == pytest.approx(l1, rel=1e-6)
+        return e1
+
+    @staticmethod
+    def _dense(seed):
+        rng = np.random.RandomState(seed)
+        X = rng.randn(256, 10).astype(np.float32)
+        return X, (X @ np.linspace(1, -1, 10) > 0).astype(np.float32)
+
+    @pytest.mark.parametrize("ndev", [2, 4, 8])
+    @pytest.mark.parametrize("loss_name", ["binary_logistic", "least_square", "hinge"])
+    def test_dense(self, ndev, loss_name, monkeypatch):
         from flink_ml_tpu.ops import losses
 
         loss = {
             "binary_logistic": losses.BINARY_LOGISTIC_LOSS,
             "least_square": losses.LEAST_SQUARE_LOSS,
+            "hinge": losses.HINGE_LOSS,
         }[loss_name]
-        mesh = _mesh(ndev)
-        rng = np.random.RandomState(0)
-        X = rng.randn(256, 10).astype(np.float32)
-        y = (X @ np.linspace(1, -1, 10) > 0).astype(np.float32)
+        X, y = self._dense(0)
         kw = dict(max_iter=12, global_batch_size=64, tol=0.0, reg=0.05, elastic_net=0.3)
-        with mesh_lib.use_mesh(mesh):
-            c0, l0, e0 = self._fit(mesh, X, y, loss, 10, False, **kw)
-            c1, l1, e1 = self._fit(mesh, X, y, loss, 10, True, **kw)
-        np.testing.assert_array_equal(c0, c1)
-        assert (l0, e0) == (l1, e1)
+        assert self._agree(ndev, X, y, loss, 10, monkeypatch, **kw) == 12
 
-    @pytest.mark.parametrize("ndev", [2, 8])
-    def test_tol_early_stop(self, ndev):
+    @pytest.mark.parametrize("ndev", [2, 4, 8])
+    def test_tol_early_stop(self, ndev, monkeypatch):
         from flink_ml_tpu.ops.losses import BINARY_LOGISTIC_LOSS
 
-        mesh = _mesh(ndev)
-        rng = np.random.RandomState(3)
-        X = rng.randn(256, 10).astype(np.float32)
-        y = (X @ np.linspace(1, -1, 10) > 0).astype(np.float32)
+        X, y = self._dense(3)
         kw = dict(max_iter=50, global_batch_size=64, tol=0.4)
-        with mesh_lib.use_mesh(mesh):
-            c0, l0, e0 = self._fit(mesh, X, y, BINARY_LOGISTIC_LOSS, 10, False, **kw)
-            c1, l1, e1 = self._fit(mesh, X, y, BINARY_LOGISTIC_LOSS, 10, True, **kw)
-        assert e0 < 50  # the tol stop actually engaged
-        np.testing.assert_array_equal(c0, c1)
-        assert (l0, e0) == (l1, e1)
+        assert self._agree(ndev, X, y, BINARY_LOGISTIC_LOSS, 10, monkeypatch, **kw) < 50
 
-    @pytest.mark.parametrize("ndev", [1, 2, 8])
-    def test_sparse(self, ndev):
-        """Sparse losses: at 1-2 shards the gradient densifies onto the
-        chunked path and the fit is bit-identical; at 8 shards the
-        per-shard pair bytes beat the threshold and the index-value
-        reduction engages, which matches the densified psum to float
-        rounding only (TestSparseParity), so ten epochs agree to ~1e-7
-        relative, pinned here at 1e-5."""
+    @pytest.mark.parametrize("ndev", [2, 4, 8])
+    def test_sparse(self, ndev, monkeypatch):
+        """Padded-CSR rows: each shard scatters its rows' gradient, and the
+        psum adds the shards' vectors."""
         from flink_ml_tpu.ops.losses import SPARSE_BINARY_LOGISTIC_LOSS
 
-        mesh = _mesh(ndev)
         dim, n, nnz = 500, 256, 5
         rng = np.random.RandomState(1)
         indices = rng.randint(0, dim, size=(n, nnz)).astype(np.int32)
@@ -218,50 +223,26 @@ class TestOverlapSgdParity:
         values = rng.rand(n, nnz).astype(np.float32)
         y = (rng.rand(n) > 0.5).astype(np.float32)
         kw = dict(max_iter=10, global_batch_size=64, tol=0.0)
-        with mesh_lib.use_mesh(mesh):
-            c0, l0, e0 = self._fit(
-                mesh, (indices, values), y, SPARSE_BINARY_LOGISTIC_LOSS, dim, False, **kw
-            )
-            c1, l1, e1 = self._fit(
-                mesh, (indices, values), y, SPARSE_BINARY_LOGISTIC_LOSS, dim, True, **kw
-            )
-        assert e0 == e1
-        if ndev <= 2:
-            np.testing.assert_array_equal(c0, c1)
-            assert l0 == l1
-        else:
-            np.testing.assert_allclose(c0, c1, rtol=1e-5, atol=1e-9)
-            assert l0 == pytest.approx(l1, rel=1e-6)
+        self._agree(ndev, (indices, values), y, SPARSE_BINARY_LOGISTIC_LOSS, dim, monkeypatch, **kw)
 
-    def test_sparse_pairs_route_engages(self, mesh8):
-        """The trace-time router picks index-value pairs exactly when the
-        pair bytes beat the threshold at the current shard count."""
-        X_b = (
-            np.zeros((4, 64, 5), np.int32),
-            np.zeros((4, 64, 5), np.float32),
-        )
-        assert overlap.sgd_use_sparse_pairs(X_b, 500, mesh8)  # 8 shards: 320B < 1KB
-        assert not overlap.sgd_use_sparse_pairs(X_b, 500, _mesh(2))  # 1280B > 1KB
-        assert not overlap.sgd_use_sparse_pairs(X_b, 500, _mesh(1))  # nothing to reduce
-        assert not overlap.sgd_use_sparse_pairs(np.zeros((4, 64, 5)), 500, mesh8)  # dense
-
-
-class TestOverlapKMeans:
-    def test_lloyd_bit_identical(self):
+    @pytest.mark.parametrize("ndev", [4, 8])
+    def test_kmeans(self, ndev):
+        """Lloyd's loop over the rows split on several shards: each shard's
+        partial sums and counts, reduced, then the one update. Counts are
+        exact; the centroids agree with one shard's to float rounding."""
         from flink_ml_tpu.models.clustering.kmeans import KMeans
         from flink_ml_tpu.table import Table
 
         rng = np.random.RandomState(0)
         X = np.concatenate([rng.randn(64, 6) + 3, rng.randn(64, 6) - 3]).astype(np.float64)
 
-        def fit():
-            return KMeans().set_k(3).set_seed(2).set_max_iter(7).fit(Table({"features": X}))
+        def fit(n):
+            with mesh_lib.use_mesh(_mesh(n)):
+                return KMeans().set_k(3).set_seed(2).set_max_iter(7).fit(Table({"features": X}))
 
-        m0 = fit()
-        with config.collective_overlap_mode(True):
-            m1 = fit()
-        np.testing.assert_array_equal(m0.centroids, m1.centroids)
-        np.testing.assert_array_equal(m0.weights, m1.weights)
+        one, many = fit(1), fit(ndev)
+        np.testing.assert_array_equal(many.weights, one.weights)
+        np.testing.assert_allclose(many.centroids, one.centroids, rtol=0, atol=1e-5)
 
 
 class TestHostReduceCompileOnce:

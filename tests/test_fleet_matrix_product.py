@@ -17,12 +17,12 @@ wants the form tells `mesh_lib.on_tpu` to say yes.
    bit-parity tests of tests/test_fleet.py and tests/test_fleet_in_place.py
    stand as they were), and a sparse table takes no product form on the chip
    either (its own form is tests/test_fleet_sparse_rows.py's);
-4. the solo programs and the CPU's fleet program lower to the parent's text;
-   the TPU form's fleet program holds two `dot_general` at HIGHEST and no
-   product of the members with the batch.
+4. the solo programs lower to the same text whatever `on_tpu` and
+   `_fleet_multiplies` say, so the product form cannot reach them; the CPU's
+   fleet program forms the members' products by broadcast and holds no
+   `dot_general`; the TPU form's fleet program holds two `dot_general` at
+   HIGHEST and no product of the members with the batch.
 """
-
-import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -319,30 +319,26 @@ def lowered_fleet(program, loss=losses.BINARY_LOGISTIC_LOSS):
     return jax.jit(fn).lower(SHAPE((NB, BATCH, WIDTH), np.float32), *columns_, *rest, SHAPE((), np.int32)).as_text()
 
 
-# sha256 of the StableHLO text each program lowered to at the parent commit
-# (PR 39, read with this file's functions over the parent's package).
-PARENTS_TEXT = {
-    "_sgd_train_flat": "774aece4a622f0deb61cb348b8a4a0501b434623d9baff8c4ffe97157a5323ec",
-    "_sgd_whole_fit": "786fee87e00c9970d24acf637c9cd72990659d403627f5f3cdaea756d4413fb9",
-    "_sgd_train": "e87ddf403a5b5372744086b8ce3abd82687d071b5ffabc00dc3447741edfb4a3",
-    "_sgd_fleet_whole_fit": "9898de4f94a05b8c179cd0326006c19f58af53b40f56d350c79195d0f677edf9",
-    "_sgd_fleet_chunk": "878a607acc3b1aa8c4ba52b5c44322a46fd328af271de6ee8ec1eba53b7c3e5f",
-}
-
-
-def digest(text):
-    return hashlib.sha256(text.encode()).hexdigest()
+def lowered_solo_told(program, says, monkeypatch):
+    """`lowered_solo(program)` traced afresh, with the chip's two predicates
+    answering `says`."""
+    jax.clear_caches()  # nothing traced before is found again
+    with monkeypatch.context() as patched:
+        patched.setattr(mesh_lib, "on_tpu", lambda arr: says)
+        patched.setattr(optimizer, "_fleet_multiplies", lambda X_b, loss_func: says)
+        return lowered_solo(program)
 
 
 @pytest.mark.parametrize("program", ["_sgd_train_flat", "_sgd_whole_fit", "_sgd_train"])
-def test_the_solo_programs_lower_to_the_parents_text(program):
-    assert digest(lowered_solo(program)) == PARENTS_TEXT[program]
+def test_the_solo_programs_never_take_the_product_form(program, monkeypatch):
+    text = lowered_solo_told(program, False, monkeypatch)
+    assert lowered_solo_told(program, True, monkeypatch) == text
+    assert "HIGHEST" not in text
 
 
 @pytest.mark.parametrize("program", ["_sgd_fleet_whole_fit", "_sgd_fleet_chunk"])
-def test_the_cpus_fleet_program_lowers_to_the_parents_text(program):
+def test_the_cpus_fleet_program_forms_the_products_by_broadcast(program):
     text = lowered_fleet(program)
-    assert digest(text) == PARENTS_TEXT[program]
     assert "dot_general" not in text and f"tensor<{MEMBERS}x{BATCH}x{WIDTH}xf32>" in text  # the members' products
 
 

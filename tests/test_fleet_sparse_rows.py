@@ -21,10 +21,8 @@ that wants the form tells `mesh_lib.on_tpu` to say yes.
    and `sparse_epoch.*` tick as docs/observability.md says;
 4. the programs: the row program holds its state [d, N] and gathers rows;
    the solo sparse programs and the CPU's sparse fleet program lower to the
-   parent's text.
+   same text whatever `_fleet_rows` says, and hold no [d, N] state.
 """
-
-import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -463,18 +461,20 @@ def lowered_solo(program, loss=losses.SPARSE_BINARY_LOGISTIC_LOSS):
     ).as_text()
 
 
-# sha256 of the StableHLO text each program lowered to before the fleet had a
-# row form (read with this file's function over that package).
-PARENTS_TEXT = {
-    "_sgd_train_flat": "aece945bd79ff1cfe4d3e000acf8e552148b1cc7bbcc2f41c229395feaab0902",
-    "_sgd_train_flat_planned": "57ca22db98d2a3b31412a745044cdae2cbc9557eeddf50602a2f304f124882f2",
-    "_sgd_fleet_whole_fit": "b03fa7675cd17f6a661c91d4b7006312a2499e0e3991bd92e7b20b88a0fc3c85",
-}
+def lowered_solo_told(program, says, monkeypatch):
+    """`lowered_solo(program)` traced afresh, with `_fleet_rows` answering
+    `says`."""
+    jax.clear_caches()  # nothing traced before is found again
+    with monkeypatch.context() as patched:
+        patched.setattr(optimizer, "_fleet_rows", lambda X_b, loss_func: says)
+        return lowered_solo(program)
 
 
-@pytest.mark.parametrize("program", list(PARENTS_TEXT))
-def test_the_sparse_programs_lower_to_the_parents_text(program):
-    assert hashlib.sha256(lowered_solo(program).encode()).hexdigest() == PARENTS_TEXT[program]
+@pytest.mark.parametrize("program", ["_sgd_train_flat", "_sgd_train_flat_planned", "_sgd_fleet_whole_fit"])
+def test_the_sparse_programs_never_take_the_row_form(program, monkeypatch):
+    text = lowered_solo_told(program, False, monkeypatch)
+    assert lowered_solo_told(program, True, monkeypatch) == text
+    assert "tensor<5000x5xf32>" not in text  # no [dim, N] state of lowered_solo's dim and members
 
 
 def test_a_fleet_under_a_checkpoint_directory_keeps_the_reduce_form(one_device, fits, on_the_chip, monkeypatch, tmp_path):

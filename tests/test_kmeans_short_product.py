@@ -226,14 +226,6 @@ def test_every_fit_looks_again(monkeypatch):
     assert "lloyd.product.short" in again and "lloyd.product.full" in scaled
 
 
-def test_the_overlap_schedule_keeps_the_six_passes(monkeypatch):
-    from flink_ml_tpu import config
-
-    monkeypatch.setattr(config, "collective_overlap", True)
-    _, _, counted = fit(pixel_clusters(), 4, monkeypatch, on_tpu=True)
-    assert counted["lloyd.product.full"] == 1 and "iteration.host_sync.look" not in counted
-
-
 # --- the program ----------------------------------------------------------------
 
 

@@ -224,9 +224,9 @@ class TestTwoAxisAccounting:
         """End-to-end: a 2D fit's trace must account model-axis traffic
         (active-feature assembly) separately from data-axis traffic
         (gradient + loss reduces)."""
-        from flink_ml_tpu.parallel import overlap
+        from flink_ml_tpu.parallel import sgd2d
 
-        overlap.clear_program_cache()  # force a fresh trace to count
+        sgd2d.clear_program_cache()  # force a fresh trace to count
         indices, values, y = _sparse_problem(n=64, d=16, nnz=4, seed=2)
         before = metrics.snapshot()
         _fit(mesh_2d, indices, values, y, 16, max_iter=2)

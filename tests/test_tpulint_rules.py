@@ -1659,8 +1659,8 @@ class TestSpecConsistency:
         assert [f.data[0] for f in report.findings] == ["spec-arity"]
 
     def test_true_negative_reduced_output_and_carry_loop(self, tmp_path):
-        # the overlap.py shape in miniature: sharded batch, carry-delayed
-        # reduce through a lax.while_loop, replicated result
+        # sharded batch, carry-delayed reduce through a lax.while_loop,
+        # replicated result
         report = _run(tmp_path, {
             "models/good.py": """
                 import jax.numpy as jnp
@@ -1840,8 +1840,8 @@ class TestPrecisionDeterminism:
         assert report.findings[0].data[2] == "float16"
 
     def test_true_negative_f32_accumulator_cast(self, tmp_path):
-        # the overlap.py tol-check shape: astype(jnp.float32) on the two
-        # scalars is a WIDENING (or no-op) cast and must stay legal
+        # astype(jnp.float32) on a loss scalar before its reduce is a
+        # WIDENING (or no-op) cast and must stay legal
         report = _run(tmp_path, {
             "models/good.py": """
                 import jax.numpy as jnp
