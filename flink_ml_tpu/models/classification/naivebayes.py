@@ -9,12 +9,40 @@ pi[i] = log(count(label i) * featureSize + smoothing)
 NaiveBayesModel.java calculateProb (sum of per-feature log-probs + pi,
 argmax by label) and NaiveBayesModelData.java:57-69. Unseen feature values
 at predict time raise, as the reference's map lookup does.
+
+The model is held as arrays: `theta_array` [labels, categories], each
+feature's seen values in ascending order in `categories`, feature j's at
+`offsets[j]:offsets[j + 1]`, beside `pi` and `labels`. The reference's
+[label][feature] -> {value: log-probability} maps (`theta`, the model data
+table, the Java codec) are built from them only when asked for.
+
+A fit over a float32 device table counts on the device, each feature by one
+of two routes, chosen by a probe of the table in one round trip:
+
+- a feature whose values are all whole numbers in [0, 2**24) (float32 holds
+  each exactly: dictionary indices) is counted into bins 0..max; with at
+  most MATCH_MAX_CATEGORIES bins by compares (`ops.bincount.
+  label_match_counts`), with more by a scatter-add (`ops.bincount.
+  label_bincount`), the INDEXED route. No column is sorted (XLA's TPU
+  scatter sorts its own keys, block by block); its categories are the bins
+  that counted a row;
+- any other feature with at most MATCH_MAX_CATEGORIES distinct values finds
+  them by a column sort and is counted by compares.
+
+Counts are exact int32, and come back whole in one read. The host takes each
+feature's seen bins and their theta in float64 from the exact counts, rounded
+once to float32, on every route, so that every route gives the same bits:
+`jnp.log` in float32 on a TPU v5e reads up to 1.1e-4 off float64 (up to
+~500 ulps, read alone over every count below 2**24), more than one row added
+to or lost from a category of fewer than 65,536 rows moves a theta. A table
+the device routes cannot take (another dtype, labels float32 does not hold,
++inf, a feature of more distinct values that are not indices) is counted on
+the host, into the same arrays.
 """
 
 from __future__ import annotations
 
-import math
-from typing import Dict, List
+from typing import Dict, List, NamedTuple, Tuple
 
 import numpy as np
 
@@ -22,22 +50,53 @@ from ...api import Estimator, Model
 from ...common.param import HasFeaturesCol, HasLabelCol, HasPredictionCol
 from ...param import DoubleParam, ParamValidators, StringParam
 from ...table import Table, as_dense_matrix
-from ...utils import read_write
+from ...utils import metrics, read_write
 from ...utils.param_utils import update_existing_params
 
 
-# Largest per-feature category count served by the device kernels; bigger
-# category sets fall back to the host path (the (chunk, d, m) compare
-# volume grows linearly in m).
-DEVICE_MAX_CATEGORIES = 512
+# Largest number of categories a feature is counted by compares (fit), and
+# of categories a feature may have for the device scoring kernel
+# (transform): the (rows, categories) compare volume grows with it.
+MATCH_MAX_CATEGORIES = 512
+# Float32 holds every whole number below this exactly: the indexed route's
+# bound on a feature's values.
+INDEX_LIMIT = 1 << 24
+# Labels that are whole numbers below this are found by the probe's
+# compares; any others by two sorts of the labels.
+LABEL_RANGE = 64
+# Rows a count step reads; the last step ends at the table's last row.
+COUNT_BLOCK_ROWS = 1 << 18
+# Bins the host takes at a time when it builds a model from counts.
+HOST_BLOCK = 1 << 20
 # Bound on chunk * d * m elements per device program (~2 GB of f32 temps).
 _CHUNK_BUDGET = 5 * 10**8
 
 
 def _nb_chunk_rows(d: int, m: int) -> int:
     # cap at 2^24 rows so per-chunk f32 count accumulation stays integer-
-    # exact regardless of d * m (cross-chunk sums are f64 on host)
+    # exact regardless of d * m
     return max(1, min(_CHUNK_BUDGET // max(1, d * m), 1 << 24))
+
+
+def bucket(n: int) -> int:
+    """`n` rounded up to one of eight sizes an octave (a multiple of 2**(k-3)
+    for n in (2**(k-1), 2**k]; every n up to 16 is its own size): a
+    feature's bins take these sizes, so that a log whose largest index grows
+    a little compiles no new program."""
+    step = 1 << max(0, (max(n, 1) - 1).bit_length() - 4)
+    return -(-n // step) * step
+
+
+class _Field(NamedTuple):
+    """How one feature is counted: `form` "scatter" (ids into bins by the
+    scatter-add), "range" (ids against 0..width-1 by compares) or "sorted"
+    (values against its sorted distinct values, row `row` of the staged
+    candidates); its bins are offset..offset+width-1 of the count."""
+
+    form: str
+    offset: int
+    width: int
+    row: int = -1
 
 
 def _nb_sorted_cat_counts_impl(X):
@@ -67,15 +126,99 @@ def _nb_extract_cats_impl(Xs, m_max: int):
     return jnp.where(valid, vals, jnp.inf).T  # (d, m_max)
 
 
-def _nb_count_chunk_impl(Xc, yc, cats, labels):
-    """(L, d, m) co-occurrence counts of one row chunk: both one-hots are
-    lane-broadcast compares, the contraction over rows is an MXU einsum —
-    no gathers, no host loops."""
+def _nb_probe_impl(X, y):
+    """One float32 vector the fit's routes are chosen by: NaN in the labels,
+    NaN in the features, +inf in the features, whether every label is a
+    whole number below LABEL_RANGE, then for each such number whether a
+    label is it, for each feature whether every value is an index (a whole
+    number in [0, 2**24)), and each feature's largest value. Nothing is
+    sorted: a sort of the table's rows costs the chip's compiler minutes."""
     import jax.numpy as jnp
 
-    eq = (Xc[:, :, None] == cats[None, :, :]).astype(jnp.float32)
-    Y1 = (yc[:, None] == labels[None, :]).astype(jnp.float32)
-    return jnp.einsum("cdm,cl->ldm", eq, Y1), Y1.sum(axis=0)
+    index = jnp.all((X >= 0) & (X < INDEX_LIMIT) & (X == jnp.floor(X)), axis=0)
+    small = jnp.all((y >= 0) & (y < LABEL_RANGE) & (y == jnp.floor(y)))
+    present = jnp.any(y[:, None] == jnp.arange(LABEL_RANGE, dtype=y.dtype)[None, :], axis=0)
+    flags = jnp.stack(
+        [jnp.isnan(y).any(), jnp.isnan(X).any(), jnp.isposinf(X).any(), small]
+    ).astype(jnp.float32)
+    return jnp.concatenate(
+        [flags, present.astype(jnp.float32), index.astype(jnp.float32), jnp.max(X, axis=0).astype(jnp.float32)]
+    )
+
+
+def _candidates(field: _Field, sorted_cats):
+    import jax.numpy as jnp
+
+    if field.form == "sorted":
+        return sorted_cats[field.row, : field.width]
+    return jnp.arange(field.width, dtype=jnp.float32)
+
+
+def _nb_count_impl(X, y, labels, sorted_cats, plan: Tuple[_Field, ...], block_rows: int):
+    """Exact int32 counts [labels, bins] of every (label, feature value)
+    pair of the table, in one pass of row blocks (one program for every
+    block, the last one too: each copy of the scatter costs the chip's
+    compiler tens of seconds), as one int32 vector with what the host needs
+    beside them: [the labels' float32 bits | the sorted features' candidates'
+    float32 bits | the counts, features in order]. The features counted by
+    compares add into a carry of their own, a few hundred bins wide."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ...ops.bincount import label_bincount, label_match_counts
+
+    n = X.shape[0]
+    num_labels = labels.shape[0]
+    scattered = [j for j, f in enumerate(plan) if f.form == "scatter"]
+    matched = [j for j, f in enumerate(plan) if f.form != "scatter"]
+    # each form's carry holds its features' bins side by side
+    within = {}
+    for group in (scattered, matched):
+        start = 0
+        for j in group:
+            within[j] = start
+            start += plan[j].width
+    scatter_offsets = jnp.asarray([within[j] for j in scattered], jnp.int32)
+    candidates = {j: _candidates(plan[j], sorted_cats) for j in matched}
+    block = min(block_rows, n)
+
+    def step(k, carry):
+        wide, narrow = carry
+        # the last block ends at the last row; the rows it shares with the
+        # block before are counted there and get the label past the end,
+        # which no bin holds
+        start = jnp.minimum(k * block, n - block)
+        Xb = lax.dynamic_slice_in_dim(X, start, block, axis=0)
+        yb = lax.dynamic_slice_in_dim(y, start, block, axis=0)
+        fresh = start + jnp.arange(block) >= k * block
+        label_idx = jnp.where(fresh, jnp.searchsorted(labels, yb), num_labels).astype(jnp.int32)
+        if scattered:
+            ids = jnp.stack([Xb[:, j] for j in scattered], axis=1).astype(jnp.int32) + scatter_offsets
+            wide = label_bincount(wide, ids, label_idx)
+        if matched:
+            narrow = narrow + jnp.concatenate(
+                [label_match_counts(Xb[:, j], candidates[j], label_idx, num_labels) for j in matched], axis=1
+            )
+        return wide, narrow
+
+    carry = tuple(
+        jnp.zeros((num_labels, sum(plan[j].width for j in group)), jnp.int32) for group in (scattered, matched)
+    )
+    wide, narrow = lax.fori_loop(0, -(-n // block), step, carry)
+    bins = jnp.concatenate(
+        [
+            (wide if f.form == "scatter" else narrow)[:, within[j] : within[j] + f.width]
+            for j, f in enumerate(plan)
+        ],
+        axis=1,
+    )
+    return jnp.concatenate(
+        [
+            lax.bitcast_convert_type(labels, jnp.int32),
+            lax.bitcast_convert_type(sorted_cats, jnp.int32).reshape(-1),
+            bins.reshape(-1),
+        ]
+    )
 
 
 def _nb_predict_chunk_impl(Xc, cats, logp, pi, labels):
@@ -131,9 +274,98 @@ from ...utils.lazyjit import lazy_jit
 
 _nb_sorted_cat_counts = lazy_jit(_nb_sorted_cat_counts_impl)
 _nb_extract_cats = lazy_jit(_nb_extract_cats_impl, static_argnames=("m_max",))
-_nb_count_chunk = lazy_jit(_nb_count_chunk_impl)
+_nb_probe = lazy_jit(_nb_probe_impl)
+_nb_count = lazy_jit(_nb_count_impl, static_argnames=("plan", "block_rows"))
 _nb_predict_chunk = lazy_jit(_nb_predict_chunk_impl)
 _nb_unpack_model = lazy_jit(_nb_unpack_model_impl, static_argnames=("d", "m", "L"))
+
+
+class _Theta:
+    """float32 theta of one feature's counts, taken in float64 as
+    NaiveBayes.java takes it and rounded once: log(count + smoothing) -
+    log(label rows + smoothing * categories), `categories` the feature's
+    number of seen categories. Each label's value is computed once for every
+    count from 0 to the feature's largest, `top`, and looked up (a log's
+    counts are mostly small, and the same few values repeat), unless the
+    table would outgrow the counts. `fill` works in blocks of HOST_BLOCK
+    counts, so that its temporaries are reused and not fresh memory (a TPU
+    v5e host writes fresh memory at about 1 GB/s)."""
+
+    def __init__(self, label_rows, categories: int, smoothing: float, top: int, size: int):
+        self.smoothing = smoothing
+        self.below = np.log(np.asarray(label_rows, np.float64) + smoothing * categories)
+        self.table = None
+        if top < max(size, 1 << 16):
+            with np.errstate(divide="ignore"):  # smoothing 0: log(0) = -inf, as Java's Math.log
+                self.table = (np.log(np.arange(top + 1) + smoothing)[None, :] - self.below[:, None]).astype(np.float32)
+
+    def fill(self, label: int, counts, out) -> None:
+        """theta of one label's counts [n] into out [n]."""
+        for start in range(0, counts.size, HOST_BLOCK):
+            part, into = counts[start : start + HOST_BLOCK], out[start : start + HOST_BLOCK]
+            if self.table is None:
+                with np.errstate(divide="ignore"):
+                    into[...] = np.log(part + self.smoothing) - self.below[label]
+            else:
+                np.take(self.table[label], part, out=into, mode="clip")  # every count is in the table
+
+
+def _log_priors(label_rows, rows: int, features: int, smoothing: float) -> np.ndarray:
+    label_rows = np.asarray(label_rows, np.float64)
+    with np.errstate(divide="ignore"):
+        return np.log(label_rows * features + smoothing) - np.log(
+            rows * features + label_rows.size * smoothing
+        )
+
+
+def _arrays_from_maps(theta) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """(theta_array float64, categories, offsets) of the nested
+    [label][feature] -> {value: log-probability} maps."""
+    num_labels = len(theta)
+    num_features = len(theta[0]) if num_labels else 0
+    categories, columns = [], []
+    for j in range(num_features):
+        keys = sorted(theta[0][j])
+        try:
+            columns.append(np.asarray([[theta[i][j][k] for k in keys] for i in range(num_labels)], np.float64))
+        except KeyError as e:
+            raise ValueError(f"NaiveBayes theta: feature {j} lacks category {e.args[0]} under some label") from None
+        if any(len(theta[i][j]) != len(keys) for i in range(num_labels)):
+            raise ValueError(f"NaiveBayes theta: feature {j} has other categories under some label than under label 0")
+        categories.append(np.asarray(keys, np.float64))
+    offsets = np.concatenate([[0], np.cumsum([c.size for c in categories], dtype=np.int64)]).astype(np.int64)
+    if not categories:
+        return np.zeros((num_labels, 0)), np.zeros(0), offsets
+    return np.concatenate(columns, axis=1).reshape(num_labels, -1), np.concatenate(categories), offsets
+
+
+def _model_from_counts(host, num_labels: int, sorted_shape, plan: Tuple[_Field, ...], rows: int, smoothing: float):
+    """Model arrays from the count program's vector (`_nb_count_impl`): each
+    feature's seen bins, ascending, and their theta."""
+    labels = host[:num_labels].view(np.float32)
+    taken = num_labels + int(np.prod(sorted_shape))
+    sorted_cats = host[num_labels:taken].view(np.float32).reshape(sorted_shape)
+    bins = host[taken:].reshape(num_labels, -1)
+    seen = bins.any(axis=0)
+    m = [int(np.count_nonzero(seen[f.offset : f.offset + f.width])) for f in plan]
+    offsets = np.concatenate([[0], np.cumsum(m)]).astype(np.int64)
+    first = plan[0]
+    label_rows = bins[:, first.offset : first.offset + first.width].sum(axis=1, dtype=np.int64)
+    categories = np.empty(offsets[-1], np.float32)
+    theta_array = np.empty((num_labels, offsets[-1]), np.float32)
+    for j, f in enumerate(plan):
+        field = bins[:, f.offset : f.offset + f.width]
+        theta = _Theta(label_rows, m[j], smoothing, int(field.max()), m[j])
+        at = offsets[j]
+        # in blocks, so that the host's temporaries are reused (`_Theta`)
+        for start in range(0, f.width, HOST_BLOCK):
+            local = np.flatnonzero(seen[f.offset + start : f.offset + min(start + HOST_BLOCK, f.width)]) + start
+            k = local.size
+            categories[at : at + k] = sorted_cats[f.row, local] if f.form == "sorted" else local
+            for i in range(num_labels):
+                theta.fill(i, np.take(field[i], local), theta_array[i, at : at + k])
+            at += k
+    return theta_array, categories, offsets, _log_priors(label_rows, rows, len(plan), smoothing), labels
 
 
 class NaiveBayesModelParams(HasFeaturesCol, HasPredictionCol):
@@ -164,23 +396,59 @@ class NaiveBayesParams(NaiveBayesModelParams, HasLabelCol):
 
 
 class NaiveBayesModel(Model, NaiveBayesModelParams):
+    """`theta_array` [labels, categories] (float32 from a fit, float64 as a
+    saved reference model or model data table gives it), `categories` (each
+    feature's seen values ascending, in the dtype of the features they were
+    fitted on), `offsets` (feature j's are offsets[j]:offsets[j + 1]),
+    `pi` [labels] float64, `labels` [labels] float64."""
+
     fusable = False
     fusable_reason = "exactness contract needs host f64 rescoring of near-tie rows and a data-dependent unseen-category error, both mid-transform readbacks"
 
     def __init__(self):
-        self.theta: List[List[Dict[float, float]]] = None  # [label][feature] -> {value: logp}
+        self.theta_array: np.ndarray = None
+        self.categories: np.ndarray = None
+        self.offsets: np.ndarray = None
         self.pi: np.ndarray = None  # (numLabels,) log priors
         self.labels: np.ndarray = None  # (numLabels,) label values
+        self._maps = None  # the [label][feature] maps, once asked for
         self._device_tensors = None  # cached (cats, logp, pi, labels) on device
+
+    def _set_arrays(self, theta_array, categories, offsets, pi, labels) -> "NaiveBayesModel":
+        self.theta_array = theta_array
+        self.categories = categories
+        self.offsets = np.asarray(offsets, np.int64)
+        self.pi = np.asarray(pi, dtype=np.float64)
+        self.labels = np.asarray(labels, dtype=np.float64)
+        self._maps = None
+        self._device_tensors = None
+        return self
+
+    @property
+    def theta(self) -> List[List[Dict[float, float]]]:
+        """The reference's form, [label][feature] -> {value: log-probability},
+        built from the arrays on first use."""
+        if self._maps is None and self.theta_array is not None:
+            bounds = list(zip(self.offsets[:-1].tolist(), self.offsets[1:].tolist()))
+            values = [self.categories[a:b].tolist() for a, b in bounds]
+            self._maps = [
+                [dict(zip(values[j], row[a:b].tolist())) for j, (a, b) in enumerate(bounds)]
+                for row in self.theta_array
+            ]
+        return self._maps
+
+    def _feature(self, j: int):
+        """(values, theta [labels, values]) of feature j."""
+        a, b = int(self.offsets[j]), int(self.offsets[j + 1])
+        return self.categories[a:b], self.theta_array[:, a:b]
 
     def set_model_data(self, *inputs: Table) -> "NaiveBayesModel":
         (model_data,) = inputs
         row = model_data.collect()[0]
-        self.theta = row["theta"]
-        self.pi = np.asarray(row["piArray"].to_array(), dtype=np.float64)
-        self.labels = np.asarray(row["labels"].to_array(), dtype=np.float64)
-        self._device_tensors = None
-        return self
+        theta_array, categories, offsets = _arrays_from_maps(row["theta"])
+        return self._set_arrays(
+            theta_array, categories, offsets, row["piArray"].to_array(), row["labels"].to_array()
+        )
 
     def get_model_data(self) -> List[Table]:
         from ...linalg import DenseVector
@@ -196,18 +464,24 @@ class NaiveBayesModel(Model, NaiveBayesModelParams):
         ]
 
     def _theta_tensors(self):
-        """(cats (d, m_max) +inf-padded, logp (d, m_max, L)) views of the
-        per-feature log-prob dictionaries for the device kernel."""
+        """(cats (d, m_max) +inf-padded, logp (d, m_max, L)) for the device
+        kernel, or (None, None) where the model is scored on the host: a
+        feature of more than MATCH_MAX_CATEGORIES categories, or values or
+        labels float32 does not hold exactly."""
         num_labels = len(self.labels)
-        d = len(self.theta[0])
-        per_col = [np.asarray(sorted(self.theta[0][j]), np.float64) for j in range(d)]
-        m_max = max(v.size for v in per_col)
-        cats = np.full((d, m_max), np.inf, np.float32)
-        logp = np.zeros((d, m_max, num_labels), np.float32)
+        widths = np.diff(self.offsets)
+        d = widths.size
+        m_max = int(widths.max()) if d else 0
+        if not 0 < m_max <= MATCH_MAX_CATEGORIES:
+            return None, None
         labels_cast = self.labels.astype(np.float32)
         if not np.array_equal(labels_cast.astype(np.float64), self.labels):
             return None, None  # labels not f32-exact: decode would round
-        for j, values in enumerate(per_col):
+        cats = np.full((d, m_max), np.inf, np.float32)
+        logp = np.zeros((d, m_max, num_labels), np.float32)
+        for j in range(d):
+            values, theta = self._feature(j)
+            values = np.asarray(values, np.float64)
             if not np.isfinite(values).all():
                 # +inf IS the padding sentinel: a trained +inf category
                 # would also match every padding slot of its column (logp 0
@@ -219,12 +493,10 @@ class NaiveBayesModel(Model, NaiveBayesModelParams):
                 # categories not exactly f32-representable: the device
                 # compare would accept/merge values the host path rejects
                 return None, None
-            if np.unique(cast).size != cast.size:
+            if np.any(np.diff(cast) <= 0):
                 return None, None  # f32 merges distinct categories: host path
             cats[j, : values.size] = cast
-            for r, v in enumerate(values):
-                for i in range(num_labels):
-                    logp[j, r, i] = self.theta[i][j][float(v)]
+            logp[j, : values.size, :] = theta.T
         return cats, logp
 
     def transform(self, *inputs: Table) -> List[Table]:
@@ -327,15 +599,11 @@ class NaiveBayesModel(Model, NaiveBayesModelParams):
     def _predict_host(self, X: np.ndarray) -> np.ndarray:
         """Reference-precision (float64) scoring, columnwise on host."""
         n, d = X.shape
-        num_labels = len(self.labels)
         probs = np.tile(self.pi, (n, 1))  # (n, numLabels)
         for j in range(d):
-            # columnwise: sorted category values + (num_values, num_labels)
-            # log-prob matrix, then one searchsorted gather per feature
-            values = np.asarray(sorted(self.theta[0][j]), dtype=np.float64)
-            logp = np.stack(
-                [[self.theta[i][j][v] for i in range(num_labels)] for v in values]
-            )  # (num_values, num_labels)
+            # columnwise: the feature's ascending values and its
+            # (labels, values) log-probabilities, one searchsorted gather
+            values, theta = self._feature(j)
             col = X[:, j]
             pos = np.searchsorted(values, col)
             pos_clipped = np.clip(pos, 0, values.size - 1)
@@ -345,13 +613,15 @@ class NaiveBayesModel(Model, NaiveBayesModelParams):
                 raise ValueError(
                     f"Feature value {bad} in column {j} was not seen during training"
                 )
-            probs += logp[pos_clipped]
+            probs += theta[:, pos_clipped].T
         return self.labels[np.argmax(probs, axis=1)]
 
     def _save_extra(self, path: str) -> None:
         read_write.save_model_arrays(
             path,
-            theta=np.asarray(self.theta, dtype=object),
+            theta_array=self.theta_array,
+            categories=self.categories,
+            offsets=self.offsets,
             piArray=self.pi,
             labels=self.labels,
         )
@@ -362,26 +632,60 @@ class NaiveBayesModel(Model, NaiveBayesModelParams):
         arrays = read_write.load_arrays_or_reference(
             path, javacodec.load_reference_naivebayes
         )
-        self.theta = [list(row) for row in arrays["theta"]]
-        self.pi = arrays["piArray"]
-        self.labels = arrays["labels"]
-        self._device_tensors = None
+        if "theta_array" in arrays:
+            model = (arrays["theta_array"], arrays["categories"], arrays["offsets"])
+        else:  # saved as the [label][feature] maps
+            model = _arrays_from_maps([list(row) for row in arrays["theta"]])
+        self._set_arrays(*model, arrays["piArray"], arrays["labels"])
+
+
+class _Staged(NamedTuple):
+    """A device table the device routes take, as the probe and the sort
+    left it."""
+
+    X: object
+    y: object
+    labels: object
+    sorted_cats: object
+    plan: Tuple[_Field, ...]
 
 
 class NaiveBayes(Estimator, NaiveBayesParams):
     checkpointable = False
     checkpoint_reason = "single-pass label/feature count aggregation; a restart recomputes the fit"
-    def _fit_stats_device(self, X, y):
-        """(labels, per-label counts, per-column category values, per-pair
-        co-occurrence counts) aggregated on device: column sorts for the
-        category sets, lane-broadcast one-hot compares + an MXU einsum for
-        the counts. Only the small (L, d, m) statistics cross to the host
-        (at the benchmark's 1M x 10 that is 100 floats vs an 80 MB matrix
-        pull + per-label np.unique loops). Exact: every count is an
-        integer < 2^24 accumulated in f32 per chunk, summed in f64 across
-        chunks. Returns None when a column's category count exceeds the
-        device bound. Matches NaiveBayes.java GenerateModelFunction's
-        aggregation exactly."""
+
+    def fit(self, *inputs: Table) -> NaiveBayesModel:
+        import jax
+
+        from ...obs import tracing
+
+        (table,) = inputs
+        with tracing.phase("fit.extract"):
+            smoothing = self.get_smoothing()
+            X = as_dense_matrix(table.column(self.get_features_col()), allow_device=True)
+            y = table.column(self.get_label_col())
+            n, d = X.shape
+        metrics.inc_counter("naivebayes.fits")
+        metrics.inc_counter("naivebayes.rows", n)
+        with tracing.phase("fit.stage"):
+            staged = self._stage(X, y) if isinstance(X, jax.Array) else None
+        if staged is None:
+            metrics.inc_counter("naivebayes.fields.host", d)
+            arrays = self._fit_host(X, y, smoothing)
+        else:
+            arrays = self._fit_device(staged, smoothing)
+        model = NaiveBayesModel()._set_arrays(*arrays)
+        metrics.inc_counter("naivebayes.categories", int(model.offsets[-1]))
+        update_existing_params(model, self)
+        return model
+
+    @staticmethod
+    def _stage(X, y):
+        """The plan of a device fit, or None where the table takes the host
+        route. One round trip reads the probe; a second one, only where a
+        feature is not an index, the distinct counts of those features.
+        NaN labels or features raise here, as on the host route: a NaN
+        "category" can never be matched at predict time (NaN != NaN)."""
         import jax
         import jax.numpy as jnp
 
@@ -389,11 +693,8 @@ class NaiveBayes(Estimator, NaiveBayesParams):
         from ...utils.packing import packed_device_get
 
         n, d = X.shape
-        if n == 0:
-            return None
-        if X.dtype != jnp.float32:
+        if n == 0 or d == 0 or X.dtype != jnp.float32:
             return None  # f64 device input (x64 on): f32 cast could merge
-        X32 = X
         if isinstance(y, jax.Array):
             if y.dtype != jnp.float32:
                 return None
@@ -401,107 +702,77 @@ class NaiveBayes(Estimator, NaiveBayesParams):
         else:
             y_np = np.asarray(y)
             y32 = y_np.astype(np.float32)
-            if not np.array_equal(
-                y32.astype(y_np.dtype), y_np, equal_nan=True
-            ):
+            if not np.array_equal(y32.astype(y_np.dtype), y_np, equal_nan=True):
                 return None  # labels not f32-exact: counts would merge
             y_dev = jnp.asarray(y32)
-        Xs, m_per_col = _nb_sorted_cat_counts(X32)
-        # round trip 1: the scalars the later programs are shaped by. The
-        # feature-NaN probe rides the same transfer: NaN features would
-        # silently inflate the category sets (NaN != NaN makes every NaN a
-        # distinct "category" through the sorted-compare counting), so they
-        # are rejected here exactly like NaN labels.
-        nan_flag, x_nan_flag, x_inf_flag, m_max_arr, nunique = packed_device_get(
-            jnp.isnan(y_dev).any().astype(jnp.float32),
-            jnp.isnan(X32).any().astype(jnp.float32),
-            jnp.isposinf(X32).any().astype(jnp.float32),
-            jnp.max(m_per_col).astype(jnp.float32),
-            _nunique_device(y_dev).astype(jnp.float32),
-        )
-        if bool(nan_flag):
+        (probe,) = packed_device_get(_nb_probe(X, y_dev), sync_kind="probe")
+        if probe[0]:
             raise ValueError("Label column contains null/NaN values")
-        if bool(x_nan_flag):
+        if probe[1]:
             raise ValueError("Feature column contains null/NaN values")
-        if bool(x_inf_flag):
-            # +inf doubles as the category-padding sentinel in the count
-            # kernel — a real +inf feature would co-count with every padding
-            # slot. The host path trains inf categories exactly (and the
+        if probe[2]:
+            # +inf doubles as the category-padding sentinel of the compare
+            # kernels. The host path trains inf categories exactly (and the
             # predict-side _theta_tensors guard keeps serving them on host).
             return None
-        m_max = int(m_max_arr)
-        if m_max > DEVICE_MAX_CATEGORIES:
-            return None
-        cats = _nb_extract_cats(Xs, m_max)  # (d, m_max), +inf padded
-        num_labels = int(nunique)
-        labels_dev = _unique_device(y_dev, num_labels)
-        chunk = _nb_chunk_rows(d, m_max)
-        counts = np.zeros((num_labels, d, m_max), np.float64)
-        label_counts_arr = np.zeros(num_labels, np.float64)
-        cats_h = m_h = labels_h = None
-        for s in range(0, n, chunk):
-            c, lc = _nb_count_chunk(
-                X32[s : s + chunk], y_dev[s : s + chunk], cats, labels_dev
-            )
-            if cats_h is None:
-                # round trip 2 (once): chunk stats + model-shaping arrays
-                c_h, lc_h, cats_h, m_h, labels_h = packed_device_get(
-                    c, lc, cats, m_per_col.astype(jnp.float32), labels_dev
-                )
+        labels = np.flatnonzero(probe[4 : 4 + LABEL_RANGE]).astype(np.float32) if probe[3] else None
+        index = probe[4 + LABEL_RANGE : 4 + LABEL_RANGE + d].astype(bool)
+        top = probe[4 + LABEL_RANGE + d :].astype(np.int64)
+        others = np.flatnonzero(~index)
+        sorted_cats, widths = None, {}
+        if others.size:
+            Xs, distinct = _nb_sorted_cat_counts(X[:, jnp.asarray(others)])
+            (distinct,) = packed_device_get(distinct, sync_kind="sort")
+            if int(distinct.max()) > MATCH_MAX_CATEGORIES:
+                return None
+            width = bucket(int(distinct.max()))
+            sorted_cats = _nb_extract_cats(Xs, width)
+            widths = {int(j): width for j in others}
+        plan, offset = [], 0
+        for j in range(d):
+            if index[j]:
+                cap = int(top[j]) + 1
+                form = "range" if cap <= MATCH_MAX_CATEGORIES else "scatter"
+                field = _Field(form, offset, bucket(cap))
             else:
-                c_h, lc_h = packed_device_get(c, lc)
-            counts += np.asarray(c_h, np.float64)
-            label_counts_arr += np.asarray(lc_h, np.float64)
-        return (
-            np.asarray(labels_h, np.float64),
-            label_counts_arr,
-            np.asarray(cats_h, np.float64),
-            np.asarray(m_h, np.int64),
-            counts,
+                field = _Field("sorted", offset, widths[j], int(np.searchsorted(others, j)))
+            plan.append(field)
+            offset += field.width
+        if labels is None:  # labels the probe's compares cannot find: sorted
+            (num_labels,) = packed_device_get(_nunique_device(y_dev), sync_kind="sort")
+            labels = _unique_device(y_dev, int(num_labels))
+        if (labels.shape[0] + 1) * offset >= 2**31:
+            return None  # int32 keys of the scatter
+        if sorted_cats is None:
+            sorted_cats = jnp.zeros((0, 0), jnp.float32)
+        return _Staged(X, y_dev, labels, sorted_cats, tuple(plan))
+
+    @staticmethod
+    def _fit_device(staged: _Staged, smoothing: float):
+        """Model arrays from the device routes: the count program, the one
+        read of its counts, and on the host each feature's seen bins and
+        their theta."""
+        from ...obs import tracing
+        from ...parallel import dispatch
+
+        plan = staged.plan
+        metrics.inc_counter("naivebayes.fields.indexed", sum(f.form == "scatter" for f in plan))
+        metrics.inc_counter("naivebayes.fields.compare", sum(f.form != "scatter" for f in plan))
+        metrics.inc_counter("naivebayes.bins", sum(f.width for f in plan))
+        out = dispatch.timed_dispatch(
+            _nb_count, staged.X, staged.y, staged.labels, staged.sorted_cats, plan, COUNT_BLOCK_ROWS
         )
+        with tracing.phase("fit.readback", marks=False):
+            host = tracing.sync("fit", out)
+        rows = staged.X.shape[0]
+        return _model_from_counts(host, int(staged.labels.shape[0]), staged.sorted_cats.shape, plan, rows, smoothing)
 
-    def fit(self, *inputs: Table) -> NaiveBayesModel:
-        import jax
-
-        (table,) = inputs
-        smoothing = self.get_smoothing()
-        X = as_dense_matrix(table.column(self.get_features_col()), allow_device=True)
-        n, d = X.shape
-        stats = None
-        if isinstance(X, jax.Array):
-            stats = self._fit_stats_device(X, table.column(self.get_label_col()))
-        if stats is not None:
-            labels_h, label_counts_arr, cats_h, m_h, counts = stats
-            num_labels = len(labels_h)
-            theta: List[List[Dict[float, float]]] = []
-            for i in range(num_labels):
-                label_theta = []
-                for j in range(d):
-                    m_j = int(m_h[j])
-                    theta_log = math.log(label_counts_arr[i] + smoothing * m_j)
-                    label_theta.append(
-                        {
-                            float(cats_h[j, r]): math.log(counts[i, j, r] + smoothing)
-                            - theta_log
-                            for r in range(m_j)
-                        }
-                    )
-                theta.append(label_theta)
-            pi_log = math.log(n * d + num_labels * smoothing)
-            pi = np.asarray(
-                [
-                    math.log(label_counts_arr[i] * d + smoothing) - pi_log
-                    for i in range(num_labels)
-                ]
-            )
-            model = NaiveBayesModel()
-            model.theta = theta
-            model.pi = pi
-            model.labels = labels_h
-            update_existing_params(model, self)
-            return model
+    @staticmethod
+    def _fit_host(X, y, smoothing: float):
+        """Model arrays from the host: np.unique of the labels and of each
+        feature, one bincount a feature."""
         X = np.asarray(X)
-        y = np.asarray(table.column(self.get_label_col()), dtype=np.float64)
+        y = np.asarray(y, dtype=np.float64)
         if np.isnan(y).any():
             raise ValueError("Label column contains null/NaN values")
         if np.isnan(X).any():
@@ -509,36 +780,26 @@ class NaiveBayes(Estimator, NaiveBayesParams):
             # matched at predict time (NaN != NaN), so training would bake
             # in unreachable probability mass — reject like NaN labels
             raise ValueError("Feature column contains null/NaN values")
-        labels = np.unique(y)
-        num_labels = len(labels)
-        label_counts = {float(l): int(np.sum(y == l)) for l in labels}
-        # per-feature category sets across ALL labels
-        categories = [np.unique(X[:, j]) for j in range(d)]
-        theta: List[List[Dict[float, float]]] = []
-        for l in labels:
-            rows = X[y == l]
-            label_theta = []
-            for j in range(d):
-                values, counts = np.unique(rows[:, j], return_counts=True)
-                count_map = dict(zip(values, counts))
-                theta_log = math.log(label_counts[float(l)] + smoothing * len(categories[j]))
-                label_theta.append(
-                    {
-                        float(v): math.log(count_map.get(v, 0.0) + smoothing) - theta_log
-                        for v in categories[j]
-                    }
-                )
-            theta.append(label_theta)
-        pi_log = math.log(n * d + num_labels * smoothing)
-        pi = np.asarray(
-            [
-                math.log(label_counts[float(l)] * d + smoothing) - pi_log
-                for l in labels
-            ]
+        n, d = X.shape
+        labels, label_idx = np.unique(y, return_inverse=True)
+        num_labels = labels.size
+        label_rows = np.bincount(label_idx, minlength=num_labels)
+        categories, thetas = [], []
+        for j in range(d):
+            values, inverse = np.unique(X[:, j], return_inverse=True)
+            m = values.size
+            counts = np.bincount(label_idx * m + inverse, minlength=num_labels * m).reshape(num_labels, m)
+            theta = _Theta(label_rows, m, smoothing, int(counts.max(initial=0)), m)
+            thetas.append(np.empty((num_labels, m), np.float32))
+            for i in range(num_labels):
+                theta.fill(i, counts[i], thetas[-1][i])
+            categories.append(values)
+        offsets = np.concatenate([[0], np.cumsum([c.size for c in categories], dtype=np.int64)])
+        theta_array = np.concatenate(thetas, axis=1) if thetas else np.zeros((num_labels, 0), np.float32)
+        return (
+            theta_array,
+            np.concatenate(categories) if categories else np.zeros(0, X.dtype),
+            offsets,
+            _log_priors(label_rows, n, d, smoothing),
+            labels,
         )
-        model = NaiveBayesModel()
-        model.theta = theta
-        model.pi = pi
-        model.labels = labels.astype(np.float64)
-        update_existing_params(model, self)
-        return model

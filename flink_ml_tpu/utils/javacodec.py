@@ -361,31 +361,79 @@ def load_reference_coefficient(stage_path: str) -> Optional[np.ndarray]:
 # with the same code.
 
 
-def encode_naivebayes_model_data(theta, pi, labels) -> bytes:
+# a Map<Double, Double> entry whose value is present: key, null flag 0, value
+_DOUBLE_ENTRY = np.dtype([("key", ">f8"), ("null", "u1"), ("value", ">f8")])
+
+
+def encode_double_map(keys, values) -> bytes:
+    """`encode_java_map(dict(zip(keys, values)), "double", "double")` from
+    two arrays, with no dict in between."""
+    entries = np.zeros(len(keys), _DOUBLE_ENTRY)
+    entries["key"] = keys
+    entries["value"] = values
+    return _INT.pack(len(keys)) + entries.tobytes()
+
+
+def read_double_map(stream) -> Tuple[np.ndarray, np.ndarray]:
+    """A Map<Double, Double> with no null value, as (keys, values) arrays in
+    the order they were written."""
+    (size,) = _INT.unpack(_read_exact(stream, 4))
+    entries = np.frombuffer(_read_exact(stream, size * _DOUBLE_ENTRY.itemsize), _DOUBLE_ENTRY)
+    if entries["null"].any():
+        raise ValueError("a Map<Double, Double> with a null value")
+    return entries["key"].astype(np.float64), entries["value"].astype(np.float64)
+
+
+def encode_naivebayes_model_data(theta, pi, labels, categories=None, offsets=None) -> bytes:
     """NaiveBayesModelData.ModelDataEncoder (NaiveBayesModelData.java:94-118):
     labels DenseVector + piArray DenseVector + int numLabels + int
-    numFeatures + numLabels*numFeatures Map<Double, Double>."""
+    numFeatures + numLabels*numFeatures Map<Double, Double>. `theta` is the
+    nested [label][feature] maps, or, with `categories` and `offsets`, the
+    model's [labels, categories] array (feature j's categories are
+    categories[offsets[j]:offsets[j + 1]])."""
     out = [encode_dense_vector(labels), encode_dense_vector(pi)]
-    num_labels = len(theta)
-    num_features = len(theta[0]) if num_labels else 0
+    if categories is None:
+        num_labels = len(theta)
+        num_features = len(theta[0]) if num_labels else 0
+        maps = (encode_java_map(m, "double", "double") for label_maps in theta for m in label_maps)
+    else:
+        num_labels, num_features = len(theta), len(offsets) - 1
+        bounds = list(zip(offsets[:-1], offsets[1:]))
+        maps = (encode_double_map(categories[a:b], theta[i][a:b]) for i in range(num_labels) for a, b in bounds)
     out.append(_INT.pack(num_labels))
     out.append(_INT.pack(num_features))
-    for label_maps in theta:
-        for m in label_maps:
-            out.append(encode_java_map(m, "double", "double"))
+    out.extend(maps)
     return b"".join(out)
 
 
 def read_naivebayes_model_data(stream) -> dict:
+    """The model as NaiveBayesModel keeps it: `theta_array` [labels,
+    categories] float64, each feature's `categories` ascending (a JVM's map
+    is in hash order) and their `offsets`, `piArray`, `labels`."""
     labels = read_dense_vector(stream)
     pi = read_dense_vector(stream)
     (num_labels,) = _INT.unpack(_read_exact(stream, 4))
     (num_features,) = _INT.unpack(_read_exact(stream, 4))
-    theta = np.empty((num_labels, num_features), dtype=object)
-    for i in range(num_labels):
-        for j in range(num_features):
-            theta[i, j] = read_java_map(stream, "double", "double")
-    return {"theta": theta, "piArray": pi, "labels": labels}
+    maps = [[read_double_map(stream) for _ in range(num_features)] for _ in range(num_labels)]
+    categories, columns = [], []
+    for j in range(num_features):
+        keys = np.sort(maps[0][j][0]) if num_labels else np.zeros(0)
+        rows = []
+        for i in range(num_labels):
+            order = np.argsort(maps[i][j][0], kind="stable")
+            if not np.array_equal(maps[i][j][0][order], keys):
+                raise ValueError(f"NaiveBayes theta: feature {j} has other categories under label {i} than under label 0")
+            rows.append(maps[i][j][1][order])
+        categories.append(keys)
+        columns.append(np.asarray(rows, np.float64).reshape(num_labels, keys.size))
+    offsets = np.concatenate([[0], np.cumsum([c.size for c in categories], dtype=np.int64)])
+    return {
+        "theta_array": np.concatenate(columns, axis=1) if columns else np.zeros((num_labels, 0)),
+        "categories": np.concatenate(categories) if categories else np.zeros(0),
+        "offsets": offsets.astype(np.int64),
+        "piArray": pi,
+        "labels": labels,
+    }
 
 
 def encode_countvectorizer_model_data(vocabulary) -> bytes:

@@ -60,12 +60,14 @@ def toy_fit(config_name: str, shards: int, max_iter: int = MAX_ITER) -> dict:
     module, _, cls = config["stage"]["class"].rpartition(".")
     stage = getattr(importlib.import_module(module), cls)()
     online = hasattr(stage, "set_initial_model_data")
-    if not online:
+    # NaiveBayes counts in one pass: no iterations and no batch
+    counting = not online and not hasattr(stage, "set_max_iter")
+    if not online and not counting:
         stage.set_max_iter(max_iter)
     lloyd = hasattr(stage, "set_k")
     if lloyd:
         stage.set_k(K)
-    else:
+    elif not counting:
         stage.set_global_batch_size(BATCH)
     mesh = mesh_lib.create_mesh((mesh_lib.DATA_AXIS,), devices=jax.devices()[:shards])
     rng = np.random.default_rng(29)
@@ -91,6 +93,11 @@ def toy_fit(config_name: str, shards: int, max_iter: int = MAX_ITER) -> dict:
             indices[:, 0], indices[:, 1] = 0, 1 + indices[:, 1] % 3
             indices[:, 2:] = rng.integers(4, size, (ROWS, DIM - 2))
         features = SparseBatch(size, by_rows(indices), by_rows(values))
+    elif counting:
+        # dictionary indices, fields narrow enough to be counted by compares
+        # and wide enough to be scatter-added
+        cards = [3, 24, 600, 5000] * (DIM // 4)
+        features = by_rows(np.stack([rng.integers(0, c, ROWS) for c in cards], axis=1).astype(np.float32))
     else:
         features = by_rows(values)
     label = by_rows((values.sum(axis=1) > DIM / 2).astype(np.float32))
@@ -264,7 +271,20 @@ def dense_fit_of_several_passes_over_four_shards() -> dict:
     return toy_fit("lr-dense-100", 4, SEVERAL_PASSES)["counters"]
 
 
+def naivebayes_fit_on_the_host() -> dict:
+    """The counters of a NaiveBayes fit that the device routes turn away (a
+    feature of more than 512 distinct values that are not indices): the side
+    of `nb_device_field_share` that its cell exists to show absent."""
+    from flink_ml_tpu.models.classification.naivebayes import NaiveBayes
+
+    before = metrics.snapshot()
+    values = np.random.default_rng(5).random((ROWS, 2)).astype(np.float32)
+    NaiveBayes().fit(Table({"features": jax.device_put(values), "label": jax.device_put((values[:, 0] > 0.5).astype(np.float32))}))
+    return metrics.snapshot_delta(before, metrics.snapshot())["counters"]
+
+
 OTHER_SIDE = {
+    "naivebayes.fields.host": naivebayes_fit_on_the_host,
     "assembler.dense_out": dense_assembly,
     "lloyd.product.full": lloyd_fit_of_general_floats,
     "layout.exchange": dense_fit_of_several_passes_over_four_shards,
