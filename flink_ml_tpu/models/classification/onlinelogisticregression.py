@@ -42,6 +42,7 @@ from ...common.param import (
     HasReg,
     HasWeightCol,
 )
+from ...ops import slot_write
 from ...ops.losses import sparse_dot
 from ...param import DoubleParam, ParamValidators
 from ...parallel.iteration import iterate_unbounded
@@ -148,63 +149,95 @@ def _touched_coordinates(coeff, indices, values, y):
     return coord, g, w, jnp.sum(writer)
 
 
-def _ftrl_update_at(z, n, coord, g, w, hyper):
+def _ftrl_at(z, n, coord, g, w, hyper):
     """The FTRL-Proximal update of `_ftrl_step` at the coordinates `coord`
-    (sorted, no repeats; one past the model's end is dropped): the new
-    coefficients there, and z and n with them written in. Every other
-    coordinate keeps its z and n to the bit: nothing of `d` elements is
-    swept."""
+    (sorted, no repeats; one past the model's end reads its last element):
+    the new coefficients, z and n there."""
     z_at = z.at[coord].get(mode="clip", **_SORTED_NO_REPEAT)
     n_at = n.at[coord].get(mode="clip", **_SORTED_NO_REPEAT)
-    w_new, z_new, n_new = _ftrl_proximal(w, z_at, n_at, g, hyper[0], hyper[1], hyper[2], hyper[3])
-    return (
-        w_new,
-        z.at[coord].set(z_new, mode="drop", **_SORTED_NO_REPEAT),
-        n.at[coord].set(n_new, mode="drop", **_SORTED_NO_REPEAT),
-    )
+    return _ftrl_proximal(w, z_at, n_at, g, hyper[0], hyper[1], hyper[2], hyper[3])
 
 
-def _ftrl_touched(z, n, coeff, indices, values, y, hyper):
+def _ftrl_touched(z, n, coeff, indices, values, y, hyper, writer=None):
     """One global batch of padded-CSR rows: the same mean per-dim gradient and
     FTRL-Proximal update as `_ftrl_step`, over the coordinates the batch
     holds; every other coordinate keeps w, z, n to the bit. (z, n, w) in and
     out, in that order: jax gives a donated argument to the first result of
     its shape, and z and n are the donated ones.
 
-    What the v5e charges at 2e8 coordinates shapes it (PERF.md, PR 31): a
+    What the v5e charges at 2e8 coordinates shapes it (PERF.md §5, §6): a
     gather costs 16 ns an element, so z and n are read at the batch's
     DISTINCT coordinates where those fill no more than a quarter of the
     batch's entries (skewed click-log fields repeat: 159,744 entries hold
-    37,000 coordinates), and at all of the entries' slots otherwise. z and n
-    are written in place. The coefficient is not donated, because a published
-    record holds it: its scatter writes into a copy, and that copy is the
-    fresh buffer the next record needs."""
+    37,000 coordinates), and at all of the entries' slots otherwise. XLA's
+    scatter passes over its whole operand whatever it writes, so with a
+    `writer` (`_slot_writer`'s word) `ops/slot_write` writes the state at the
+    distinct coordinates alone: the coefficient, which is not donated because
+    a published record holds it, as a fresh buffer made in one pass over it,
+    and z and n in place on the quarter's side, where a tile's read and
+    write a slot cost less than a pass (PERF.md §6). Otherwise (the
+    CPU, state on several devices, and z and n on the side of all the
+    entries) XLA scatters z and n in place and the coefficient into its
+    copy."""
     coord, g, w, distinct = _touched_coordinates(coeff, indices, values, y)
     entries = coord.shape[0]
     quarter = max(entries // 4, 1)
+    interpret = writer == "interpret"
 
-    def update_of(size):
+    def update_of(size, in_place):
         def update(z_, n_):
-            w_new, z_new, n_new = _ftrl_update_at(z_, n_, coord[:size], g[:size], w[:size], hyper)
-            return jnp.pad(w_new, (0, entries - size)), z_new, n_new
+            w_new, z_new, n_new = _ftrl_at(z_, n_, coord[:size], g[:size], w[:size], hyper)
+            if in_place:
+                z_, n_ = slot_write.write_in_place((z_, n_), coord[:size], (z_new, n_new), distinct, interpret)
+            else:
+                z_ = z_.at[coord[:size]].set(z_new, mode="drop", **_SORTED_NO_REPEAT)
+                n_ = n_.at[coord[:size]].set(n_new, mode="drop", **_SORTED_NO_REPEAT)
+            return jnp.pad(w_new, (0, entries - size)), z_, n_
 
         return update
 
-    w_new, z, n = lax.cond(distinct <= quarter, update_of(quarter), update_of(entries), z, n)
-    return z, n, coeff.at[coord].set(w_new, mode="drop", **_SORTED_NO_REPEAT)
+    small = update_of(quarter, writer is not None)
+    w_new, z, n = lax.cond(distinct <= quarter, small, update_of(entries, False), z, n)
+    if writer is None:
+        return z, n, coeff.at[coord].set(w_new, mode="drop", **_SORTED_NO_REPEAT)
+    return z, n, slot_write.copy_writing(coeff, coord, w_new, distinct, interpret)
+
+
+def _slot_writer(*state):
+    """How `_ftrl_touched` writes the state: by `ops/slot_write`'s kernels
+    ("kernel") where every array is float32 on one TPU and holds a whole tile
+    of them (a shorter vector is laid out in smaller tiles, which their
+    copies do not take), else by XLA's scatter (None), so the CPU's program
+    is XLA's. Read off the arrays, nothing a user sets; "interpret" runs the
+    kernels' own code where `mesh.on_tpu` says yes of an array that lies
+    elsewhere."""
+    from ...parallel import mesh as mesh_lib
+
+    if not all(
+        a.dtype == jnp.float32 and a.shape[0] >= slot_write.TILE and len(a.devices()) == 1 and mesh_lib.on_tpu(a)
+        for a in state
+    ):
+        return None
+    return "kernel" if all(d.platform == "tpu" for a in state for d in a.devices()) else "interpret"
 
 
 _touched_kernel: list = []
 
 
 def _ftrl_touched_step(coeff, z, n, indices, values, y, hyper):
-    """`_ftrl_touched` jitted once, z and n donated: (w, z, n) -> (w, z, n)."""
+    """`_ftrl_touched` jitted once, z and n donated: (w, z, n) -> (w, z, n).
+    A batch whose state the slot writer writes counts `ftrl.write.slots`."""
+    from ...utils import metrics
+
     if not _touched_kernel:
         from ...parallel.dispatch import supports_donation
 
         donate = {"donate_argnames": ("z", "n")} if supports_donation() else {}
-        _touched_kernel.append(lazy_jit(_ftrl_touched, **donate))
-    z, n, coeff = _touched_kernel[0](z, n, coeff, indices, values, y, hyper)
+        _touched_kernel.append(lazy_jit(_ftrl_touched, static_argnames=("writer",), **donate))
+    writer = _slot_writer(coeff, z, n)
+    if writer is not None:
+        metrics.inc_counter("ftrl.write.slots")
+    z, n, coeff = _touched_kernel[0](z, n, coeff, indices, values, y, hyper, writer=writer)
     return coeff, z, n
 
 

@@ -862,3 +862,52 @@ def test_compiled_for_a_v5e_the_plan_reads_the_rows_a_fit_reaches_where_they_lie
     memory = plan.memory_analysis()
     assert memory.argument_size_in_bytes >= 28_000_000 * 39 * 4
     assert memory.temp_size_in_bytes < 64 << 20
+
+
+# --- one chip: the online FTRL step writes the slots it changes (ops/slot_write.py) ------
+
+
+STREAM_DIM = 204_184_601  # the stream cell's coordinates
+
+
+def compiled_ftrl_touched(four_v5e, writer):
+    """`_ftrl_touched` for the stream cell's batch (4,096 rows of 39 entries)
+    on ONE described v5e, z and n donated as the online loop donates them."""
+    from jax.sharding import SingleDeviceSharding
+
+    from flink_ml_tpu.models.classification import onlinelogisticregression as olr
+
+    chip = SingleDeviceSharding(four_v5e.devices.flat[0])
+
+    def on_chip(shape, dtype=np.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    step = jax.jit(olr._ftrl_touched, static_argnames=("writer",), donate_argnames=("z", "n"))
+    state = on_chip((STREAM_DIM,))
+    return step.lower(
+        state, state, state, on_chip((4096, 39), np.int32), on_chip((4096, 39)), on_chip((4096,)), on_chip((4,)), writer=writer
+    ).compile()
+
+
+def test_compiled_for_a_v5e_the_ftrl_step_writes_its_slots_and_passes_over_the_coefficient_once(four_v5e):
+    """With the slot writer the step copies no model-sized vector and scatters
+    into none on the quarter's side, the stream cell's: there z and n are
+    written in place by one kernel (their partial last tile by an in-place
+    update of its 25 elements). The fresh coefficient the record needs is made
+    by one kernel that passes over the old one once, on either side (XLA's
+    form copies it, then scatters into the copy). z and n keep XLA's in-place
+    scatters on the side of all the entries alone."""
+    full = rf"f32\[{STREAM_DIM}\]"
+    kernel = compiled_ftrl_touched(four_v5e, "kernel")
+    text = kernel.as_text()
+    assert not re.search(rf"{full}\S* copy\(", text)
+    calls = [name.rsplit(".", 1)[0] for name, opcode in instructions(kernel) if opcode == "custom-call"]
+    assert sorted(name for name in calls if name.startswith("slot_write")) == ["slot_write_copy", "slot_write_in_place"]
+    assert re.search(rf"= {full}\S* custom-call\(", text)  # the one pass that makes the record's buffer
+    updates = re.findall(rf"= {full}\S* fusion\(.*op_name=\"jit\(_ftrl_touched\)/([^\"]*)\"", text)
+    assert sorted(updates) == ["cond/branch_0_fun/scatter"] * 2 + ["cond/branch_1_fun/dynamic_update_slice"] * 2
+    assert 'op_name="jit(_ftrl_touched)/cond/branch_1_fun/slot_write_in_place/pallas_call"' in text  # the quarter's side
+    assert "input_output_alias={ {0}: (0, {}, may-alias), {1}: (1, {}, may-alias) }" in text
+    memory = kernel.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * STREAM_DIM * 4  # z and n where they lie
+    assert memory.temp_size_in_bytes < 64 << 20

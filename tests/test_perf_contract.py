@@ -319,8 +319,36 @@ def fence_of_a_ready_state() -> dict:
     return metrics.snapshot_delta(before, metrics.snapshot())["counters"]
 
 
+def stream_on_a_tpu() -> dict:
+    """The counters a sparse stream moves where its state lies on a TPU: the
+    slot writer's, which the CPU's arrays never take (`mesh.on_tpu` told
+    yes; its kernels interpreted)."""
+    from flink_ml_tpu.models.classification.onlinelogisticregression import OnlineLogisticRegression
+
+    rng = np.random.default_rng(7)
+    batches = [
+        Table({"features": SparseBatch(2048, rng.integers(0, 64, (16, 4)).astype(np.int32), rng.random((16, 4)).astype(np.float32)),
+               "label": (rng.random(16) > 0.5).astype(np.float32)})
+        for _ in range(2)
+    ]
+    learner = OnlineLogisticRegression().set_global_batch_size(16)
+    learner.set_initial_model_data(Table({"coefficient": [DenseVector(np.zeros(2048))]}))
+    on_tpu = mesh_lib.on_tpu
+    mesh_lib.on_tpu = lambda arr: True
+    try:
+        before = metrics.snapshot()
+        learner.fit(StreamTable.from_batches(batches)).process_updates()
+        return metrics.snapshot_delta(before, metrics.snapshot())["counters"]
+    finally:
+        mesh_lib.on_tpu = on_tpu
+
+
 # counters that no toy fit is sure to move, by what is sure to move them
-ON_DEMAND = {"host.gc.ns": collection_inside_a_fit, "online.fence.dry": fence_of_a_ready_state}
+ON_DEMAND = {
+    "host.gc.ns": collection_inside_a_fit,
+    "online.fence.dry": fence_of_a_ready_state,
+    "ftrl.write.slots": stream_on_a_tpu,
+}
 
 
 @pytest.mark.parametrize("metric,counter", counters_read())
